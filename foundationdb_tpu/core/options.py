@@ -31,10 +31,11 @@ class Knobs:
     # the FULL per-batch accept step as one fused Pallas kernel
     # (ops/pallas_scan.py): exact ring check + intra-batch segment
     # intersection + greedy acceptance in VMEM, subsuming pallas_ring's
-    # lane when engaged. Same tri-state as pallas_ring; auto-gates off
-    # when the static shape is ineligible (txns > 1024, partitioned
-    # ring) and falls back to the jit path under the pallas_to_jit
-    # taxonomy on lowering errors.
+    # lane when engaged. Only "on" engages it (interpreter off-TPU, for
+    # the differential tests); "auto" and "off" leave it out, because
+    # the v5e's compiler refuses the kernel (tests/test_tpu_compile.py)
+    # and a refusal at run time costs a fenced restart under the
+    # pallas_to_jit taxonomy.
     pallas_scan: str = "auto"
     # mesh lane ownership (resolver/meshresolver.py, multi-lane tpu
     # fleets only): "range" routes each packed entry host-side to the

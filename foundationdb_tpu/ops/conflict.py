@@ -296,11 +296,7 @@ def resolve_batch(
         shard_idx = jnp.int32(0)
         mesh_n = 1
         for nm in names:
-            # lax.axis_size is the modern API; older jax answers the
-            # static size via the psum(1, axis) idiom
-            sz = (jax.lax.axis_size(nm)
-                  if hasattr(jax.lax, "axis_size")
-                  else jax.lax.psum(1, nm))
+            sz = jax.lax.axis_size(nm)
             shard_idx = shard_idx * sz + jax.lax.axis_index(nm)
             mesh_n *= sz
         if n_shards != mesh_n:
@@ -1020,10 +1016,9 @@ def make_resolve_scan_fn(params: ResolverParams, donate=True,
     fused accept kernel replaces the whole step body (ring + intra-batch
     + acceptance), so there is no jnp/pallas split for XLA to schedule
     around — the scan path keeps it whenever the params carry it. One
-    dispatch amortizes the host→device launch cost across B batches,
-    which dominates when the host link is high-latency (remote TPU) and
-    still saves ~dispatch-overhead×B on local chips. This is the proxy's
-    throughput path; single-batch ``make_resolve_fn`` is the latency path.
+    dispatch amortizes the host→device launch cost across B batches.
+    This is the proxy's throughput path; single-batch
+    ``make_resolve_fn`` is the latency path.
     Returns (state, statuses[B, T]).
     """
     validate_params(params)

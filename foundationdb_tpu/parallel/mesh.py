@@ -20,17 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from foundationdb_tpu.ops import conflict as ck
 
-# jax moved shard_map to the top level (and renamed check_rep →
-# check_vma) around 0.6; older runtimes only ship the experimental
-# module. One gated alias keeps the kernel running on both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # pragma: no cover — exercised on older-jax containers
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 AXIS = "rs"
 
 
@@ -118,21 +107,21 @@ class ShardedResolverKernel:
             ck.resolve_batch, params=params, axis_name=self.spec_axes,
             n_shards=self.n,
         )
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=(_state_specs(self.spec_axes), _batch_specs()),
             out_specs=(P(), P(), _state_specs(self.spec_axes)),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         self._step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
-        scan_sharded = _shard_map(
+        scan_sharded = jax.shard_map(
             ck.scan_of(fn),
             mesh=self.mesh,
             in_specs=(_state_specs(self.spec_axes), _batch_specs()),
             out_specs=(_state_specs(self.spec_axes), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         self._scan_step = jax.jit(
             scan_sharded, donate_argnums=(0,) if donate else ()
@@ -206,23 +195,23 @@ class PreshardedResolverKernel(ShardedResolverKernel):
             ck.resolve_batch_presharded, params=params,
             axis_name=self.spec_axes,
         )
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=(_state_specs(self.spec_axes),
                       _shard_batch_specs(self.spec_axes)),
             out_specs=(P(), P(), _state_specs(self.spec_axes)),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         self._step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
-        scan_sharded = _shard_map(
+        scan_sharded = jax.shard_map(
             ck.scan_of(fn),
             mesh=self.mesh,
             in_specs=(_state_specs(self.spec_axes),
                       _shard_batch_specs(self.spec_axes, scan=True)),
             out_specs=(_state_specs(self.spec_axes), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         self._scan_step = jax.jit(
             scan_sharded, donate_argnums=(0,) if donate else ()

@@ -1481,10 +1481,15 @@ class Cluster:
         dispatch accounting — pad/bucket occupancy, compile-cache
         events, staging reuse, transfer bytes, per-lane walls — plus a
         cluster aggregate, all from the cluster-owned store so the doc
-        survives recoveries and configure()."""
+        survives recoveries and configure(); the devices the live
+        resolvers' history sits on; and, in a process started through
+        an entry point, its XLA build counts."""
         profs = [p for (_, _), p in sorted(self._device_store.items())]
+        log = deviceprofile.compile_log()
         return {
             "enabled": deviceprofile.enabled(),
+            **deviceprofile.placement(self.resolvers),
+            "compile": log.snapshot() if log is not None else None,
             "resolvers": [p.snapshot() for p in profs],
             "aggregate": deviceprofile.merged_snapshot(profs),
         }

@@ -201,6 +201,9 @@ def main(argv=None):
                 [a.strip() for a in args.coordinators.split(",")],
                 secret=secret,
             )
+        from foundationdb_tpu.utils import deviceprofile
+
+        deviceprofile.enter_process()  # compile cache + build counts
         cluster = build_cluster(args, coordination)
         service = ClusterService(cluster)
         server.add_handlers(service.handlers(), long_methods={"watch_wait"})

@@ -31,6 +31,7 @@ runs the ycsb e2e both ways (interleaved pairs, median compare) and
 gates at ≤2% overhead, the metrics_smoke protocol.
 """
 
+import os
 import threading
 
 from foundationdb_tpu.core import deterministic
@@ -334,6 +335,94 @@ class DeviceProfile:
                 "kernel_routes": dict(sorted(
                     self.kernel_routes.items())),
             }
+
+
+def placement(resolvers):
+    """Where the resolvers' history actually lives: platform, device
+    kind and distinct-device count read off the devices holding each
+    device-backed resolver's state arrays (not off ``jax.devices()`` —
+    a mesh clamped to one device and a host backend both answer
+    differently from what the process could see). All None/0 when no
+    resolver keeps state on a device."""
+    devs = set()
+    for r in resolvers:
+        state = getattr(r, "state", None)
+        if state is not None:
+            # the sharding outlives donation; .devices() raises on an
+            # array a concurrent step has just consumed
+            devs |= state.ht.sharding.device_set
+    first = min(devs, key=lambda d: d.id, default=None)
+    return {
+        "platform": first.platform if first else None,
+        "device_kind": first.device_kind if first else None,
+        "device_count": len(devs),
+    }
+
+
+class CompileLog:
+    """Process-wide XLA build accounting off ``jax.monitoring``: every
+    program JAX builds (``backend_compiles``, persistent-cache hits
+    included), the seconds that took, and how many came out of the
+    persistent cache. A steady window that builds anything has met a
+    shape its warm-up missed."""
+
+    def __init__(self):
+        self._lock = lockdep.lock("CompileLog._lock")
+        self.backend_compiles = 0
+        self.backend_compile_s = 0.0
+        self.cache_hits = 0
+
+    def _on_duration(self, event, duration_secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            with self._lock:
+                self.backend_compiles += 1
+                self.backend_compile_s += duration_secs
+
+    def _on_event(self, event, **_kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.cache_hits += 1
+
+    def snapshot(self):
+        with self._lock:
+            return {
+                "backend_compiles": self.backend_compiles,
+                "backend_compile_s": round(self.backend_compile_s, 3),
+                "cache_hits": self.cache_hits,
+            }
+
+
+_compile_log = None
+
+
+def compile_log():
+    """This process's :class:`CompileLog`, or None until an entry point
+    has called :func:`enter_process` (a library import installs no
+    listener, and same-seed sim docs stay byte-identical)."""
+    return _compile_log
+
+
+def enter_process():
+    """Called once by each process entry point (chip_smoke.py's
+    children, tools/fdbserver.py, bench.py) before JAX builds anything:
+    place the persistent compile cache and start counting builds.
+
+    The cache goes where ``JAX_COMPILATION_CACHE_DIR`` says; only when
+    that is unset does code name a place, ``<checkout>/.jax_cache`` —
+    fixed, because the path is part of the cache key."""
+    global _compile_log
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    if _compile_log is None:
+        _compile_log = CompileLog()
+        jax.monitoring.register_event_duration_secs_listener(
+            _compile_log._on_duration)
+        jax.monitoring.register_event_listener(_compile_log._on_event)
 
 
 def merged_snapshot(profiles):

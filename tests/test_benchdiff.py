@@ -1,7 +1,9 @@
-"""tools/benchdiff.py over the CHECKED-IN bench rounds: round loading
-(parsed / tail-recovery / unparseable), metric alignment with explicit
-"n/a" for missing fields, polarity-oriented regression flags, and the
-CLI entrypoint."""
+"""tools/benchdiff.py over five bench rounds — the checked-in
+BENCH_r03–r05 behind two written here in their shape (a crashed round,
+and the one pre-series round the driver parsed on a chip): round
+loading (parsed / tail-recovery / unparseable), metric alignment with
+explicit "n/a" for missing fields, polarity-oriented regression flags,
+and the CLI entrypoint."""
 
 import json
 import os
@@ -13,18 +15,39 @@ import pytest
 from foundationdb_tpu.tools import benchdiff
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROUNDS = [os.path.join(REPO, f"BENCH_r0{n}.json") for n in range(1, 6)]
+CMD = "if [ -f bench.py ]; then python bench.py; else exit 0; fi"
+# ROADMAP "What the records are": the one round that ran on a chip
+CHIP_ROUND = {
+    "metric": "resolved_txns_per_sec_ycsb_a_zipfian99",
+    "value": 1675420.4, "unit": "txns/sec", "vs_baseline": 1.675,
+    "batch_size": 8192, "p99_batch_ms": 7.63, "kernel_step_ms": 2.968,
+    "platform": "tpu", "pallas_kernel_step": False,
+    "e2e_committed_txns_per_sec": 8628.7, "e2e_mean_batch": 858.2,
+}
 
 
 @pytest.fixture(scope="module")
-def report():
-    missing = [p for p in ROUNDS if not os.path.exists(p)]
-    if missing:
-        pytest.skip(f"bench rounds not checked in: {missing}")
+def ROUNDS(tmp_path_factory):
+    d = tmp_path_factory.mktemp("rounds")
+    crashed = d / "BENCH_r01.json"  # rc=1, a traceback, no JSON
+    crashed.write_text(json.dumps({
+        "n": 1, "cmd": CMD, "rc": 1, "parsed": None,
+        "tail": "Traceback (most recent call last):\n  ...\n"
+                "RuntimeError: Unable to initialize backend\n"}))
+    chip = d / "BENCH_r02.json"  # the driver parsed the headline
+    chip.write_text(json.dumps({
+        "n": 2, "cmd": CMD, "rc": 0, "parsed": CHIP_ROUND,
+        "tail": json.dumps(CHIP_ROUND) + "\n"}))
+    return [str(crashed), str(chip)] + [
+        os.path.join(REPO, f"BENCH_r0{n}.json") for n in (3, 4, 5)]
+
+
+@pytest.fixture(scope="module")
+def report(ROUNDS):
     return benchdiff.diff_rounds([benchdiff.load_round(p) for p in ROUNDS])
 
 
-def test_load_round_classifies_the_fixtures():
+def test_load_round_classifies_the_fixtures(ROUNDS):
     r1 = benchdiff.load_round(ROUNDS[0])  # crashed: rc=1, no JSON
     assert r1["doc"] is None and "unparseable" in r1["note"]
     r2 = benchdiff.load_round(ROUNDS[1])  # driver parsed the headline
@@ -128,7 +151,7 @@ def test_format_report_renders_na_and_regressions(report):
     assert "REGRESSIONS" in text and "value" in text
 
 
-def test_cli_module_entrypoint(tmp_path):
+def test_cli_module_entrypoint(ROUNDS):
     """``python -m foundationdb_tpu.tools.benchdiff`` produces the
     aligned report (text and --json) and exits nonzero on regression."""
     proc = subprocess.run(

@@ -405,7 +405,8 @@ def test_status_device_section_and_special_key():
         raw = db.run(lambda tr: tr.get(specialkeys.DEVICE))
         doc = json.loads(raw)
         assert doc["aggregate"]["dispatches"] >= 1
-        assert set(doc) == {"enabled", "resolvers", "aggregate"}
+        assert set(doc) == {"enabled", "resolvers", "aggregate", "platform",
+                            "device_kind", "device_count", "compile"}
         # special reads never add conflict ranges
         tr = db.create_transaction()
         tr.get(specialkeys.DEVICE)

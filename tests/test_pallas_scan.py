@@ -184,14 +184,14 @@ def test_forced_lowering_error_in_backlog_scan(monkeypatch):
 
 def test_explicit_on_beyond_txn_budget_rejected():
     """pallas_scan="on" with txns > MAX_TXNS must fail loudly at
-    construction (validate_params), not silently downgrade — only
-    "auto" gates off."""
+    construction (validate_params), not silently downgrade; "auto"
+    never engages the kernel."""
     kw = dict(KNOBS_KW, batch_txn_capacity=pallas_scan_mod.MAX_TXNS * 2,
               hash_table_bits=14,
               range_ring_capacity=pallas_scan_mod.MAX_TXNS * 2)
     with pytest.raises(ValueError, match="MAX_TXNS|txns"):
         Resolver(Knobs(**kw, pallas_scan="on"))
-    r = Resolver(Knobs(**kw, pallas_scan="auto"))  # auto: quiet downgrade
+    r = Resolver(Knobs(**kw, pallas_scan="auto"))
     assert not r.params.use_pallas_scan
 
 

@@ -369,7 +369,7 @@ def test_grv_coalescing_leader_failure_releases_waiters():
             self.calls += 1
             if self.calls == 1:
                 self.gate.wait(5)  # hold round 1 until waiters register
-                raise OSError("tunnel died")
+                raise OSError("socket died")
             return 42
 
     rc = FakeRC()

@@ -1,0 +1,107 @@
+"""The main path's device programs, compiled for a described v5e.
+
+The sandbox has no chip but it has the chip's compiler: these tests
+hand it the five programs a default-knob resolver can dispatch (the
+single-step full variant on the jnp lanes and with the Pallas ring
+kernel, the B=8 backlog scan in its fast and full variants, and the
+fused Pallas scan kernel) at ``Knobs()`` shapes. Four must compile; the
+fused kernel is refused by Mosaic, which is why ``pallas_scan="auto"``
+no longer selects it (resolver/resolver.py). A compile that passes is
+not a chip run — ``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file.
+"""
+
+import os
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from foundationdb_tpu.core.options import Knobs
+from foundationdb_tpu.ops import conflict as ck
+from foundationdb_tpu.resolver.packing import BatchPacker
+from foundationdb_tpu.resolver.resolver import (
+    BACKLOG_B, fast_params_of, params_from_knobs)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_branch(monkeypatch):
+    """ops/conflict.py asks ``jax.default_backend()`` to choose between
+    compiling a Pallas kernel and interpreting it; the sandbox answers
+    "cpu". Steer it here, in the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(tree, sharding, lead=()):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(lead + tuple(x.shape), x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compile(make_fn, params, sharding, lead=()):
+    state = _shapes(jax.eval_shape(lambda: ck.init_state(params)), sharding)
+    batch = _shapes(BatchPacker(params).pack_empty(0, 1, 0), sharding, lead)
+    return make_fn(params).lower(state, batch).compile()
+
+
+def _default_params(**flags):
+    knobs = Knobs()
+    assert (knobs.batch_txn_capacity, knobs.hash_table_bits,
+            knobs.range_ring_capacity, knobs.coarse_buckets_bits) == (
+                1024, 22, 4096, 14)
+    return params_from_knobs(knobs, **flags)
+
+
+@pytest.mark.parametrize("program", [
+    "step_full_jnp", "step_full_pallas_ring", "scan_fast", "scan_full"])
+def test_default_knob_programs_compile_for_v5e(one_chip, tpu_branch, program):
+    if program == "step_full_jnp":
+        compiled = _compile(ck.make_resolve_fn, _default_params(), one_chip)
+    elif program == "step_full_pallas_ring":
+        compiled = _compile(ck.make_resolve_fn,
+                            _default_params(use_pallas=True), one_chip)
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        # the served backlog path: what Resolver._make_scan_fn builds on
+        # a TPU, where every backlog pads to the one B=8 scan
+        params = _default_params(use_pallas=True)
+        if program == "scan_fast":
+            params = fast_params_of(params)
+        compiled = _compile(ck.make_resolve_scan_fn, params, one_chip,
+                            lead=(BACKLOG_B,))
+        assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "Mosaic refuses ops/pallas_scan.py's fused kernel: infer-vector-layout:"
+    " unsupported shape cast, tpu.reshape (vector<128xi1>) ->"
+    " vector<128x1xi1>. The day this compiles, decide ROADMAP C2."))
+def test_fused_scan_kernel_compiles_for_v5e(one_chip, tpu_branch):
+    params = params_from_knobs(Knobs(batch_txn_capacity=128),
+                               use_pallas_scan=True)
+    _compile(ck.make_resolve_fn, params, one_chip)
