@@ -443,8 +443,8 @@ def run_e2e(cpu, mode=None, n_resolvers=None, backend="tpu", seconds=None,
     if n_proxies is None:
         n_proxies = int(env("BENCH_E2E_PROXIES",
                             2 if backend in ("native", "cpu") else 1))
-    # distributed tracing (utils/span.py): off unless the caller (the
-    # tracing_smoke probe) or the env asks — spans_sampled rides the
+    # distributed tracing (utils/span.py): off unless the caller or
+    # the env asks — spans_sampled rides the
     # line either way so the artifact shows whether tracing was live
     if tracing_sample_rate is None:
         tracing_sample_rate = float(env("BENCH_TRACING_RATE", 0.0))
@@ -1908,66 +1908,11 @@ def run_kernel_smoke(cpu):
     }
 
 
-def run_metrics_smoke(cpu, seconds=None, rounds=None):
-    """BENCH_MODE=metrics_smoke: the metrics subsystem's overhead
-    budget, measured — the ycsb e2e with the registry ENABLED vs the
-    module kill switch OFF, interleaved pairs, median throughput each.
-    The acceptance bar is ≤2% overhead (``within_budget``); the enabled
-    run's commit/GRV bands ride along so the smoke also proves the
-    spans are live. Short runs are noisy, so pairs interleave
-    (scheduler drift hits both arms) and the medians compare."""
-    from foundationdb_tpu.utils import metrics as metrics_mod
-
-    env = os.environ.get
-    secs = seconds if seconds is not None \
-        else float(env("BENCH_SMOKE_SECONDS", 2))
-    rounds = rounds if rounds is not None \
-        else int(env("BENCH_SMOKE_ROUNDS", 3))
-    backend = "native"
-    runs = {True: [], False: []}
-    fields_on = None
-    try:
-        for _ in range(rounds):
-            for on in (False, True):
-                metrics_mod.set_enabled(on)
-                try:
-                    r = run_e2e(cpu, backend=backend, seconds=secs)
-                except Exception as e:
-                    sys.stderr.write(f"native smoke failed ({e}); cpu\n")
-                    backend = "cpu"
-                    r = run_e2e(cpu, backend=backend, seconds=secs)
-                runs[on].append(r["e2e_committed_txns_per_sec"])
-                if on:
-                    fields_on = r
-    finally:
-        metrics_mod.set_enabled(True)
-    v_on = float(np.median(runs[True]))
-    v_off = float(np.median(runs[False]))
-    overhead_pct = round(max(0.0, 1.0 - v_on / max(v_off, 1e-9)) * 100, 2)
-    return {
-        "metric": "e2e_metrics_smoke",
-        "value": v_on,
-        "unit": "txns/sec",
-        "vs_baseline": round(v_on / BASELINE_TXNS_PER_SEC, 3),
-        "disabled_txns_per_sec": round(v_off, 1),
-        "metrics_overhead_pct": overhead_pct,
-        "overhead_budget_pct": 2.0,
-        "within_budget": overhead_pct <= 2.0,
-        "smoke_rounds": rounds,
-        "e2e_backend": backend,
-        "platform": fields_on.get("platform"),
-        "commit_p50_ms": fields_on.get("commit_p50_ms"),
-        "commit_p99_ms": fields_on.get("commit_p99_ms"),
-        "grv_p99_ms": fields_on.get("grv_p99_ms"),
-        "hottest_stage": fields_on.get("hottest_stage"),
-    }
-
-
 def run_health_smoke(cpu, seconds=None, rounds=None):
     """BENCH_MODE=health_smoke: the cluster-doctor subsystem's overhead
     budget, measured — the ycsb e2e with the latency prober + health
     rollups ENABLED vs the health kill switch OFF, interleaved pairs,
-    median throughput each, ≤2% budget (the metrics_smoke protocol).
+    median throughput each, ≤2% budget (interleaved pairs, medians compared).
     The enabled arm's probe bands / verdict ride along so the smoke
     also proves the prober actually committed real probe transactions
     under the measured load."""
@@ -2029,8 +1974,8 @@ def run_history_smoke(cpu, seconds=None, rounds=None):
     """BENCH_MODE=history_smoke: the metrics-history collector's
     overhead budget, measured — the ycsb e2e with the HistoryCollector
     + flight recorder ENABLED vs the timeseries kill switch OFF,
-    interleaved pairs, median throughput each, ≤2% budget (the
-    metrics_smoke protocol). The enabled arm's retained windows /
+    interleaved pairs, median throughput each, ≤2% budget. The
+    enabled arm's retained windows /
     flight dumps / commit-rate trend ride along so the smoke also
     proves the collector actually cut windows under the measured
     load."""
@@ -2227,7 +2172,7 @@ def run_heatmap_smoke(cpu, seconds=None, rounds=None):
     overhead budget, measured — the ycsb e2e with the heatmap kill
     switch ON (conflict charging + storage key sampling + per-tag
     counters live) vs OFF, interleaved pairs, median throughput each,
-    ≤2% budget (the metrics_smoke protocol). The enabled arm's
+    ≤2% budget (interleaved pairs, medians compared). The enabled arm's
     hot-range/tag fields ride along so the smoke also proves the
     heatmaps actually populated under the measured load."""
     from foundationdb_tpu.utils import heatmap as heatmap_mod
@@ -2277,66 +2222,6 @@ def run_heatmap_smoke(cpu, seconds=None, rounds=None):
             "hot_range_conflict_heat"),
         "tags_seen": fields_on.get("tags_seen"),
         "tag_busiest": fields_on.get("tag_busiest"),
-        "commit_p50_ms": fields_on.get("commit_p50_ms"),
-        "commit_p99_ms": fields_on.get("commit_p99_ms"),
-    }
-
-
-def run_profile_smoke(cpu, seconds=None, rounds=None):
-    """BENCH_MODE=profile_smoke: the device-path execution profiler's
-    overhead budget, measured — the ycsb e2e with the deviceprofile
-    kill switch ON (dispatch accounting, compile-cache observation,
-    staging/fallback hooks live) vs OFF, interleaved pairs, median
-    throughput each, ≤2% budget (the metrics_smoke protocol). The
-    enabled arm's profiler fields ride along so the smoke also proves
-    the dispatch accounting populated under the measured load."""
-    from foundationdb_tpu.utils import deviceprofile as dev_mod
-
-    env = os.environ.get
-    secs = seconds if seconds is not None \
-        else float(env("BENCH_SMOKE_SECONDS", 2))
-    rounds = rounds if rounds is not None \
-        else int(env("BENCH_SMOKE_ROUNDS", 3))
-    backend = "native"
-    runs = {True: [], False: []}
-    fields_on = None
-    try:
-        for _ in range(rounds):
-            for on in (False, True):
-                dev_mod.set_enabled(on)
-                try:
-                    r = run_e2e(cpu, backend=backend, seconds=secs)
-                except Exception as e:
-                    sys.stderr.write(f"native smoke failed ({e}); cpu\n")
-                    backend = "cpu"
-                    r = run_e2e(cpu, backend=backend, seconds=secs)
-                runs[on].append(r["e2e_committed_txns_per_sec"])
-                if on:
-                    fields_on = r
-    finally:
-        dev_mod.set_enabled(True)
-    v_on = float(np.median(runs[True]))
-    v_off = float(np.median(runs[False]))
-    overhead_pct = round(max(0.0, 1.0 - v_on / max(v_off, 1e-9)) * 100, 2)
-    return {
-        "metric": "e2e_profile_smoke",
-        "value": v_on,
-        "unit": "txns/sec",
-        "vs_baseline": round(v_on / BASELINE_TXNS_PER_SEC, 3),
-        "disabled_txns_per_sec": round(v_off, 1),
-        "profile_overhead_pct": overhead_pct,
-        "overhead_budget_pct": 2.0,
-        "within_budget": overhead_pct <= 2.0,
-        "smoke_rounds": rounds,
-        "e2e_backend": backend,
-        "platform": fields_on.get("platform"),
-        "pad_waste_pct": fields_on.get("pad_waste_pct"),
-        "bucket_histogram": fields_on.get("bucket_histogram"),
-        "recompiles": fields_on.get("recompiles"),
-        "fallback_causes": fields_on.get("fallback_causes"),
-        "lane_skew_pct": fields_on.get("lane_skew_pct"),
-        "device_dispatches": fields_on.get("device_dispatches"),
-        "staging_reuse_rate": fields_on.get("staging_reuse_rate"),
         "commit_p50_ms": fields_on.get("commit_p50_ms"),
         "commit_p99_ms": fields_on.get("commit_p99_ms"),
     }
@@ -2480,7 +2365,7 @@ def run_lockdep_smoke(cpu, seconds=None, rounds=None):
     lock wrapped, per-thread acquisition-order recording, edge/cycle
     bookkeeping until the graph freezes) vs OFF (factories hand out
     plain threading primitives), interleaved pairs, median throughput
-    each, ≤2% budget (the metrics_smoke protocol). The witness wraps
+    each, ≤2% budget (interleaved pairs, medians compared). The witness wraps
     locks at CONSTRUCTION, so each enabled arm flips it on before
     run_e2e builds its cluster and off right after. The enabled arm's
     witness gauges ride along — observed edges prove the witness was
@@ -2544,7 +2429,7 @@ def run_faultcov_smoke(cpu, seconds=None, rounds=None):
     (every FDBError construction attributes its fabrication site via
     one frame walk and bumps a per-site counter) vs OFF (one
     module-global read per construction), interleaved pairs, median
-    throughput each, ≤2% budget (the metrics_smoke protocol). The
+    throughput each, ≤2% budget (interleaved pairs, medians compared). The
     enabled arms' gauges ride along — the union of fired sites across
     rounds, diffed against the static FL011 table
     (analysis/faultsites.txt): coverage is observational, but a fired
@@ -2606,117 +2491,12 @@ def run_faultcov_smoke(cpu, seconds=None, rounds=None):
     }
 
 
-def run_tracing_smoke(cpu, seconds=None, rounds=None, rate=None):
-    """BENCH_MODE=tracing_smoke: the distributed-tracing overhead
-    budget, measured — the ycsb e2e with tracing at the DEFAULT enabled
-    sample rate (0.01) vs tracing off, interleaved pairs, median
-    compare, ≤2% budget (same protocol as metrics_smoke). The enabled
-    arm's Span events feed the critical-path tool, whose hottest-STAGE
-    attribution is cross-checked against stage_summary's hottest stage
-    (the acceptance tie between span trees and the PR-1 stage
-    timers)."""
-    from foundationdb_tpu.tools import tracing as tracetool
-    from foundationdb_tpu.utils.trace import global_trace_log
-
-    env = os.environ.get
-    secs = seconds if seconds is not None \
-        else float(env("BENCH_SMOKE_SECONDS", 2.5))
-    rounds = rounds if rounds is not None \
-        else int(env("BENCH_SMOKE_ROUNDS", 4))
-    rate = rate if rate is not None \
-        else float(env("BENCH_TRACING_RATE", 0.01))
-    backend = "native"
-    runs = {True: [], False: []}
-    fields_on = None
-    spans = []
-    log = global_trace_log()
-    # one discarded warmup pair: first-run JIT/allocator warmup lands
-    # on whichever arm goes first and was measured inflating the
-    # first pair's difference ~3x on a 1-core host. Single proxy: the
-    # smoke also cross-checks the STAGE spans against the stage
-    # timers, which the pipelined (begin/finish) path records — a
-    # fleet splits the backlog and can starve it of multi-chunk groups
-    try:
-        run_e2e(cpu, backend=backend, seconds=min(1.0, secs),
-                n_proxies=1, tracing_sample_rate=0.0)
-        run_e2e(cpu, backend=backend, seconds=min(1.0, secs),
-                n_proxies=1, tracing_sample_rate=rate)
-    except Exception as e:
-        sys.stderr.write(f"native smoke failed ({e}); cpu\n")
-        backend = "cpu"
-    for i in range(rounds):
-        for on in (False, True):
-            capture = on and i == rounds - 1
-            if capture:
-                log.clear()  # the last enabled arm feeds the tool
-            kw = {"tracing_sample_rate": rate if on else 0.0,
-                  "n_proxies": 1}
-            try:
-                r = run_e2e(cpu, backend=backend, seconds=secs, **kw)
-            except Exception as e:
-                sys.stderr.write(f"native smoke failed ({e}); cpu\n")
-                backend = "cpu"
-                r = run_e2e(cpu, backend=backend, seconds=secs, **kw)
-            runs[on].append(r["e2e_committed_txns_per_sec"])
-            if on:
-                fields_on = r
-            if capture:
-                spans = log.events("Span")
-    v_on = float(np.median(runs[True]))
-    v_off = float(np.median(runs[False]))
-    # PAIRED estimator: each round's off/on runs are adjacent, so slow
-    # machine drift cancels within a pair. The GATE takes the BEST
-    # pair (pytest-benchmark's min-of-N rationale: background noise on
-    # a shared host only ever inflates a measurement, so the least
-    # contaminated pair is the closest to the true cost); the median
-    # pair rides along so the artifact shows the spread.
-    pair_overheads = [
-        max(0.0, 1.0 - on_v / max(off_v, 1e-9)) * 100
-        for off_v, on_v in zip(runs[False], runs[True])
-    ]
-    overhead_pct = round(min(pair_overheads), 2)
-    overhead_median_pct = round(float(np.median(pair_overheads)), 2)
-    rep = tracetool.report(spans)
-    hot_spans = rep["hottest_stage"]
-    hot_timers = fields_on.get("hottest_stage")
-    return {
-        "metric": "e2e_tracing_smoke",
-        "value": v_on,
-        "unit": "txns/sec",
-        "vs_baseline": round(v_on / BASELINE_TXNS_PER_SEC, 3),
-        "disabled_txns_per_sec": round(v_off, 1),
-        "tracing_overhead_pct": overhead_pct,
-        "tracing_overhead_median_pct": overhead_median_pct,
-        "overhead_budget_pct": 2.0,
-        "within_budget": overhead_pct <= 2.0,
-        "smoke_rounds": rounds,
-        "tracing_sample_rate": rate,
-        "spans_sampled": fields_on.get("spans_sampled"),
-        "spans_captured": len(spans),
-        "traces_captured": rep["traces"],
-        # critical-path attribution, cross-checked two ways: the span
-        # trees' hottest stage vs the StageStats timers' hottest stage
-        "hottest_edge": rep["hottest_edge"],
-        "hottest_edge_total_ms": rep["hottest_edge_total_ms"],
-        "hottest_stage_spans": hot_spans,
-        "hottest_stage_timers": hot_timers,
-        "attribution_agrees": (
-            None if hot_spans is None or hot_timers is None
-            else hot_spans == hot_timers
-        ),
-        "e2e_backend": backend,
-        "platform": fields_on.get("platform"),
-        "commit_p50_ms": fields_on.get("commit_p50_ms"),
-        "commit_p99_ms": fields_on.get("commit_p99_ms"),
-    }
-
-
 def run_repair_smoke(cpu, seconds=None, rounds=None):
     """BENCH_MODE=repair_smoke: the conflict-management subsystem's
     goodput probe — the contended tpcc e2e with transaction repair +
     abort-aware batch scheduling ON vs the restart-only baseline,
-    interleaved pairs, median committed tx/s each (the same drift-
-    cancelling protocol as metrics_smoke). The ISSUE-6 acceptance ask
+    interleaved pairs, median committed tx/s each (interleaving
+    cancels scheduler drift). The ISSUE-6 acceptance ask
     is ≥3x committed tx/s on this shape; ``speedup_repair`` is that
     number, measured, and the enabled arm's repair/scheduler counters
     ride along so the artifact shows the subsystem actually engaged."""
@@ -2742,7 +2522,7 @@ def run_repair_smoke(cpu, seconds=None, rounds=None):
             # through the standard restart protocol (on_error backoff
             # + fresh GRV + full re-read), repair through the
             # conflict-management subsystem. Interleaved pairs, median
-            # compare (the metrics_smoke drift protocol).
+            # compare.
             kw = {"mode": "tpcc", "seconds": secs,
                   "batch_scheduling": on, "txn_repair": on,
                   "retry_mode": rmode}
@@ -2790,8 +2570,8 @@ def run_read_smoke(cpu=True, seconds=None, rounds=None):
     fdbserver process, a background commit load, and one measuring
     client alternating arms: per-read round-trip of sequential blocking
     ``get()`` vs a window of ``get_async()`` futures multiplexed into
-    ``read_batch`` RPCs. Interleaved pairs, median per arm (the
-    metrics_smoke drift protocol); the ISSUE-11 acceptance ask is ≥3x
+    ``read_batch`` RPCs. Interleaved pairs, median per arm;
+    the ISSUE-11 acceptance ask is ≥3x
     loaded-RTT improvement, reported as ``read_speedup``. The server's
     batch-size bands ride along so the artifact shows the multiplexing
     actually engaged."""
@@ -2937,7 +2717,7 @@ def run_chaos_smoke(cpu, seconds=None, rounds=None, n_chaos_txns=None):
     interleaved pairs of a sync txn loop with the robustness stack ON
     (failure monitor + keepalive pings + per-class deadlines, the
     defaults) vs OFF (monitor knob off, pinger disabled), median
-    throughput each, ≤2% budget — the metrics_smoke protocol, but the
+    throughput each, ≤2% budget — the other smokes' protocol, but the
     workload crosses the RPC transport so per-call deadline/monitor
     bookkeeping is actually on the measured path.
 
@@ -3197,16 +2977,11 @@ def main():
     # interpreter vs the jit scan through the real resolver paths:
     # bit-identical verdict parity, executed-route pallas_kernel_step
     # stamp, pad_waste_pct under the checked-in threshold — all three
-    # gate exit) | metrics_smoke (metrics-registry overhead: enabled vs
-    # disabled ycsb e2e, ≤2% budget) | tracing_smoke (distributed-
-    # tracing overhead at the default 1% sample rate, ≤2% budget, plus
-    # span-tree vs stage-timer critical-path cross-check) |
+    # gate exit) |
     # repair_smoke (conflict repair + abort-aware scheduling vs the
     # restart-only baseline on the contended tpcc shape) |
     # heatmap_smoke (workload-attribution overhead: heatmap kill switch
     # on vs off, ≤2% budget) |
-    # profile_smoke (device-path execution profiler overhead: the
-    # deviceprofile kill switch on vs off, ≤2% budget) |
     # lockdep_smoke (runtime lock-order witness overhead: instrumented
     # vs plain lock factories, ≤2% budget, 0 observed cycles) |
     # faultcov_smoke (runtime fault-coverage witness overhead: FDBError
@@ -3297,30 +3072,11 @@ def main():
         })
         return
 
-    if mode == "metrics_smoke":
-        out = run_metrics_smoke(cpu)
-        watchdog_finish()
-        _emit(out)
-        # the ≤2% budget is a gate, not a log line: a blown budget
-        # exits nonzero so CI trajectories catch the regression
-        if not out["within_budget"]:
-            sys.exit(1)
-        return
-
     if mode == "heatmap_smoke":
         out = run_heatmap_smoke(cpu)
         watchdog_finish()
         _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
-        if not out["within_budget"]:
-            sys.exit(1)
-        return
-
-    if mode == "profile_smoke":
-        out = run_profile_smoke(cpu)
-        watchdog_finish()
-        _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
+        # the ≤2% budget is a GATE: a blown budget exits nonzero
         if not out["within_budget"]:
             sys.exit(1)
         return
@@ -3329,7 +3085,7 @@ def main():
         out = run_health_smoke(cpu)
         watchdog_finish()
         _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
+        # the ≤2% budget is a GATE: a blown budget exits nonzero
         if not out["within_budget"]:
             sys.exit(1)
         return
@@ -3338,7 +3094,7 @@ def main():
         out = run_history_smoke(cpu)
         watchdog_finish()
         _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
+        # the ≤2% budget is a GATE: a blown budget exits nonzero
         if not out["within_budget"]:
             sys.exit(1)
         return
@@ -3347,7 +3103,7 @@ def main():
         out = run_scan_smoke(cpu)
         watchdog_finish()
         _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
+        # the ≤2% budget is a GATE: a blown budget exits nonzero
         if not out["within_budget"]:
             sys.exit(1)
         return
@@ -3378,15 +3134,6 @@ def main():
         # ≤2% budget gate, plus the correctness half: a fired fault
         # site missing from the static FL011 table fails the smoke
         if not out["within_budget"] or out["faultcov_violations"]:
-            sys.exit(1)
-        return
-
-    if mode == "tracing_smoke":
-        out = run_tracing_smoke(cpu)
-        watchdog_finish()
-        _emit(out)
-        # same contract as metrics_smoke: the ≤2% budget is a GATE
-        if not out["within_budget"]:
             sys.exit(1)
         return
 

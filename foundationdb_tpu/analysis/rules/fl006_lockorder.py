@@ -48,6 +48,7 @@ regenerates the ``->`` section and preserves still-live ``<>`` lines.
 
 import ast
 import os
+import re
 
 from foundationdb_tpu.analysis.base import Finding, dotted_name
 
@@ -56,6 +57,12 @@ TITLE = "lock-order"
 PROGRAM = True
 
 LOCKORDER_RELPATH = "analysis/lockorder.txt"
+
+# ``# flowlint: calls(StageStats.add, DeviceProfile.add)`` on a call's
+# line (or the line above) names the tree methods a duck-typed call may
+# reach — a sink handed in as a parameter, which no field type or
+# method index can resolve (``add`` is a container method name)
+_CALLS_RE = re.compile(r"flowlint:\s*calls\(([^)]*)\)")
 
 # obj.m() resolves through the global method index only when <= this
 # many classes define m — generic names resolve nowhere, not everywhere
@@ -121,6 +128,11 @@ class _Analyzer:
         self.info = info
         self.aliases = {}      # local name -> frozenset of lock ids
         self.local_locks = {}  # local name -> lock id (constructed here)
+        self.with_calls = set()  # id() of Calls that are `with` contexts
+        # line -> "Class.m, Class.m" of this file's calls() annotations
+        self.calls_notes = {
+            ln: m.group(1) for ln, comment in info.fm.comments
+            for m in (_CALLS_RE.search(comment),) if m}
         self._collect_locals()
 
     def _collect_locals(self):
@@ -202,9 +214,39 @@ class _Analyzer:
                 return frozenset(ids)
         return frozenset()
 
+    def _ctor_targets(self, cm, call):
+        """``Class(...)`` runs ``__init__``; as the context expression
+        of a ``with`` it also runs ``__enter__`` and ``__exit__``
+        (utils/span.stage: a span's id draw and emission happen
+        there), under the locks held around the statement."""
+        names = ("__init__", "__enter__", "__exit__") \
+            if id(call) in self.with_calls else ("__init__",)
+        hits = (self.model.lookup_method(cm, n) for n in names)
+        return [h[1] for h in hits if h is not None]
+
+    def _annotated_targets(self, call):
+        """Targets named by a ``# flowlint: calls(...)`` annotation on
+        the call's line or the line above; None without one."""
+        line = getattr(call, "lineno", 0)
+        refs = self.calls_notes.get(line) or self.calls_notes.get(line - 1)
+        if refs is None:
+            return None
+        out = []
+        for ref in refs.split(","):
+            cls, _, meth = ref.strip().partition(".")
+            cm = self.model.resolve_class(cls)
+            hit = self.model.lookup_method(cm, meth) \
+                if cm is not None else None
+            if hit is not None:
+                out.append(hit[1])
+        return out
+
     def resolve_call(self, call):
         """AST nodes of the callables this call may reach."""
         model, fm, cm = self.model, self.info.fm, self.info.cm
+        named = self._annotated_targets(call)
+        if named is not None:
+            return named
         fn = call.func
         if isinstance(fn, ast.Name):
             if fn.id in fm.module_funcs:
@@ -217,9 +259,7 @@ class _Analyzer:
             # ClassName(...) runs __init__
             target_cm = model.resolve_class(fn.id)
             if target_cm is not None:
-                hit = model.lookup_method(target_cm, "__init__")
-                if hit is not None:
-                    return [hit[1]]
+                return self._ctor_targets(target_cm, call)
             return []
         if not isinstance(fn, ast.Attribute):
             return []
@@ -244,9 +284,7 @@ class _Analyzer:
                     return [f2.module_funcs[name]]
                 c2 = f2.classes.get(name)
                 if c2 is not None:
-                    hit = model.lookup_method(c2, "__init__")
-                    if hit is not None:
-                        return [hit[1]]
+                    return self._ctor_targets(c2, call)
             return []
         if isinstance(base, ast.Attribute) and not (
                 isinstance(base.value, ast.Name)
@@ -302,6 +340,8 @@ class _Analyzer:
         if isinstance(st, (ast.With, ast.AsyncWith)):
             ids = frozenset()
             for item in st.items:
+                if isinstance(item.context_expr, ast.Call):
+                    self.with_calls.add(id(item.context_expr))
                 self._expr(item.context_expr, held)
                 ids |= self.resolve(item.context_expr)
                 if item.optional_vars is not None and \

@@ -141,7 +141,7 @@ class Knobs:
     # default-ON key sampling: conflict heat charged at the proxy's
     # abort-fabrication site, read/write heat sampled storage-side.
     # BENCH_MODE=heatmap_smoke measures the enabled-vs-kill-switch cost
-    # and gates it at <=2% like metrics_smoke.
+    # and gates it at <=2%.
     workload_sampling: bool = True
     # bounded histogram state: adjacent-range coalescing keeps each
     # heatmap at most this many buckets no matter how long the run

@@ -301,31 +301,28 @@ class Resolver:
             raise ResolverDown()
         self._m_batches.inc()
         self._m_txns.inc(len(txns))
-        # HOST-side scan span (the proxy's ambient trace context): the
-        # dispatch wall for this batch. Never inside a traced/jitted
+        # HOST-side scan span for a sampled batch (the proxy's ambient
+        # trace context); the stages under it — resolver.pack, enqueue,
+        # readback — time the work. Never inside a traced/jitted
         # region — FL004 keeps kernel code pure.
-        ssp = span_mod.from_context("resolver.scan", span_mod.current(),
-                                    txns=len(txns))
-        try:
+        with span_mod.from_context("resolver.scan", span_mod.current(),
+                                   txns=len(txns)):
             return self._resolve_traced(txns, commit_version,
                                         new_window_start)
-        finally:
-            ssp.finish()
 
     def _resolve_traced(self, txns, commit_version, new_window_start):
         if isinstance(txns, FlatTxnBatch):
             return self._resolve_flat(txns, commit_version,
                                       new_window_start)
         if self.backend in ("cpu", "native"):
-            prof = deviceprofile.enabled()
-            pt0 = deviceprofile.now() if prof else 0.0
-            out = self.cset.resolve(txns, commit_version, new_window_start)
-            if prof:
+            with span_mod.stage("resolver.dispatch") as dsp:
+                out = self.cset.resolve(txns, commit_version,
+                                        new_window_start)
+            if deviceprofile.enabled():
                 # host sets pack nothing: slots == live, zero pad waste
                 self.profile.record_dispatch(
                     bucket=1, live_batches=1, live_txns=len(txns),
-                    txn_slots=len(txns),
-                    wall_s=deviceprofile.now() - pt0)
+                    txn_slots=len(txns), wall_s=dsp.seconds)
             return out
         self._maybe_rebase(commit_version)
         # base_version only ever advances to a past window start, so a read
@@ -345,14 +342,14 @@ class Resolver:
         )
         for c in range(0, max(len(live), 1), self.params.txns):
             chunk = live[c : c + self.params.txns]
-            batch = packer.pack(
-                [t for _, t in chunk], self.base_version, commit_version, new_window_start
-            )
-            prof = deviceprofile.enabled()
-            pt0 = deviceprofile.now() if prof else 0.0
-            out = self._step_kernel(resolve_fn, batch, len(chunk),
-                                    commit_version)
-            if prof:
+            with span_mod.stage("resolver.pack", self.profile):
+                batch = packer.pack(
+                    [t for _, t in chunk], self.base_version,
+                    commit_version, new_window_start
+                )
+            out, step_s = self._step_kernel(resolve_fn, batch, len(chunk),
+                                            commit_version)
+            if deviceprofile.enabled():
                 # each chunk is one device step padded to a full
                 # params.txns batch — the single-batch route's pad waste
                 pp = self._fast_params if use_fast else self.params
@@ -361,7 +358,7 @@ class Resolver:
                     txn_slots=pp.txns,
                     transfer_bytes=sum(
                         int(x.nbytes) for x in jax.tree.leaves(batch)),
-                    wall_s=deviceprofile.now() - pt0)
+                    wall_s=step_s)
             if out is None:  # pallas fallback engaged: fenced restart
                 for j in range(len(statuses)):
                     if statuses[j] is None:
@@ -373,16 +370,25 @@ class Resolver:
         return statuses
 
     def _step_kernel(self, resolve_fn, batch, n, commit_version):
-        """One threaded kernel step → statuses[:n], or None when the
-        Pallas fallback engaged (the resolver restarted fenced and the
-        caller must answer TOO_OLD)."""
+        """One threaded kernel step → (statuses[:n], its wall seconds:
+        the dispatch wall, enqueue + readback), or (None, seconds) when
+        the Pallas fallback engaged (the resolver restarted fenced and
+        the caller must answer TOO_OLD)."""
+        # enqueue: the jitted call returning (H2D + launch); readback:
+        # the device wait + D2H of the verdicts
+        enq = span_mod.stage("resolver.enqueue", self.profile)
+        rdb = span_mod.stage("resolver.readback", self.profile)
         try:
-            status, _accepted, self.state = resolve_fn(self.state, batch)
+            with enq:
+                status, _accepted, self.state = resolve_fn(self.state,
+                                                           batch)
             # materialize INSIDE the try: dispatch is async, so a
             # kernel that compiles but faults at runtime only raises
             # here — outside, the fallback would never engage and
             # self.state would hold poisoned arrays
-            return np.asarray(status)[:n].tolist()
+            with rdb:
+                status = np.asarray(status)
+            return status[:n].tolist(), enq.seconds + rdb.seconds
         except Exception as e:
             if (not (self.params.use_pallas or self.params.use_pallas_scan)
                     or resolve_fn is not self._resolve
@@ -390,7 +396,7 @@ class Resolver:
                 raise  # pallas only runs in the full variant; non-JAX
                 # errors (packer bugs …) must not wipe device history
             self._engage_pallas_fallback(commit_version)
-            return None
+            return None, enq.seconds + rdb.seconds
 
     def _engage_pallas_fallback(self, commit_version):
         """A Pallas kernel (ring lane or the fused scan) failed to
@@ -435,19 +441,18 @@ class Resolver:
         host must pre-filter — decodes to TxnRequests and rides the
         legacy path (rare by construction)."""
         if self.backend in ("native", "cpu"):
-            prof = deviceprofile.enabled()
-            pt0 = deviceprofile.now() if prof else 0.0
-            if self.backend == "native":
-                out = self.cset.resolve_flat(flat, commit_version,
-                                             new_window_start)
-            else:
-                out = self.cset.resolve(flat.to_txn_requests(),
-                                        commit_version, new_window_start)
-            if prof:
+            with span_mod.stage("resolver.dispatch") as dsp:
+                if self.backend == "native":
+                    out = self.cset.resolve_flat(flat, commit_version,
+                                                 new_window_start)
+                else:
+                    out = self.cset.resolve(
+                        flat.to_txn_requests(), commit_version,
+                        new_window_start)
+            if deviceprofile.enabled():
                 self.profile.record_dispatch(
                     bucket=1, live_batches=1, live_txns=len(flat),
-                    txn_slots=len(flat),
-                    wall_s=deviceprofile.now() - pt0)
+                    txn_slots=len(flat), wall_s=dsp.seconds)
             return out
         self._maybe_rebase(commit_version)
         cause = self._flat_fallback_cause(flat)
@@ -460,13 +465,12 @@ class Resolver:
         packer, resolve_fn = self._fast if use_fast else (
             self.packer, self._resolve
         )
-        batch = packer.pack_flat(flat, self.base_version, commit_version,
-                                 new_window_start)
-        prof = deviceprofile.enabled()
-        pt0 = deviceprofile.now() if prof else 0.0
-        out = self._step_kernel(resolve_fn, batch, len(flat),
-                                commit_version)
-        if prof:
+        with span_mod.stage("resolver.pack", self.profile):
+            batch = packer.pack_flat(flat, self.base_version,
+                                     commit_version, new_window_start)
+        out, step_s = self._step_kernel(resolve_fn, batch, len(flat),
+                                        commit_version)
+        if deviceprofile.enabled():
             pp = self._fast_params if use_fast else self.params
             self.profile.record_dispatch(
                 bucket=1, live_batches=1, live_txns=len(flat),
@@ -481,7 +485,7 @@ class Resolver:
                              "rw": pp.txns * pp.range_writes},
                 transfer_bytes=sum(
                     int(x.nbytes) for x in jax.tree.leaves(batch)),
-                wall_s=deviceprofile.now() - pt0)
+                wall_s=step_s)
         if out is None:
             return [TOO_OLD] * len(flat)
         self.profile.record_kernel_route(self._kernel_route(use_fast))

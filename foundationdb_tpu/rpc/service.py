@@ -40,6 +40,7 @@ from foundationdb_tpu.rpc.transport import (
     DeadlineExceeded,
     RpcServer,
     connect_any,
+    rpc_class,
 )
 from foundationdb_tpu.utils.backoff import Backoff
 from foundationdb_tpu.txn.futures import FutureRange, FutureValue
@@ -93,6 +94,10 @@ class ClusterService:
 
     def __init__(self, cluster):
         self.cluster = cluster
+        # the RpcServer these handlers are attached to (serve_cluster /
+        # fdbserver set it): its per-class request counters ride the
+        # status document as cluster.rpc
+        self.rpc_server = None
         self._watches = {}  # watch_id -> (Watch, threading.Event, born)
         self._watch_ids = itertools.count(1)
         self._watch_lock = lockdep.lock("ClusterService._watch_lock")
@@ -191,7 +196,10 @@ class ClusterService:
         return dataclasses.asdict(self.cluster.knobs)
 
     def status(self):
-        return self.cluster.status()
+        doc = self.cluster.status()
+        if self.rpc_server is not None:
+            doc["cluster"]["rpc"] = self.rpc_server.stats()
+        return doc
 
     def metrics(self):
         return self.cluster.metrics_status()
@@ -406,6 +414,7 @@ def serve_cluster(cluster, host="127.0.0.1", port=0, max_workers=16,
     server = RpcServer(host, port, service.handlers(),
                        max_workers=max_workers,
                        long_methods={"watch_wait"}, secret=secret)
+    service.rpc_server = server
     # tlog_peek long-polls; it must not occupy the short-RPC pool
     server.add_handlers(LogFeed(cluster).handlers(),
                         long_methods={"tlog_peek"})
@@ -415,28 +424,15 @@ def serve_cluster(cluster, host="127.0.0.1", port=0, max_workers=16,
 
 # ───────────────────────────── client side ───────────────────────────────
 # RPC deadline classes: every method maps to one of the four per-class
-# deadline knobs (rpc_deadline_*_s). Unlisted methods are admin-class —
-# management/status calls tolerate the longest bound. watch_wait blocks
-# server-side in 5s chunks, safely under the admin deadline.
-_RPC_CLASS = {
-    "storage_get": "read",
-    "resolve_selector": "read",
-    "get_range": "read",
-    "read_batch": "read",
-    "ping": "read",
-    "get_read_version": "grv",
-    "commit": "commit",
-    "commit_batch": "commit",
-}
-
-
-def _class_deadline(knobs, rpc_class):
+# deadline knobs (rpc_deadline_*_s) through transport.rpc_class, the
+# table the server's per-class counters share.
+def _class_deadline(knobs, cls):
     return {
         "read": knobs.rpc_deadline_read_s,
         "grv": knobs.rpc_deadline_grv_s,
         "commit": knobs.rpc_deadline_commit_s,
         "admin": knobs.rpc_deadline_admin_s,
-    }[rpc_class]
+    }[cls]
 class _RemoteWatch:
     """Client handle satisfying the Watch surface _WatchHandle polls."""
 
@@ -881,7 +877,7 @@ class RemoteCluster:
 
     def _deadline_for(self, method):
         return _class_deadline(
-            self._effective_knobs(), _RPC_CLASS.get(method, "admin")
+            self._effective_knobs(), rpc_class(method)
         )
 
     def _monitor_enabled(self):
@@ -916,7 +912,7 @@ class RemoteCluster:
                     address=addr, method=method,
                     strikes=client.deadline_strikes).log()
                 client.close()
-            if _RPC_CLASS.get(method, "admin") == "commit":
+            if rpc_class(method) == "commit":
                 raise FDBError.from_name("commit_unknown_result") from e
             raise FDBError.from_name("process_behind") from e
         except (ConnectionLost, OSError) as e:

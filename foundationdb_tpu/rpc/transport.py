@@ -49,6 +49,43 @@ _DEADLINE_TICK_S = 0.05
 # the full deadline again on a link that will never answer
 WEDGED_STRIKE_LIMIT = 3
 
+# RPC classes: every method maps to one of four — the client's per-class
+# deadline knobs (rpc_deadline_*_s, rpc/service.py) and the server's
+# per-class counters (RpcServer.stats) share the table. Unlisted
+# methods are admin-class — management/status calls tolerate the
+# longest bound. watch_wait blocks server-side in 5s chunks, safely
+# under the admin deadline.
+RPC_CLASSES = ("read", "grv", "commit", "admin")
+_RPC_CLASS = {
+    "storage_get": "read",
+    "resolve_selector": "read",
+    "get_range": "read",
+    "read_batch": "read",
+    "ping": "read",
+    "get_read_version": "grv",
+    "commit": "commit",
+    "commit_batch": "commit",
+}
+
+
+def rpc_class(method):
+    return _RPC_CLASS.get(method, "admin")
+
+
+# totals RpcServer keeps per RPC class (integer microseconds in status).
+# ``requests`` counts every request; the four durations are summed over
+# every TIME_EVERY-th request of a class (``timed_requests`` of them),
+# so a mean is <sum> / timed_requests. On the v5e's host the
+# interpreter is the server's bottleneck and a microsecond added to
+# every request costs several of throughput (PERF.md, PR 28). The
+# thread's CPU clock is not read at all: ``time.thread_time`` is a real
+# system call there, 6 µs against a read handler of 36 µs, and two on
+# every request cost a tenth of the ycsb cell's ops_per_s.
+RPC_COUNTERS = ("requests", "timed_requests", "decode_us", "queue_wait_us",
+                "handler_wall_us", "reply_us")
+TIME_EVERY = 4
+_RPC_STAGE = {c: "rpc." + c for c in RPC_CLASSES}
+
 # Chaos transport hook (rpc/chaos.py): when armed, every NEW client
 # socket is wrapped in the seeded fault injector. None on the default
 # path — chaos code is never even imported unless a seed arms it via
@@ -165,6 +202,14 @@ class RpcServer:
     A handler raising FDBError sends the error to the client intact
     (the client re-raises it); any other exception becomes a generic
     remote failure string.
+
+    Every fourth request of a class is stamped on the injected clock at
+    its layer boundaries — frame read, decoded, handler start (on the
+    pool thread), handler end, reply sent — and the differences
+    accumulate per RPC class (:meth:`stats`): what a request cost in
+    decode, in the pool's queue, in its handler and in the reply. Every
+    request is counted, and annotated for the profiler. One locked add
+    a request.
     """
 
     def __init__(self, host, port, handlers, max_workers=16,
@@ -179,9 +224,21 @@ class RpcServer:
         )
         self._listener.settimeout(0.2)
         self.host, self.port = self._listener.getsockname()[:2]
+        self.max_workers = max_workers
         self._pool = ThreadPoolExecutor(
             max_workers=max_workers, thread_name_prefix="rpc-handler"
         )
+        self._stats_lock = lockdep.lock("RpcServer._stats_lock")
+        # per class, in RPC_COUNTERS' order; seconds until stats()
+        self._acc = {c: [0, 0, 0.0, 0.0, 0.0, 0.0] for c in RPC_CLASSES}
+        # requests decoded per class, for the sampling alone: bumped by
+        # every connection thread without a lock (a lost count moves a
+        # sample by one request)
+        # flowlint: shared(sampling phase only; requests are counted under _stats_lock)
+        self._seen = dict.fromkeys(RPC_CLASSES, 0)
+        # flowlint: shared(a maximum; a lost race costs one stale reading)
+        self._queued_high_water = 0
+        self._born = time.monotonic()
         self._long_pool = (
             ThreadPoolExecutor(
                 max_workers=256, thread_name_prefix="rpc-blocking"
@@ -269,6 +326,7 @@ class RpcServer:
                 self._authenticate(sock, send_lock, peer)
             while not self._closed.is_set():
                 frame = _recv_frame(sock)
+                t_recv = span_mod.now()
                 msg = wire.loads(frame)
                 # protocol v5: an optional TRACING frame rides as a 5th
                 # element (the caller's SpanContext); shorter tuples are
@@ -283,10 +341,21 @@ class RpcServer:
                     and method in self.long_methods
                     else self._pool
                 )
+                cls = rpc_class(method)
+                # stamps for the first request of a class and every
+                # fourth after it
+                seen = self._seen[cls]
+                self._seen[cls] = seen + 1
+                timed = seen % TIME_EVERY == 0
                 pool.submit(
                     self._dispatch, sock, send_lock, seq, method, args,
-                    trace_ctx,
+                    trace_ctx, cls, t_recv,
+                    span_mod.now() if timed else None,
                 )
+                if timed and pool is self._pool:
+                    queued = self._queue_depth()
+                    if queued > self._queued_high_water:
+                        self._queued_high_water = queued
         except (ConnectionLost, ConnectionError, OSError, ValueError):
             pass
         finally:
@@ -297,8 +366,15 @@ class RpcServer:
             except OSError:
                 pass
 
-    def _dispatch(self, sock, send_lock, seq, method, args,
-                  trace_ctx=None):
+    def _queue_depth(self):
+        """Requests decoded and not yet running on the short pool: the
+        executor's own queue (counting them here would take two more
+        locked adds a request)."""
+        return self._pool._work_queue.qsize()
+
+    def _dispatch(self, sock, send_lock, seq, method, args, trace_ctx,
+                  cls, t_recv, t_decoded):
+        timed = t_decoded is not None
         prior_ctx = None
         if trace_ctx is not None:
             # install the caller's SpanContext as this handler thread's
@@ -306,24 +382,81 @@ class RpcServer:
             # opens child spans off span.current() without every
             # handler signature growing a tracing parameter
             prior_ctx = span_mod.set_current(tuple(trace_ctx))
+        # rpc.<class>, handler start → reply sent, on this thread: a
+        # stage where its stamps or its span are wanted, else the
+        # profiler annotation alone
+        scope = span_mod.stage if timed or trace_ctx is not None \
+            else span_mod.annotation
+        try:
+            with scope(_RPC_STAGE[cls]) as st:
+                ok, payload = self._handle(method, args)
+                if timed:
+                    t_handled = span_mod.now()
+                self._reply(sock, send_lock, seq, method, ok, payload)
+        finally:
+            if trace_ctx is not None:
+                span_mod.set_current(prior_ctx)
+        acc = self._acc[cls]
+        with self._stats_lock:
+            acc[0] += 1
+            if timed:
+                acc[1] += 1
+                acc[2] += t_decoded - t_recv
+                acc[3] += st.t0 - t_decoded
+                acc[4] += t_handled - st.t0
+                acc[5] += st.t1 - t_handled
+
+    @staticmethod
+    def _remote_failure(method, e):
+        # the client only receives a flattened string — the server
+        # trace is the record with the real type/context (FL005)
+        TraceEvent("RpcHandlerError", severity=SEV_ERROR).detail(
+            method=method, etype=type(e).__name__,
+            error=str(e)[:200]).log()
+        return False, f"{type(e).__name__}: {e}"
+
+    def _handle(self, method, args):
+        """Run the handler → (ok, payload); never raises."""
         try:
             fn = self.handlers.get(method)
             if fn is None:
                 raise KeyError(f"no such endpoint: {method}")
-            result = fn(*args)
-            reply = wire.dumps(("r", seq, True, result))
+            return True, fn(*args)
         except FDBError as e:
-            reply = wire.dumps(("r", seq, False, e))
+            return False, e
         except Exception as e:  # generic remote failure
-            # the client only receives a flattened string — the server
-            # trace is the record with the real type/context (FL005)
-            TraceEvent("RpcHandlerError", severity=SEV_ERROR).detail(
-                method=method, etype=type(e).__name__,
-                error=str(e)[:200]).log()
-            reply = wire.dumps(("r", seq, False, f"{type(e).__name__}: {e}"))
-        finally:
-            if trace_ctx is not None:
-                span_mod.set_current(prior_ctx)
+            return self._remote_failure(method, e)
+
+    def stats(self):
+        """``cluster.rpc`` of the status document: the per-class totals
+        (``<counter>.<class>``), the short pool's size and the deepest
+        its queue has been seen (looked at with every timed request),
+        and this process's CPU and wall time, read
+        now (no hot-path cost): Δcpu/Δwall ≈ 1.0 over a busy interval
+        means the interpreter lock is the machine."""
+        with self._stats_lock:
+            acc = {c: list(v) for c, v in self._acc.items()}
+        # a clock that stepped backwards counts 0, never negative
+        doc = {counter: {c: v[i] if isinstance(v[i], int)
+                         else round(max(0.0, v[i]) * 1e6)
+                         for c, v in acc.items()}
+               for i, counter in enumerate(RPC_COUNTERS)}
+        doc["pool"] = {"workers": self.max_workers,
+                       "queued": self._queue_depth(),
+                       "queued_high_water": self._queued_high_water}
+        doc["process"] = {
+            "cpu_us": round(time.process_time() * 1e6),
+            "wall_us": round((time.monotonic() - self._born) * 1e6),
+        }
+        return doc
+
+    def _reply(self, sock, send_lock, seq, method, ok, payload):
+        """Encode and send; a result the wire cannot carry becomes a
+        generic remote failure, like a handler that raised."""
+        try:
+            reply = wire.dumps(("r", seq, ok, payload))
+        except Exception as e:
+            reply = wire.dumps(("r", seq, *self._remote_failure(method, e)))
         try:
             _send_frame(sock, send_lock, reply)
         except (ConnectionError, OSError):

@@ -170,6 +170,10 @@ class BatchingCommitProxy:
         # latency-band number the <2ms-added-p99 target is gated on
         self._m_e2e = self.metrics.latency("commit_e2e")
         self._m_settled_batches = self.metrics.counter("batches_settled")
+        # how long a drained window's OLDEST submit waited for the
+        # batcher thread (the queue in front of the proxy): one record
+        # per window, from the stamp commit_e2e already takes
+        self._m_wait = self.metrics.latency("batcher_wait")
         self.stages = StageStats(registry=self.metrics)
         self._inflight = deque()  # [(chunks, _PipelinedGroup)] FIFO
         self._inflight_cv = lockdep.condition("BatchingCommitProxy._inflight_cv")
@@ -201,7 +205,7 @@ class BatchingCommitProxy:
                 # stamp the FIRST submit of each batch window only: it
                 # is the oldest — the span _record_span publishes — and
                 # one clock call per window keeps per-txn metric cost
-                # out of the commit hot path (metrics_smoke's 2% budget)
+                # out of the commit hot path
                 fut.born = metrics_mod.now()
             self._pending.append((request, fut))
             self._wake.notify()
@@ -665,11 +669,15 @@ class BatchingCommitProxy:
                     return
             # batch window: let concurrent committers pile in
             if self.interval_s:
-                time.sleep(self.interval_s)
+                with span_mod.stage("batcher.window"):
+                    time.sleep(self.interval_s)
             with self._lock:
                 pending, self._pending = self._pending, []
                 self._first_pending_step = None
             if pending:
+                born = pending[0][1].born
+                if born is not None and metrics_mod.enabled():
+                    self._m_wait.record(max(0.0, metrics_mod.now() - born))
                 try:
                     self._run_batch(pending)
                 except BaseException as e:  # pragma: no cover — last resort

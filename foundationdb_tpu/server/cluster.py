@@ -1354,9 +1354,14 @@ class Cluster:
         # hottest-stage attribution: the commit-pipeline stage with the
         # most TOTAL wall time across the fleet is the critical path an
         # operator should look at first
+        # (the backlog route's four, and the serial route's six —
+        # commit_batch, their parent, would always win and is left out)
         stage_totals = {}
         for reg in commit_regs:
-            for stage in ("pack", "dispatch", "resolve", "apply"):
+            for stage in ("pack", "dispatch", "resolve", "apply",
+                          "commit_build", "commit_resolve",
+                          "commit_assemble", "commit_log_push",
+                          "commit_storage_apply", "commit_report"):
                 s = reg.get_latency(f"stage_{stage}")
                 if s is not None and s.count:
                     stage_totals[stage] = (

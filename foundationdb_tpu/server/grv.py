@@ -56,28 +56,29 @@ class GrvProxy:
             # failure monitor recruits a new generation (ref: GRVs
             # blocking through a master recovery)
             raise err("process_behind")
-        if self.ratekeeper is not None:
-            ok, reason = self.ratekeeper.admit_with_reason(priority, tags)
-            if not ok:
-                # tag-throttled (1213) vs cluster-saturated (1037): both
-                # retryable, but the client (and its operator) should
-                # know WHICH gate closed (ref: GrvProxyTagThrottler)
-                if reason == "tag":
-                    self._m_tag_throttled.inc()
-                    raise err("tag_throttled")
-                self._m_throttled.inc()
-                raise err("process_behind")
-        self.grv_count += 1
-        self._m_grants.inc()
-        if tags:
-            self._note_tag_started(tags)
-        v = self.sequencer.committed_version
-        # a traced request (in-process ambient context or the wire's
-        # tracing frame) gets its grant recorded as a server-side hop
-        ctx = span_mod.current()
-        if ctx is not None:
-            span_mod.emit_span("grv.grant", ctx, version=v,
-                               priority=priority)
+        # the grant as a stage: profiler annotation, and for a traced
+        # request (in-process ambient context or the wire's tracing
+        # frame) a server-side hop span
+        with span_mod.stage("grv.grant", priority=priority) as gsp:
+            if self.ratekeeper is not None:
+                ok, reason = self.ratekeeper.admit_with_reason(priority,
+                                                               tags)
+                if not ok:
+                    # tag-throttled (1213) vs cluster-saturated (1037):
+                    # both retryable, but the client (and its operator)
+                    # should know WHICH gate closed (ref:
+                    # GrvProxyTagThrottler)
+                    if reason == "tag":
+                        self._m_tag_throttled.inc()
+                        raise err("tag_throttled")
+                    self._m_throttled.inc()
+                    raise err("process_behind")
+            self.grv_count += 1
+            self._m_grants.inc()
+            if tags:
+                self._note_tag_started(tags)
+            v = self.sequencer.committed_version
+            gsp.attr(version=v)
         return v
 
     def status(self):
@@ -150,45 +151,44 @@ class BatchingGrvProxy:
             # start HERE, where the tags are still in hand
             self.inner._note_tag_started(tags)
         qkey = "batch" if priority == "batch" else "default"
-        fast_v = None
-        with self._lock:
-            if (
-                not self._closed
-                and self._pending == 0  # covers drained-but-unresolved too
-                and (rk is None or rk.admit(priority))
-            ):
-                # uncontended fast path: no request is ahead of us in ANY
-                # state (queued or mid-round) and the budget has room —
-                # grant inline, no thread handoff. Checking _pending
-                # rather than the raw queues means a fresh arrival can
-                # never steal a refilled token from an older request the
-                # grant loop is currently holding.
-                self.inner.grv_count += 1
-                self.inner._m_grants.inc()
-                self._m_fast.inc()
-                fast_v = self.inner.sequencer.committed_version
-        if fast_v is not None:
-            # span emitted OUTSIDE the grant lock (file sinks write)
-            ctx = span_mod.current()
-            if ctx is not None:
-                span_mod.emit_span("grv.grant", ctx, version=fast_v,
-                                   priority=priority)
-            return fast_v
-        # queued: the span opens at ENQUEUE so its duration is the
-        # grant-queue wait the latency bands measure
-        gsp = span_mod.from_context("grv.grant", span_mod.current())
-        fut = self._make_future(priority)
-        with self._lock:
-            if self._closed:
-                raise err("process_behind")
-            self._queues[qkey].append(fut)
-            self._pending += 1
-            self._wake.notify()
-        fut["event"].wait()
-        if fut["error"] is not None:
-            raise fut["error"]
-        gsp.finish(version=fut["value"], priority=priority, queued=1)
-        return fut["value"]
+        # one stage for both outcomes (profiler annotation; a hop span
+        # for a traced request, finished OUTSIDE the grant lock — file
+        # sinks write): ~0 on the fast path, the grant-queue wait the
+        # latency bands measure when queued
+        with span_mod.stage("grv.grant", priority=priority) as gsp:
+            fast_v = None
+            with self._lock:
+                if (
+                    not self._closed
+                    and self._pending == 0  # drained-but-unresolved too
+                    and (rk is None or rk.admit(priority))
+                ):
+                    # uncontended fast path: no request is ahead of us
+                    # in ANY state (queued or mid-round) and the budget
+                    # has room — grant inline, no thread handoff.
+                    # Checking _pending rather than the raw queues means
+                    # a fresh arrival can never steal a refilled token
+                    # from an older request the grant loop is currently
+                    # holding.
+                    self.inner.grv_count += 1
+                    self.inner._m_grants.inc()
+                    self._m_fast.inc()
+                    fast_v = self.inner.sequencer.committed_version
+            if fast_v is not None:
+                gsp.attr(version=fast_v)
+                return fast_v
+            fut = self._make_future(priority)
+            with self._lock:
+                if self._closed:
+                    raise err("process_behind")
+                self._queues[qkey].append(fut)
+                self._pending += 1
+                self._wake.notify()
+            fut["event"].wait()
+            if fut["error"] is not None:
+                raise fut["error"]
+            gsp.attr(version=fut["value"], queued=1)
+            return fut["value"]
 
     def _grant_loop(self):
         # throttled rounds back off exponentially (cap 20ms) instead of

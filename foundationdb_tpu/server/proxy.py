@@ -25,6 +25,7 @@ from foundationdb_tpu.utils import heatmap as heatmap_mod
 from foundationdb_tpu.utils import lockdep
 from foundationdb_tpu.utils import metrics as metrics_mod
 from foundationdb_tpu.utils import span as span_mod
+from foundationdb_tpu.utils.trace import StageStats
 
 
 class GateTimeout(Exception):
@@ -138,6 +139,11 @@ class CommitProxy:
         # records the wider submit→settle span instead (queue included)
         self.spans_owned_externally = False
         self._m_e2e = self.metrics.latency("commit_e2e")
+        # the serial commit_batch route's stages (utils/span.stage):
+        # commit.batch ⊃ build, resolve, assemble, log_push,
+        # storage_apply, report — sequential, so they sum to the batch;
+        # bands stage_commit_* in this registry
+        self.stages = StageStats(registry=self.metrics)
         # fleet ordering (None when this proxy is the whole fleet)
         self.resolve_gate = resolve_gate
         self.log_gate = log_gate
@@ -326,12 +332,24 @@ class CommitProxy:
             ]
         t0 = None if self.spans_owned_externally \
             or not metrics_mod.enabled() else metrics_mod.now()
+        # ambient trace context for the batch's stage spans and the
+        # hops under them: the first sampled member's commit span is
+        # the parent (over the wire the context arrived inside the
+        # CommitRequest, so the handler thread has no ambient one to
+        # inherit)
+        rctx = span_mod.first_request_context(requests)
+        prior_ctx = span_mod.set_current(rctx) if rctx is not None \
+            else None
         try:
             with self._commit_mu:
-                return self._commit_batch_locked(requests)
+                with span_mod.stage("commit.batch", self.stages,
+                                    txns=len(requests)):
+                    return self._commit_batch_locked(requests)
         except GateTimeout:
             return self._gate_wedged(len(requests))
         finally:
+            if rctx is not None:
+                span_mod.set_current(prior_ctx)
             if t0 is not None:
                 self._note_e2e(t0, len(requests))
 
@@ -511,42 +529,36 @@ class CommitProxy:
             )
             if out is not None:
                 return out
+        with span_mod.stage("commit.build", self.stages):
+            try:
+                prev, cv = self.sequencer.next_commit_versions(1)[0]
+            except SequencerDown:
+                # the kill raced past the entry check (TOCTOU): same
+                # honest 1021 — a raw exception here would strand
+                # batcher futures
+                self._note_abort("commit_unknown_result", len(requests))
+                return [
+                    FDBError.from_name("commit_unknown_result")
+                    for _ in requests
+                ]
+            window = max(
+                0, cv - self.knobs.max_read_transaction_life_versions)
+            # past every admission gate: reorder for fewer self-inflicted
+            # aborts (results are mapped back to request order at return)
+            requests, plan = self._maybe_schedule(requests)
+            try:
+                txns = self._build_txns(requests)
+            except BaseException:
+                # the grant happened but neither gate was consumed: skip
+                # both turns or every successor waits on a turn no one
+                # will take (advisor r4: a wedged gate never self-heals)
+                self._skip_turns_quiet(prev, cv)
+                raise
+        # commit_batch made the first sampled member's context ambient:
+        # the resolver's scan span parents to the resolve stage's
+        traced = span_mod.current() is not None
         try:
-            prev, cv = self.sequencer.next_commit_versions(1)[0]
-        except SequencerDown:
-            # the kill raced past the entry check (TOCTOU): same honest
-            # 1021 — a raw exception here would strand batcher futures
-            self._note_abort("commit_unknown_result", len(requests))
-            return [
-                FDBError.from_name("commit_unknown_result")
-                for _ in requests
-            ]
-        window = max(0, cv - self.knobs.max_read_transaction_life_versions)
-        # past every admission gate: reorder for fewer self-inflicted
-        # aborts (results are mapped back to request order at return)
-        requests, plan = self._maybe_schedule(requests)
-        try:
-            txns = self._build_txns(requests)
-        except BaseException:
-            # the grant happened but neither gate was consumed: skip
-            # both turns or every successor waits on a turn no one
-            # will take (advisor r4: a wedged gate never self-heals)
-            self._skip_turns_quiet(prev, cv)
-            raise
-        # ambient trace context for the resolver's scan span: the first
-        # sampled member's commit span is the parent (over the wire the
-        # context arrived inside the CommitRequest, so the handler
-        # thread has no ambient one to inherit)
-        rctx = span_mod.first_request_context(requests)
-        try:
-            if rctx is not None:
-                prior_ctx = span_mod.set_current(rctx)
-                try:
-                    statuses = self._resolve_ordered(txns, cv, window,
-                                                     prev)
-                finally:
-                    span_mod.set_current(prior_ctx)
-            else:
+            with span_mod.stage("commit.resolve", self.stages):
                 statuses = self._resolve_ordered(txns, cv, window, prev)
         except ResolverDown:
             # resolution never ran: definitively not committed (1020,
@@ -568,7 +580,7 @@ class CommitProxy:
             raise
         results = self._finalize_batch(requests, txns, statuses, cv,
                                        window, prev,
-                                       traced=rctx is not None, plan=plan)
+                                       traced=traced, plan=plan)
         return plan.restore(results) if plan is not None else results
 
     def _resolve_ordered(self, txns, cv, window, prev):
@@ -1101,76 +1113,77 @@ class CommitProxy:
         # per-request scan is skipped (a measured per-batch cost).
         bsp = span_mod.batch_span(requests) if traced else span_mod.NULL
         try:
-            results = []
-            batch_mutations = []
-            batch_conflicts = 0
-            from foundationdb_tpu.core import systemdata
+            with span_mod.stage("commit.assemble", self.stages):
+                results = []
+                batch_mutations = []
+                batch_conflicts = 0
+                from foundationdb_tpu.core import systemdata
 
-            for i, (req, st) in enumerate(zip(requests, statuses)):
-                if st == COMMITTED:
-                    muts = [
-                        substitute_versionstamp(m, cv, batch_order=0, txn_order=i)
-                        if m.op in (Op.SET_VERSIONSTAMPED_KEY, Op.SET_VERSIONSTAMPED_VALUE)
-                        else m
-                        for m in req.mutations
-                    ]
-                    batch_mutations.extend(muts)
-                    if getattr(req, "idempotency_id", None):
-                        # the id row commits ATOMICALLY with the txn's
-                        # mutations — its presence at any later read
-                        # version proves this commit applied (ref:
-                        # idempotencyIdKeys written in the same batch)
-                        batch_mutations.append(Mutation(
-                            Op.SET,
-                            systemdata.idmp_key(req.idempotency_id),
-                            systemdata.pack_version(cv),
-                        ))
-                    results.append(cv)
-                    self._note_tags("committed", getattr(req, "tags", ()))
-                elif st == TOO_OLD:
-                    results.append(FDBError.from_name("transaction_too_old"))
-                    batch_conflicts += 1
-                    self._note_tags("too_old", getattr(req, "tags", ()))
-                else:
-                    self._note_tags("conflicted", getattr(req, "tags", ()))
-                    self._charge_conflict(req)
-                    e = FDBError.from_name("not_committed")
-                    if req.report_conflicting_keys:
-                        e.conflicting_key_ranges = self._conflicting_ranges(
-                            txns[i]
-                        )
-                        # the version whose writes rejected this txn:
-                        # the client repair engine re-reads ONLY the
-                        # conflicting keys at exactly this version —
-                        # its non-conflicting reads are resolver-proven
-                        # unchanged through it (txn/repair.py)
-                        e.conflict_version = cv
-                    results.append(e)
-                    batch_conflicts += 1
+                for i, (req, st) in enumerate(zip(requests, statuses)):
+                    if st == COMMITTED:
+                        muts = [
+                            substitute_versionstamp(m, cv, batch_order=0, txn_order=i)
+                            if m.op in (Op.SET_VERSIONSTAMPED_KEY, Op.SET_VERSIONSTAMPED_VALUE)
+                            else m
+                            for m in req.mutations
+                        ]
+                        batch_mutations.extend(muts)
+                        if getattr(req, "idempotency_id", None):
+                            # the id row commits ATOMICALLY with the txn's
+                            # mutations — its presence at any later read
+                            # version proves this commit applied (ref:
+                            # idempotencyIdKeys written in the same batch)
+                            batch_mutations.append(Mutation(
+                                Op.SET,
+                                systemdata.idmp_key(req.idempotency_id),
+                                systemdata.pack_version(cv),
+                            ))
+                        results.append(cv)
+                        self._note_tags("committed", getattr(req, "tags", ()))
+                    elif st == TOO_OLD:
+                        results.append(FDBError.from_name("transaction_too_old"))
+                        batch_conflicts += 1
+                        self._note_tags("too_old", getattr(req, "tags", ()))
+                    else:
+                        self._note_tags("conflicted", getattr(req, "tags", ()))
+                        self._charge_conflict(req)
+                        e = FDBError.from_name("not_committed")
+                        if req.report_conflicting_keys:
+                            e.conflicting_key_ranges = self._conflicting_ranges(
+                                txns[i]
+                            )
+                            # the version whose writes rejected this txn:
+                            # the client repair engine re-reads ONLY the
+                            # conflicting keys at exactly this version —
+                            # its non-conflicting reads are resolver-proven
+                            # unchanged through it (txn/repair.py)
+                            e.conflict_version = cv
+                        results.append(e)
+                        batch_conflicts += 1
 
-            # expired-id GC rides an ordinary batch (same durability /
-            # replication / DR path as the rows themselves): every
-            # pump_interval batches, clear ids older than RETENTION —
-            # a deliberate multiple of the MVCC window, because a 1021
-            # retry carries a FRESH read version and can arrive long
-            # after the original's window closed (ref: the idempotency
-            # id cleaner retaining ids by AGE, far past the window).
-            # Runs on the next batch AFTER the pump, capped per round.
-            if self._batches_since_pump == 0 and self.commit_count:
-                horizon = max(0, cv - self.IDMP_RETENTION_WINDOWS *
-                              self.knobs.max_read_transaction_life_versions)
-                batch_mutations.extend(self._idmp_expired(horizon))
+                # expired-id GC rides an ordinary batch (same durability /
+                # replication / DR path as the rows themselves): every
+                # pump_interval batches, clear ids older than RETENTION —
+                # a deliberate multiple of the MVCC window, because a 1021
+                # retry carries a FRESH read version and can arrive long
+                # after the original's window closed (ref: the idempotency
+                # id cleaner retaining ids by AGE, far past the window).
+                # Runs on the next batch AFTER the pump, capped per round.
+                if self._batches_since_pump == 0 and self.commit_count:
+                    horizon = max(0, cv - self.IDMP_RETENTION_WINDOWS *
+                                  self.knobs.max_read_transaction_life_versions)
+                    batch_mutations.extend(self._idmp_expired(horizon))
 
-            # Route BEFORE the push so the log stores the per-tag split
-            # (ref: applyMetadataToCommittedTransactions tagging mutations
-            # with storage tags, TLogServer's per-tag streams): storage
-            # workers then peek only their own stream. Full replication
-            # skips tags — every tag's stream IS the full batch.
-            routed = self._route(batch_mutations)
-            tags = None
-            if (self.dd is not None
-                    and self.dd.replication < len(self.storages)):
-                tags = dict(enumerate(routed))
+                # Route BEFORE the push so the log stores the per-tag split
+                # (ref: applyMetadataToCommittedTransactions tagging mutations
+                # with storage tags, TLogServer's per-tag streams): storage
+                # workers then peek only their own stream. Full replication
+                # skips tags — every tag's stream IS the full batch.
+                routed = self._route(batch_mutations)
+                tags = None
+                if (self.dd is not None
+                        and self.dd.replication < len(self.storages)):
+                    tags = dict(enumerate(routed))
         except BaseException:
             # assembly blew up before the ordered section: the version's
             # log turn must still be consumed or successors hang (quiet:
@@ -1228,7 +1241,8 @@ class CommitProxy:
 
         # push even empty batches so storage's version advances with cv
         try:
-            self.tlog.push(cv, batch_mutations, tags=tags)
+            with span_mod.stage("commit.log_push", self.stages):
+                self.tlog.push(cv, batch_mutations, tags=tags)
         except TLogDown:
             # no durability quorum: the would-be-committed txns are in
             # limbo → honest 1021, nothing applied to storage (ref:
@@ -1253,42 +1267,44 @@ class CommitProxy:
         if (self.regions is not None
                 and self.regions.config.satellite_mode == "sync"):
             self.regions.sync_push(cv, batch_mutations)
-        for sid, muts in enumerate(routed):
-            if not self.storages[sid].alive:
-                # a detected-dead storage misses the batch; recruitment
-                # replaces it wholesale (re-ingest from live teammates),
-                # so skipping cannot strand a partial state
-                continue
-            try:
-                self.storages[sid].apply(cv, muts)
-                self.storages[sid].advance_window(window)
-            except Exception:  # NOT BaseException: interrupts must escape
-                # the batch IS committed — the log is durable — so an
-                # apply failure must not fail the commit (a 1021 here
-                # would lie: a retry would pass the idempotency dedupe,
-                # whose lookup reads applied state, and double-commit
-                # into the log). The failed storage's state is suspect
-                # (possibly half-applied): declare it dead so
-                # recruitment replays the log from its durable version,
-                # restoring log↔storage agreement (ref: storage apply
-                # being async from the commit point in the reference).
-                from foundationdb_tpu.utils.trace import TraceEvent
+        with span_mod.stage("commit.storage_apply", self.stages):
+            for sid, muts in enumerate(routed):
+                if not self.storages[sid].alive:
+                    # a detected-dead storage misses the batch; recruitment
+                    # replaces it wholesale (re-ingest from live teammates),
+                    # so skipping cannot strand a partial state
+                    continue
+                try:
+                    self.storages[sid].apply(cv, muts)
+                    self.storages[sid].advance_window(window)
+                except Exception:  # NOT BaseException: interrupts must escape
+                    # the batch IS committed — the log is durable — so an
+                    # apply failure must not fail the commit (a 1021 here
+                    # would lie: a retry would pass the idempotency dedupe,
+                    # whose lookup reads applied state, and double-commit
+                    # into the log). The failed storage's state is suspect
+                    # (possibly half-applied): declare it dead so
+                    # recruitment replays the log from its durable version,
+                    # restoring log↔storage agreement (ref: storage apply
+                    # being async from the commit point in the reference).
+                    from foundationdb_tpu.utils.trace import TraceEvent
 
-                TraceEvent("StorageApplyFailed", severity=40).detail(
-                    storage=sid, version=cv).log()
-                self.storages[sid].kill()
-        if self.change_feeds is not None and batch_mutations:
-            # after the log has the batch (durable order) and before the
-            # version is readable — consumers reading up to a GRV they
-            # observed always see the feed entries for it
-            self.change_feeds.note_commit(cv, batch_mutations)
-        self.sequencer.report_committed(cv)
-        if self.ratekeeper is not None:
-            self.ratekeeper.observe_commit(len(requests), batch_conflicts)
-        self._batches_since_pump += 1
-        if self._batches_since_pump >= self.pump_interval:
-            self._batches_since_pump = 0
-            self._pump_durability(window)
+                    TraceEvent("StorageApplyFailed", severity=40).detail(
+                        storage=sid, version=cv).log()
+                    self.storages[sid].kill()
+        with span_mod.stage("commit.report", self.stages):
+            if self.change_feeds is not None and batch_mutations:
+                # after the log has the batch (durable order) and before the
+                # version is readable — consumers reading up to a GRV they
+                # observed always see the feed entries for it
+                self.change_feeds.note_commit(cv, batch_mutations)
+            self.sequencer.report_committed(cv)
+            if self.ratekeeper is not None:
+                self.ratekeeper.observe_commit(len(requests), batch_conflicts)
+            self._batches_since_pump += 1
+            if self._batches_since_pump >= self.pump_interval:
+                self._batches_since_pump = 0
+                self._pump_durability(window)
         return results
 
     def _conflicting_ranges(self, txn):

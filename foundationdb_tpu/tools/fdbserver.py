@@ -206,6 +206,7 @@ def main(argv=None):
         deviceprofile.enter_process()  # compile cache + build counts
         cluster = build_cluster(args, coordination)
         service = ClusterService(cluster)
+        service.rpc_server = server
         server.add_handlers(service.handlers(), long_methods={"watch_wait"})
         # log-feed endpoints so --join storage-worker processes can pull
         from foundationdb_tpu.rpc.storageworker import LogFeed

@@ -26,9 +26,14 @@ in production the clock is the real wall clock. Everything else is
 integer counters.
 
 Overhead: the module-level ``set_enabled(False)`` kill switch turns
-every ``record_*`` into an early return — ``BENCH_MODE=profile_smoke``
-runs the ycsb e2e both ways (interleaved pairs, median compare) and
-gates at ≤2% overhead, the metrics_smoke protocol.
+every ``record_*`` into an early return. What the profile costs when on
+is the benchmark's to measure (the untraced run against the parent
+commit, the traced pair in PERF.md), not a CPU gate's.
+
+The resolver's host stages reach the profile through
+``utils/span.stage(name, stats=profile)``: :meth:`DeviceProfile.add`
+maps ``resolver.pack`` / ``resolver.enqueue`` / ``resolver.readback``
+to ``pack_wall_ms`` / ``enqueue_wall_ms`` / ``verdict_reduce_wall_ms``.
 """
 
 import os
@@ -52,8 +57,16 @@ FALLBACK_CAUSES = (
 SIDES = ("pr", "pw", "rr", "rw")
 
 
+# utils/span.stage names → the wall each accumulates into
+STAGE_WALLS = {
+    "resolver.pack": "pack_wall_s",
+    "resolver.enqueue": "enqueue_wall_s",
+    "resolver.readback": "verdict_reduce_wall_s",
+}
+
+
 def set_enabled(on):
-    """Process-wide kill switch (the profile_smoke overhead probe)."""
+    """Process-wide kill switch."""
     global _enabled
     _enabled = bool(on)
 
@@ -99,7 +112,13 @@ class DeviceProfile:
         # host->device transfer estimate (sum of packed array nbytes)
         self.transfer_bytes = 0
         # walls (deterministic clock; 0.0 under the sim step clock)
+        # dispatch = all of one kernel step (enqueue + readback); pack =
+        # host packing before it; enqueue = the jitted call returning
+        # (H2D + launch); verdict_reduce = the status readback (device
+        # wait + D2H) — on the mesh route, the cross-lane reduce
         self.dispatch_wall_s = 0.0
+        self.pack_wall_s = 0.0
+        self.enqueue_wall_s = 0.0
         self.verdict_reduce_wall_s = 0.0
         # mesh lanes: accumulated per-lane dispatch wall (hash-sharded
         # mode / legacy host fan-out) OR per-lane routed-entry counts
@@ -211,6 +230,15 @@ class DeviceProfile:
         with self._lock:
             self.verdict_reduce_wall_s += float(wall_s)
 
+    def add(self, stage, seconds):
+        """The ``stats`` sink of ``utils/span.stage``: a resolver host
+        stage's seconds into its wall (:data:`STAGE_WALLS`)."""
+        if not _enabled:
+            return
+        wall = STAGE_WALLS[stage]
+        with self._lock:
+            setattr(self, wall, getattr(self, wall) + float(seconds))
+
     # ── carryover + rollup ──
 
     def absorb(self, other):
@@ -233,6 +261,8 @@ class DeviceProfile:
                 "staging_reuse_misses": other.staging_reuse_misses,
                 "transfer_bytes": other.transfer_bytes,
                 "dispatch_wall_s": other.dispatch_wall_s,
+                "pack_wall_s": other.pack_wall_s,
+                "enqueue_wall_s": other.enqueue_wall_s,
                 "verdict_reduce_wall_s": other.verdict_reduce_wall_s,
                 "lane_walls_s": list(other.lane_walls_s),
                 "lane_entries": list(other.lane_entries),
@@ -259,6 +289,8 @@ class DeviceProfile:
             self.staging_reuse_misses += o["staging_reuse_misses"]
             self.transfer_bytes += o["transfer_bytes"]
             self.dispatch_wall_s += o["dispatch_wall_s"]
+            self.pack_wall_s += o["pack_wall_s"]
+            self.enqueue_wall_s += o["enqueue_wall_s"]
             self.verdict_reduce_wall_s += o["verdict_reduce_wall_s"]
             if len(self.lane_walls_s) < len(o["lane_walls_s"]):
                 self.lane_walls_s.extend(
@@ -323,6 +355,8 @@ class DeviceProfile:
                     hits / max(hits + misses, 1), 3),
                 "transfer_bytes": self.transfer_bytes,
                 "dispatch_wall_ms": round(self.dispatch_wall_s * 1e3, 3),
+                "pack_wall_ms": round(self.pack_wall_s * 1e3, 3),
+                "enqueue_wall_ms": round(self.enqueue_wall_s * 1e3, 3),
                 "verdict_reduce_wall_ms": round(
                     self.verdict_reduce_wall_s * 1e3, 3),
                 "lanes": max(len(lanes), len(entries)),
@@ -405,7 +439,10 @@ def compile_log():
 def enter_process():
     """Called once by each process entry point (chip_smoke.py's
     children, tools/fdbserver.py, bench.py) before JAX builds anything:
-    place the persistent compile cache and start counting builds.
+    place the persistent compile cache, start counting builds, and hand
+    ``utils/span.stage`` the profiler's annotation, so that a
+    ``jax.profiler`` trace of this process carries ``fdb.<stage>``
+    events on its host planes, on the clock of its device planes.
 
     The cache goes where ``JAX_COMPILATION_CACHE_DIR`` says; only when
     that is unset does code name a place, ``<checkout>/.jax_cache`` —
@@ -423,6 +460,9 @@ def enter_process():
         jax.monitoring.register_event_duration_secs_listener(
             _compile_log._on_duration)
         jax.monitoring.register_event_listener(_compile_log._on_event)
+    from foundationdb_tpu.utils import span as span_mod
+
+    span_mod.set_annotator(jax.profiler.TraceAnnotation)
 
 
 def merged_snapshot(profiles):

@@ -19,8 +19,9 @@ clock. Two same-seed sims therefore emit byte-identical Span streams.
 Overhead: with tracing off (``sample_rate`` 0 and no per-transaction
 force) every call site degrades to :data:`NULL` — a shared no-op span
 whose methods return immediately — so the commit hot path pays a couple
-of attribute calls per transaction (``BENCH_MODE=tracing_smoke`` gates
-the enabled-at-default-rate cost at ≤2%). Promotion of UNSAMPLED
+of attribute calls per transaction (what the instrumentation costs is
+measured by the benchmark: the untraced run against the parent commit,
+and the traced pair in PERF.md). Promotion of UNSAMPLED
 traffic follows the metrics subsystem's per-window lesson (PR 4: even
 one extra clock stamp per transaction busts a 2% budget at tens of
 thousands of commits/sec):
@@ -35,11 +36,20 @@ thousands of commits/sec):
   (:func:`slow_window_span` — no new clock reads anywhere).
 
 Full hop-level trees come from sampled or forced transactions.
+
+:class:`stage` is the one timing primitive of the served request path:
+one pair of stamps around a piece of host work feeds a stage timer
+(``StageStats`` → ``stage_*`` latency bands in status json), a child
+span under a sampled ambient context, and — in a process whose entry
+point installed one (:func:`set_annotator`) — a profiler annotation
+``fdb.<name>`` on the host plane of the same trace as the device planes.
 """
 
+import contextlib
 import threading
 
 from foundationdb_tpu.core import deterministic
+from foundationdb_tpu.utils import metrics as metrics_mod
 from foundationdb_tpu.utils import trace as trace_mod
 
 # named deterministic streams: a seeded sim mints identical ids and
@@ -323,6 +333,112 @@ def emit_span(name, ctx, begin=None, end=None, **attrs):
         sp.begin = begin
     sp.finish(end=end, **attrs)
     return sp
+
+
+# ── the stage primitive ──────────────────────────────────────────────
+# The profiler sink: a factory ``name -> context manager`` installed by
+# a process entry point (utils/deviceprofile.enter_process hands in
+# jax.profiler.TraceAnnotation). None everywhere else — clients, the
+# simulator and rpc/ never import JAX for this.
+_annotator = None
+ANNOTATION_PREFIX = "fdb."
+
+
+def set_annotator(factory):
+    """Install (or with None remove) the profiler-annotation factory;
+    returns the prior one so tests restore it."""
+    global _annotator
+    prior, _annotator = _annotator, factory
+    return prior
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name):
+    """The profiler sink of :class:`stage` alone: ``with
+    annotation(name):`` is ``fdb.<name>`` on the host plane when an
+    annotator is installed, and nothing otherwise. For a call site as
+    hot as the RPC handler (every request), which takes the stage's
+    stamps for one request in four only."""
+    f = _annotator
+    return _NO_ANNOTATION if f is None else f(ANNOTATION_PREFIX + name)
+
+
+class stage:
+    """``with stage(name, stats, **attrs):`` — ONE pair of stamps off
+    the injected clock around a piece of HOST work (never inside a
+    jitted function, FL004), fed to three sinks:
+
+    1. ``stats.add(name, seconds)`` when ``stats`` is given (a
+       ``StageStats``, or the resolver's ``DeviceProfile``), behind the
+       metrics kill switch;
+    2. a child :class:`Span` named ``name`` when a SAMPLED context is
+       ambient — it is the ambient context while the stage is open, so
+       nested stages and hop spans parent to it; nothing is built
+       otherwise;
+    3. a profiler annotation ``fdb.<name>`` when an annotator is
+       installed (:func:`set_annotator`).
+
+    With no sampled context and no annotator the cost is two clock
+    reads and one locked add. After exit ``t0``/``t1``/``seconds`` hold
+    the stamps, for a caller that feeds its own band or counter from
+    the same interval (tlog_push, storage_apply, the rpc counters)."""
+
+    __slots__ = ("name", "stats", "attrs", "t0", "t1", "_span", "_prior",
+                 "_ann")
+
+    def __init__(self, name, stats=None, **attrs):
+        self.name = name
+        self.stats = stats
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def seconds(self):
+        return max(0.0, self.t1 - self.t0)
+
+    def __enter__(self):
+        ann = _annotator
+        if ann is not None:
+            ann = ann(ANNOTATION_PREFIX + self.name)
+            ann.__enter__()
+        self._ann = ann
+        ctx = getattr(_tls, "ctx", None)
+        self.t0 = t0 = now()
+        if ctx is not None and ctx[2]:
+            sp = Span(self.name, trace_id=ctx[0], parent_id=ctx[1],
+                      begin=t0)
+            if self.attrs:
+                sp.attrs_d = dict(self.attrs)
+            self._span = sp
+            self._prior = ctx
+            _tls.ctx = sp.context()
+        else:
+            self._span = None
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = t1 = now()
+        sp = self._span
+        if sp is not None:
+            _tls.ctx = self._prior
+            if exc is not None:
+                sp.attr(error=str(exc)[:200])
+            sp.finish(end=t1)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self.stats is not None and metrics_mod.enabled():
+            # flowlint: calls(StageStats.add, DeviceProfile.add)
+            self.stats.add(self.name, max(0.0, t1 - self.t0))
+        return False
+
+    def attr(self, **kw):
+        """Attributes known only once the work is done (a granted
+        version); they ride the span, when there is one."""
+        if self._span is not None:
+            self._span.attr(**kw)
+        return self
 
 
 def first_request_context(requests):

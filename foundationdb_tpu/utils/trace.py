@@ -230,8 +230,10 @@ class StageStats:
         if self._registry is not None:
             band = self._bands.get(stage)
             if band is None:
+                # a dotted stage name (utils/span.stage: "commit.build")
+                # bands as stage_commit_build
                 band = self._bands[stage] = self._registry.latency(
-                    f"stage_{stage}"
+                    "stage_" + stage.replace(".", "_")
                 )
             band.record(seconds)
 

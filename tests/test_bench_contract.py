@@ -283,26 +283,6 @@ def test_e2e_line_folds_proxies_and_platform():
     assert fields["pad_waste_pct"] == 0.0
 
 
-def test_metrics_smoke_contract():
-    """BENCH_MODE=metrics_smoke: the overhead probe emits the budget
-    fields the trajectory tracks, and the enabled run carries live
-    commit bands. One short round here — the unit test checks the
-    contract, the bench run owns the statistically serious comparison."""
-    out = bench.run_metrics_smoke(cpu=True, seconds=0.5, rounds=1)
-    for key in ("value", "vs_baseline", "disabled_txns_per_sec",
-                "metrics_overhead_pct", "overhead_budget_pct",
-                "within_budget", "commit_p50_ms", "commit_p99_ms",
-                "grv_p99_ms"):
-        assert key in out, key
-    assert out["metric"] == "e2e_metrics_smoke"
-    assert out["overhead_budget_pct"] == 2.0
-    assert out["commit_p99_ms"] > 0  # the enabled arm recorded spans
-    # the disabled arm really disabled the registry (kill switch back on)
-    from foundationdb_tpu.utils import metrics as metrics_mod
-
-    assert metrics_mod.enabled()
-
-
 def test_health_smoke_contract():
     """BENCH_MODE=health_smoke: the cluster-doctor overhead probe emits
     the budget fields plus the probe-band/recovery/verdict gauges from
@@ -427,30 +407,6 @@ def test_heatmap_smoke_contract():
     assert heatmap_mod.enabled()
 
 
-def test_profile_smoke_contract():
-    """BENCH_MODE=profile_smoke: the device-profiler overhead probe
-    emits the budget fields plus the profiler gauges from the enabled
-    arm, and restores the kill switch. One short round checks the
-    contract; the bench run owns the statistically serious
-    comparison."""
-    out = bench.run_profile_smoke(cpu=True, seconds=0.5, rounds=1)
-    for key in ("value", "vs_baseline", "disabled_txns_per_sec",
-                "profile_overhead_pct", "overhead_budget_pct",
-                "within_budget", "pad_waste_pct", "bucket_histogram",
-                "recompiles", "fallback_causes", "lane_skew_pct",
-                "device_dispatches", "staging_reuse_rate",
-                "commit_p50_ms", "commit_p99_ms"):
-        assert key in out, key
-    assert out["metric"] == "e2e_profile_smoke"
-    assert out["overhead_budget_pct"] == 2.0
-    # the enabled arm really profiled: dispatches flowed end to end
-    assert out["device_dispatches"] > 0
-    # the probe restored the kill switch (profiling stays default-on)
-    from foundationdb_tpu.utils import deviceprofile as dev_mod
-
-    assert dev_mod.enabled()
-
-
 def test_lockdep_smoke_contract():
     """BENCH_MODE=lockdep_smoke: the runtime lock-order witness
     overhead probe emits the budget fields plus the witness gauges
@@ -501,41 +457,6 @@ def test_faultcov_smoke_contract():
 
     assert not faultcov.enabled()
     assert faultcov.fired() == frozenset()
-
-
-def test_tracing_smoke_contract():
-    """BENCH_MODE=tracing_smoke: the tracing-overhead probe emits the
-    budget fields plus the span-tree vs stage-timer critical-path
-    cross-check. One short round checks the contract; the bench run
-    owns the statistically serious comparison."""
-    out = bench.run_tracing_smoke(cpu=True, seconds=0.5, rounds=1)
-    for key in ("value", "vs_baseline", "disabled_txns_per_sec",
-                "tracing_overhead_pct", "tracing_overhead_median_pct",
-                "overhead_budget_pct",
-                "within_budget", "tracing_sample_rate", "spans_sampled",
-                "spans_captured", "traces_captured", "hottest_edge",
-                "hottest_stage_spans", "hottest_stage_timers",
-                "attribution_agrees"):
-        assert key in out, key
-    assert out["metric"] == "e2e_tracing_smoke"
-    assert out["overhead_budget_pct"] == 2.0
-    assert out["tracing_sample_rate"] == 0.01
-    # the enabled arm really sampled: spans were counted and captured
-    assert out["spans_sampled"] >= 0
-    assert out["spans_captured"] >= out["traces_captured"]
-
-
-def test_tracing_smoke_spans_actually_flow():
-    """At a forced 100% sample rate even a tiny run must capture spans
-    and produce a stage attribution that matches a real stage name."""
-    out = bench.run_tracing_smoke(cpu=True, seconds=0.4, rounds=1,
-                                  rate=1.0)
-    assert out["spans_sampled"] > 0
-    assert out["spans_captured"] > 0
-    assert out["hottest_stage_spans"] in ("pack", "dispatch", "resolve",
-                                          "apply")
-    assert out["hottest_stage_timers"] in ("pack", "dispatch", "resolve",
-                                           "apply")
 
 
 def test_repair_smoke_contract():

@@ -4,6 +4,7 @@ throttled requests queue until the budget refills, they are not bounced).
 """
 
 import threading
+import time
 
 import pytest
 
@@ -90,7 +91,16 @@ def test_throttled_grvs_delay_not_reject():
     c = Cluster(commit_pipeline="thread", target_tps=300, **TEST_KNOBS)
     db = c.database()
     rk = c.ratekeeper
-    rk._tokens = 0  # drained: the next window must wait for refill
+    # drained, and the ratekeeper's own clock held still: the bucket
+    # cannot refill while the clients start, so whichever thread is
+    # scheduled first, its window MUST wait (on a free-running clock
+    # 300 tokens/s refill before thirty threads are up, and under a
+    # loaded machine nothing ever waited)
+    frozen = rk.clock()
+    rk.clock = lambda: frozen
+    with rk._mu:
+        rk._tokens = 0
+        rk._last_refill = frozen
     results, errors = [], []
 
     def client():
@@ -102,8 +112,14 @@ def test_throttled_grvs_delay_not_reject():
     threads = [threading.Thread(target=client) for _ in range(30)]
     for t in threads:
         t.start()
+    deadline = time.monotonic() + c.grv_proxy.max_wait_s / 2
+    while c.grv_proxy.delayed_count == 0 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    delayed_while_held = c.grv_proxy.delayed_count
+    rk.clock = time.monotonic  # thaw: the refill resumes, all are served
     for t in threads:
         t.join()
+    assert delayed_while_held > 0, "nothing waited on a bucket held empty"
     assert not errors, errors[:2]
     assert len(results) == 30  # everyone was served, just later
     assert c.grv_proxy.delayed_count > 0, "nothing ever waited"
