@@ -324,9 +324,14 @@ class BatchPacker:
         self.codec = KeyCodec(num_limbs=params.key_width - 1)
         self._native = None
         self._empty = None  # cached zero-txn pad batch (pack_empty)
-        self._flat_rings = {}  # B → list of reusable staging dicts
+        self._flat_rings = {}  # B → list of reusable staging sets
         self._flat_ring_next = {}  # B → next slot index
-        self._zero_hash = None  # fnv of an all-zero key row (cached)
+        # fnv of an all-zero key row: what a pad entry's hash lane holds
+        self._zero_hash = fnv_hash_np(
+            np.zeros((1, params.key_width), np.uint32))[0]
+        # one limb row as ONE element, and arange, cached (_iota_to)
+        self._row = np.dtype((np.void, 4 * params.key_width))
+        self._iota = np.arange(4 * params.txns)
         self.flat_reuse_hits = 0
         self.flat_reuse_misses = 0
         # device-path profiler hook (utils/deviceprofile.py): the
@@ -354,26 +359,38 @@ class BatchPacker:
             and flat.rwc.max(initial=0) <= p.range_writes
         )
 
-    def _flat_staging(self, B):
-        """A zeroed staging set of stacked (B, T, …) arrays from the
-        per-shape reuse ring. Reuse (a fill(0) instead of eleven fresh
-        allocations per group) is the hit the pack-stage counters
-        report."""
+    def _iota_to(self, n):
+        """``arange(n)`` as a view of one cached array. On the thread
+        that dispatches, every call that gives the interpreter lock up
+        is a wait behind the request threads, and ``np.arange`` gives it
+        up at any size — as a fancy store through several index arrays
+        does, which is why the flat pack stores through ONE flat index
+        into 1-D views (numpy keeps the lock there below 500 entries)."""
+        if n > len(self._iota):
+            self._iota = np.arange(2 * n)
+        return self._iota[:n]
+
+    def _flat_staging(self, B, n_txns):
+        """A staging set from the per-shape reuse ring, about to take
+        ``n_txns[b]`` transactions in batch row ``b``: the stacked
+        (B, T, …) arrays, every slot in its pad state (zero key,
+        ``zero_hash``, bucket 0, false mask), and a 1-D view of each to
+        store through (a key array's elements are whole limb rows).
+        Arrival order fills txn slots ``0 … n-1``, so a set remembers one
+        count a batch row (``dirty``) and a reused set resets only the
+        rows its last pack wrote: the work follows the live rows, not
+        ``params.txns``."""
         p = self.params
         ring = self._flat_rings.get(B)
         if ring is None:
             ring = self._flat_rings[B] = []
             self._flat_ring_next[B] = 0
-        zero_hash = self._zero_hash
-        if zero_hash is None:
-            zero_hash = self._zero_hash = fnv_hash_np(
-                np.zeros((1, self.params.key_width), np.uint32)
-            )[0]
         if len(ring) < self.STAGING_RING:
             self.flat_reuse_misses += 1
             if self.profile is not None:
                 self.profile.record_staging(hit=False)
             T, W = p.txns, p.key_width
+            zero_hash = self._zero_hash
             bufs = {
                 "rv": np.zeros((B, T), np.uint32),
                 "txn_mask": np.zeros((B, T), np.bool_),
@@ -400,20 +417,37 @@ class BatchPacker:
                 "cv": np.zeros(B, np.uint32),
                 "nws": np.zeros(B, np.uint32),
             }
-            ring.append(bufs)
-            return bufs
-        i = self._flat_ring_next[B]
-        self._flat_ring_next[B] = (i + 1) % len(ring)
-        self.flat_reuse_hits += 1
+            flat = {
+                name: (a.reshape(a.size // W, W).view(self._row)
+                       if a.ndim == 4 else a).reshape(-1)
+                for name, a in bufs.items()
+            }
+            # what a reset restores: every non-empty per-txn array (cv /
+            # nws are fully overwritten by each pack) with its pad value
+            pads = [
+                (a, zero_hash if name in ("pr_hash", "pw_hash") else 0)
+                for name, a in bufs.items()
+                if a.size and name not in ("cv", "nws")
+            ]
+            dirty = [0] * B
+            ring.append((bufs, flat, pads, dirty))
+        else:
+            i = self._flat_ring_next[B]
+            self._flat_ring_next[B] = (i + 1) % len(ring)
+            self.flat_reuse_hits += 1
+            if self.profile is not None:
+                self.profile.record_staging(hit=True)
+            bufs, flat, pads, dirty = ring[i]
+            for b, n in enumerate(dirty):
+                if n:
+                    for a, pad in pads:
+                        a[b, :n] = pad
         if self.profile is not None:
-            self.profile.record_staging(hit=True)
-        bufs = ring[i]
-        for name, a in bufs.items():
-            if name in ("pr_hash", "pw_hash"):
-                a.fill(zero_hash)  # the hash of an all-zero key row
-            elif name not in ("cv", "nws"):  # fully overwritten below
-                a.fill(0)
-        return bufs
+            self.profile.record_pack_rows(sum(dirty) + sum(n_txns))
+        # marked before the scatter: a pack that raises halfway leaves
+        # no row dirty beyond its mark
+        dirty[:] = n_txns + [0] * (B - len(n_txns))
+        return bufs, flat
 
     def pack_flat_group(self, flats, metas, base_version, B=None):
         """Pack a whole backlog group of FlatTxnBatches into ONE stacked
@@ -422,7 +456,8 @@ class BatchPacker:
         each batch with :meth:`pack` and ``np.stack``-ing, without a
         single per-transaction Python step: blob bytes become limb rows
         with one frombuffer per lane, slot indices come from cumsums,
-        and hashing/bucketing run once over the stacked arrays.
+        and hashing/bucketing run over those compact live rows before
+        one scatter stores them — no pass touches a pad slot.
 
         ``metas``: [(commit_version, new_window_start)] per flat batch;
         pads inherit the last entry (matching the legacy pad template).
@@ -434,25 +469,20 @@ class BatchPacker:
         nb = len(flats)
         if B is None:
             B = nb
-        bufs = self._flat_staging(B)
         u32 = np.uint32
-        # group-GLOBAL scatter: one index build + one fancy-index store
-        # per lane for the whole backlog, however many batches it holds
-        # (per-batch loops were the next-largest pack cost after the
-        # dispatch itself). b_of/t_of map a global txn row to its
-        # (batch, txn-lane) slot; entry rows index through them.
+        # group-GLOBAL scatter: one index build + one store per lane for
+        # the whole backlog, however many batches it holds (per-batch
+        # loops were the next-largest pack cost after the dispatch
+        # itself)
+        lens = [len(f) for f in flats]
         if nb == 1:
             f = flats[0]
-            n_txns = np.array([len(f)], dtype=np.int64)
             rv_all = f.rv
             cat = (
                 (f.prc, f.pwc, f.rrc, f.rwc),
                 (f.pr_blob, f.pw_blob, f.rr_blob, f.rw_blob),
             )
         else:
-            n_txns = np.fromiter(
-                (len(f) for f in flats), np.int64, count=nb
-            )
             rv_all = np.concatenate([f.rv for f in flats])
             cat = (
                 tuple(
@@ -465,54 +495,53 @@ class BatchPacker:
                 ),
             )
         (prc, pwc, rrc, rwc), (pr_blob, pw_blob, rr_blob, rw_blob) = cat
-        b_of = np.repeat(np.arange(nb), n_txns)
-        _, t_of = _slots(n_txns)
+        bufs, flat = self._flat_staging(B, lens)
+        # slot[g] = b·T + t: the flat txn slot of global txn row g
+        n_txns = np.array(lens, dtype=np.int64)
+        slot = self._iota_to(len(rv_all)) + np.repeat(
+            self._iota_to(nb) * p.txns - (np.cumsum(n_txns) - n_txns),
+            n_txns)
+
+        def entry_slots(counts, lanes):
+            """(b·T + t)·lanes + i of every op, from the per-txn op
+            counts: ops of a txn take its lanes 0 … count-1 in order."""
+            starts = np.cumsum(counts) - counts
+            return np.repeat(slot * lanes - starts, counts) + self._iota_to(
+                int(counts.sum()))
+
         if len(rv_all):
-            bufs["rv"][b_of, t_of] = np.clip(
+            flat["rv"][slot] = np.clip(
                 rv_all - base_version, 0, 0xFFFFFFFF
             ).astype(u32)
-            bufs["txn_mask"][b_of, t_of] = True
+            flat["txn_mask"][slot] = True
         L = p.key_width - 1
-        if len(pr_blob):
-            t, i = _slots(prc)
-            bufs["pr_key"][b_of[t], t_of[t], i] = flatpack.point_limbs(
-                pr_blob, L)
-            bufs["pr_mask"][b_of[t], t_of[t], i] = True
-        if len(pw_blob):
-            t, i = _slots(pwc)
-            bufs["pw_key"][b_of[t], t_of[t], i] = flatpack.point_limbs(
-                pw_blob, L)
-            bufs["pw_mask"][b_of[t], t_of[t], i] = True
-        if len(rr_blob):
-            t, i = _slots(rrc)
-            lo, hi = flatpack.range_limbs(rr_blob, L)
-            bufs["rr_b"][b_of[t], t_of[t], i] = lo
-            bufs["rr_e"][b_of[t], t_of[t], i] = hi
-            bufs["rr_mask"][b_of[t], t_of[t], i] = True
-        if len(rw_blob):
-            t, i = _slots(rwc)
-            lo, hi = flatpack.range_limbs(rw_blob, L)
-            bufs["rw_b"][b_of[t], t_of[t], i] = lo
-            bufs["rw_e"][b_of[t], t_of[t], i] = hi
-            bufs["rw_mask"][b_of[t], t_of[t], i] = True
+        for side, counts, blob, lanes in (
+                ("pr", prc, pr_blob, p.point_reads),
+                ("pw", pwc, pw_blob, p.point_writes)):
+            if len(blob):
+                at = entry_slots(counts, lanes)
+                rows = flatpack.point_limbs(blob, L)
+                flat[side + "_key"][at] = rows.view(self._row).reshape(-1)
+                flat[side + "_hash"][at] = fnv_hash_np(rows)
+                flat[side + "_bucket"][at] = bucket_of(rows, p.bucket_bits)
+                flat[side + "_mask"][at] = True
+        for side, counts, blob, lanes in (
+                ("rr", rrc, rr_blob, p.range_reads),
+                ("rw", rwc, rw_blob, p.range_writes)):
+            if len(blob):
+                at = entry_slots(counts, lanes)
+                lo, hi = flatpack.range_limbs(blob, L)
+                flat[side + "_b"][at] = lo.view(self._row).reshape(-1)
+                flat[side + "_e"][at] = hi.view(self._row).reshape(-1)
+                flat[side + "_lo"][at] = bucket_of(lo, p.bucket_bits)
+                flat[side + "_hi"][at] = bucket_of(hi, p.bucket_bits)
+                flat[side + "_mask"][at] = True
         for b, (cv, ws) in enumerate(metas):
             bufs["cv"][b] = u32(cv - base_version)
             bufs["nws"][b] = u32(max(0, ws - base_version))
         if nb < B:  # pads share the last batch's version scalars
             bufs["cv"][nb:] = bufs["cv"][nb - 1] if nb else 0
             bufs["nws"][nb:] = bufs["nws"][nb - 1] if nb else 0
-        # hash/bucket only the LIVE batches: pad rows already hold the
-        # all-zero-key constants (zero_hash / bucket 0) from staging
-        bufs["pr_hash"][:nb] = fnv_hash_np(bufs["pr_key"][:nb])
-        bufs["pr_bucket"][:nb] = bucket_of(bufs["pr_key"][:nb],
-                                           p.bucket_bits)
-        bufs["pw_hash"][:nb] = fnv_hash_np(bufs["pw_key"][:nb])
-        bufs["pw_bucket"][:nb] = bucket_of(bufs["pw_key"][:nb],
-                                           p.bucket_bits)
-        bufs["rr_lo"][:nb] = bucket_of(bufs["rr_b"][:nb], p.bucket_bits)
-        bufs["rr_hi"][:nb] = bucket_of(bufs["rr_e"][:nb], p.bucket_bits)
-        bufs["rw_lo"][:nb] = bucket_of(bufs["rw_b"][:nb], p.bucket_bits)
-        bufs["rw_hi"][:nb] = bucket_of(bufs["rw_e"][:nb], p.bucket_bits)
         return ResolveBatch(
             rv=bufs["rv"], txn_mask=bufs["txn_mask"],
             pr_hash=bufs["pr_hash"], pr_key=bufs["pr_key"],

@@ -98,6 +98,9 @@ class DeviceProfile:
         self.batch_slots = 0
         self.txns_live = 0
         self.txn_slots = 0
+        # txn rows the flat pack reset plus txn rows it wrote: follows
+        # the live rows, not txn_slots (resolver/packing.py)
+        self.pack_rows_touched = 0
         self.bucket_histogram = {}  # str(B) -> dispatches at bucket B
         # per-side entry occupancy: live vs padded slots
         self.entries_live = {s: 0 for s in SIDES}
@@ -196,6 +199,12 @@ class DeviceProfile:
             else:
                 self.staging_reuse_misses += 1
 
+    def record_pack_rows(self, rows):
+        if not _enabled:
+            return
+        with self._lock:
+            self.pack_rows_touched += int(rows)
+
     def record_lanes(self, walls_s):
         """Per-lane dispatch walls for ONE mesh dispatch (index = lane,
         stable device order) — accumulated so skew reflects the run."""
@@ -252,6 +261,7 @@ class DeviceProfile:
                 "batch_slots": other.batch_slots,
                 "txns_live": other.txns_live,
                 "txn_slots": other.txn_slots,
+                "pack_rows_touched": other.pack_rows_touched,
                 "bucket_histogram": dict(other.bucket_histogram),
                 "entries_live": dict(other.entries_live),
                 "entry_slots": dict(other.entry_slots),
@@ -276,6 +286,7 @@ class DeviceProfile:
             self.batch_slots += o["batch_slots"]
             self.txns_live += o["txns_live"]
             self.txn_slots += o["txn_slots"]
+            self.pack_rows_touched += o["pack_rows_touched"]
             for k, v in o["bucket_histogram"].items():
                 self.bucket_histogram[k] = (
                     self.bucket_histogram.get(k, 0) + v)
@@ -341,6 +352,7 @@ class DeviceProfile:
                 "batch_slots": self.batch_slots,
                 "txns_live": txns_live,
                 "txn_slots": txn_slots,
+                "pack_rows_touched": self.pack_rows_touched,
                 "pad_waste_pct": pad_waste,
                 "bucket_histogram": dict(sorted(
                     self.bucket_histogram.items(),

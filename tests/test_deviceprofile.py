@@ -109,6 +109,98 @@ def test_merged_snapshot_rolls_up_a_fleet():
     assert agg["fallback_causes"]["over_capacity"] == 1
 
 
+# ───────── pack_rows_touched: the flat pack follows live rows ─────────
+def test_pack_rows_touched_absorbs_both_ways_and_merges():
+    used, fresh = DeviceProfile("resolver", 0), DeviceProfile("resolver", 1)
+    used.record_pack_rows(18)
+    used.record_pack_rows(9)
+    assert used.snapshot()["pack_rows_touched"] == 27
+    assert fresh.snapshot()["pack_rows_touched"] == 0
+    agg = merged_snapshot([used, fresh])
+    assert agg["pack_rows_touched"] == 27
+    fresh.absorb(used)  # a respawn's new profile takes the history
+    assert fresh.snapshot()["pack_rows_touched"] == 27
+    used.absorb(DeviceProfile("resolver", 2))  # and an empty one adds 0
+    assert used.snapshot()["pack_rows_touched"] == 27
+    deviceprofile.set_enabled(False)
+    try:
+        used.record_pack_rows(5)  # gated like every capture site
+        used.absorb(fresh)  # absorb is not
+    finally:
+        deviceprofile.set_enabled(True)
+    assert used.snapshot()["pack_rows_touched"] == 54
+
+
+@pytest.mark.parametrize("variant", ["full", "point-only"])
+def test_pack_rows_touched_is_prev_plus_now_never_T(variant):
+    """Nine transactions at T = 1024: a pack touches the rows its
+    set's last pack wrote plus the rows it writes — 9 while the ring
+    fills, 18 once it is warm — and never the 1,024 slots."""
+    from foundationdb_tpu.core.commit import CommitRequest
+    from foundationdb_tpu.resolver.packing import BatchPacker
+    from foundationdb_tpu.resolver.resolver import (
+        fast_params_of,
+        params_from_knobs,
+    )
+
+    params = params_from_knobs(Knobs())
+    assert params.txns == 1024
+    if variant == "point-only":
+        params = fast_params_of(params)
+    limbs = params.key_width - 1
+    packer = BatchPacker(params)
+    prof = packer.profile = DeviceProfile("resolver")
+    ring = packer.STAGING_RING
+
+    def pack(n, salt):
+        reqs = []
+        for i in range(n):
+            k = b"u%03d%04d" % (salt, i)
+            wcr = [(k, k + b"\x00")]
+            reqs.append(CommitRequest(
+                10, [], [], wcr,
+                flat_conflicts=flatpack.encode_conflicts([], wcr, limbs)))
+        before = prof.snapshot()["pack_rows_touched"]
+        packer.pack_flat(flatpack.build_flat_batch(reqs, limbs), 0,
+                         20 + salt, 0)
+        return prof.snapshot()["pack_rows_touched"] - before
+
+    deltas = [pack(9, s) for s in range(3 * ring)]
+    assert deltas[:ring] == [9] * ring  # fresh sets: nothing to reset
+    assert deltas[ring:] == [18] * (2 * ring)  # n_prev + n_now
+    # shrink and grow, as after the bulk load: the full batch is paid
+    # once when written and once when its set comes round again
+    assert pack(1024, 90) == 9 + 1024
+    assert [pack(9, 91 + s) for s in range(ring - 1)] == [18] * (ring - 1)
+    assert pack(0, 95) == 1024 + 0
+    assert [pack(9, 96 + s) for s in range(ring)] == [18] * (ring - 1) + [9]
+
+
+def test_pack_rows_touched_rides_the_resolver_hook_per_batch_row():
+    """Through a Resolver (single-batch route and a padded backlog
+    group): the counter rises by what the staging sets held and take,
+    beside txn_slots, which rises by T a step."""
+    r = Resolver(KNOBS)
+    T = KNOBS.batch_txn_capacity
+
+    def flat(n, salt):
+        return _flat([
+            _req(10, [], [(b"p%02d%02d" % (salt, i),
+                           b"p%02d%02d\x00" % (salt, i))])
+            for i in range(n)])
+
+    r.resolve(flat(3, 0), 20, 0)
+    snap = r.profile.snapshot()
+    assert snap["pack_rows_touched"] == 3 and snap["txn_slots"] == T
+    # a backlog group: three live batches in a padded bucket, one set
+    r.resolve_many([(flat(n, 1 + g), 21 + g, 0)
+                    for g, n in enumerate((5, 0, 2))])
+    snap = r.profile.snapshot()
+    assert snap["pack_rows_touched"] == 3 + 7
+    assert snap["txn_slots"] > 2 * T
+    assert merged_snapshot([r.profile])["pack_rows_touched"] == 10
+
+
 def test_count_retraces_observes_new_signatures_only():
     import numpy as np
 
@@ -237,7 +329,7 @@ def test_flat_backlog_staging_reuse_hooks_fire():
     r = Resolver(KNOBS)
     # the staging ring keeps STAGING_RING (4) slots per shape alive
     # before reusing one: the first dispatches miss (fresh allocation),
-    # later same-shape dispatches hit (a fill(0) reuse)
+    # later same-shape dispatches hit (a reset of the rows last written)
     for d in range(6):
         batches = [
             (_flat([_req(10 + 10 * d, [],

@@ -123,6 +123,122 @@ def test_pack_flat_staging_reuse_is_clean():
     assert packer.flat_reuse_hits > 0
 
 
+# ── staging reuse: the pack resets and writes only live rows ─────────
+# One packer driven through more than 2 × STAGING_RING packs, so every
+# set of the ring is reused at least once after holding something else;
+# each result must equal a fresh packer's and the legacy pack + stack.
+DEFAULT_KNOBS = Knobs()  # T = 1024: the served deployment's shapes
+_COUNTS = [1024, 9, 0, 300, 1]  # as after the bulk load: shrink and grow
+_KINDS = ["point", "range", "mixed", "empty"]
+
+
+def _gen(kind, n, salt, limbs):
+    """n transactions of one kind (``empty``: none), keys unique to
+    ``salt`` so a row left over from another pack cannot pass."""
+    def req(i):
+        k = b"%c%03d%04d" % (97 + salt % 26, salt, i)
+        pts = [(k, k + b"\x00")]
+        rgs = [(k + b"a", k + b"q")]
+        want_pt = kind == "point" or (kind == "mixed" and i % 3 != 1)
+        want_rg = kind == "range" or (kind == "mixed" and i % 3 != 0)
+        rcr = (pts if want_pt else []) + (rgs if want_rg else [])
+        wcr = ([(k + b"w", k + b"w\x00")] if want_pt else []) + (
+            [(k + b"r", k + b"s")] if want_rg and i % 2 else [])
+        return CommitRequest(
+            5 + i % 7, [], rcr, wcr,
+            flat_conflicts=flatpack.encode_conflicts(rcr, wcr, limbs))
+
+    return [] if kind == "empty" else [req(i) for i in range(n)]
+
+
+def _schedule(case):
+    """(params, B, steps); a step is the [(kind, n)] of its live
+    batches. Every schedule is longer than 2 × STAGING_RING."""
+    full = params_from_knobs(DEFAULT_KNOBS)
+    if case == "counts":
+        return full, 1, [[("mixed", n)] for n in _COUNTS * 2]
+    if case == "counts-point-only":
+        from foundationdb_tpu.resolver.resolver import fast_params_of
+
+        return fast_params_of(full), 1, [
+            [("point", n)] for n in _COUNTS * 2]
+    if case == "sides":
+        return full, 1, [[(k, 9)] for k in _KINDS * 3]
+    if case == "sides-small":
+        return params_from_knobs(KNOBS), 1, [
+            [(k, n)] for k in _KINDS for n in (16, 3, 1)]
+    nb = {"group-nb1": [1], "group-nb3": [3], "group-nb8": [8],
+          "group-nb-varies": [8, 1, 3]}[case]
+    steps = []
+    for s in range(10):
+        n = nb[s % len(nb)]
+        steps.append([
+            (_KINDS[(s + g) % 4], _COUNTS[(s + 2 * g) % 5])
+            for g in range(n)])
+    return full, 8, steps
+
+
+@pytest.mark.parametrize("case", [
+    "counts", "counts-point-only", "sides", "sides-small",
+    "group-nb1", "group-nb3", "group-nb8", "group-nb-varies",
+])
+def test_pack_flat_reuse_follows_live_rows_bit_identical(case):
+    params, B, steps = _schedule(case)
+    assert len(steps) > 2 * BatchPacker.STAGING_RING
+    limbs = params.key_width - 1
+    shared = BatchPacker(params)
+    base = 3
+    for s, step in enumerate(steps):
+        groups = [_gen(kind, n, 10 * s + g, limbs)
+                  for g, (kind, n) in enumerate(step)]
+        metas = [(30 + 10 * s + g, 7 + s) for g in range(len(groups))]
+        flats = [flatpack.build_flat_batch(reqs, limbs) for reqs in groups]
+        assert all(shared.flat_fits(f) for f in flats)
+        got = shared.pack_flat_group(flats, metas, base, B=B)
+        fresh = BatchPacker(params).pack_flat_group(flats, metas, base, B=B)
+        _assert_batches_equal(fresh, got)
+        legacy = [
+            shared.pack([_legacy_txn(r) for r in reqs], base, cv, ws)
+            for reqs, (cv, ws) in zip(groups, metas)
+        ]
+        legacy.extend(
+            [shared.pack_empty(base, *metas[-1])] * (B - len(legacy)))
+        _assert_batches_equal(
+            jax.tree.map(lambda *xs: np.stack(xs), *legacy), got)
+    assert shared.flat_reuse_hits == len(steps) - shared.STAGING_RING
+
+
+def test_warm_pack_flat_takes_its_ramps_from_the_cached_iota(monkeypatch):
+    """``np.arange`` gives the interpreter lock up at any size (PERF.md,
+    PR 29: on the served dispatch thread each give-up is a wait behind
+    the request threads), so a pack calls it only to grow the cache."""
+    from foundationdb_tpu.resolver import packing
+
+    packer = BatchPacker(params_from_knobs(DEFAULT_KNOBS))
+    limbs = packer.params.key_width - 1
+    flats = [flatpack.build_flat_batch(_gen("mixed", n, n, limbs), limbs)
+             for n in (9, 300, 1024)]
+    calls = []
+    real = np.arange
+    monkeypatch.setattr(
+        packing.np, "arange",
+        lambda *a, **k: calls.append(a) or real(*a, **k))
+    for f in flats:
+        packer.pack_flat(f, 0, 30, 7)
+    packer.pack_flat_group(flats, [(30, 7)] * 3, 0, B=8)
+    assert calls == []
+    # a batch beyond the cached ramp grows it once, not once a pack
+    small = params_from_knobs(KNOBS)
+    many = flatpack.build_flat_batch(_gen("point", 16, 1, L), L)
+    want = BatchPacker(small).pack_flat(many, 0, 30, 7)
+    grown = BatchPacker(small)
+    grown._iota = real(2)
+    del calls[:]
+    for _ in range(3):
+        _assert_batches_equal(want, grown.pack_flat(many, 0, 30, 7))
+    assert len(calls) == 1 and len(grown._iota) == 32
+
+
 def test_encode_conflicts_rejects_over_capacity_keys():
     cap = 4 * L
     assert flatpack.encode_conflicts(
