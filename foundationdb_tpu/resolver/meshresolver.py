@@ -47,6 +47,7 @@ from foundationdb_tpu.resolver.resolver import (
     params_from_knobs,
 )
 from foundationdb_tpu.utils import deviceprofile
+from foundationdb_tpu.utils import span as span_mod
 
 
 class MeshResolver(Resolver):
@@ -159,9 +160,14 @@ class MeshResolver(Resolver):
         rollup the hash path fills with per-lane walls — in range mode
         the split balance IS the utilization story, and it is known
         before the device ever runs."""
-        sb, k, lane_counts = self._router.split(stacked)
+        with span_mod.stage("resolver.route", self.profile):
+            sb, k, lane_counts = self._router.split(stacked)
         if deviceprofile.enabled():
             self.profile.record_lane_counts(lane_counts.tolist())
+            self.profile.count(
+                route_dispatches=1, route_slices=k,
+                lane_entries_routed=lane_counts.sum(),
+                lane_entries_fullest=lane_counts.max())
         return sb, k
 
     def _route_step(self, state, batch):

@@ -33,7 +33,8 @@ commit, the traced pair in PERF.md), not a CPU gate's.
 The resolver's host stages reach the profile through
 ``utils/span.stage(name, stats=profile)``: :meth:`DeviceProfile.add`
 maps ``resolver.pack`` / ``resolver.enqueue`` / ``resolver.readback``
-to ``pack_wall_ms`` / ``enqueue_wall_ms`` / ``verdict_reduce_wall_ms``.
+/ ``resolver.route`` to ``pack_wall_ms`` / ``enqueue_wall_ms`` /
+``verdict_reduce_wall_ms`` / ``route_wall_ms``.
 """
 
 import os
@@ -62,7 +63,15 @@ STAGE_WALLS = {
     "resolver.pack": "pack_wall_s",
     "resolver.enqueue": "enqueue_wall_s",
     "resolver.readback": "verdict_reduce_wall_s",
+    "resolver.route": "route_wall_s",
 }
+
+# the mesh router's plain counters (:meth:`DeviceProfile.count`, fed by
+# ``MeshResolver._split_counted``), each summed over dispatches: the
+# dispatches routed and their slices (k: 1 unless a lane overflowed),
+# the entries routed and, of them, those of the dispatch's fullest lane
+ROUTE_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
+                  "lane_entries_fullest")
 
 
 def set_enabled(on):
@@ -131,6 +140,9 @@ class DeviceProfile:
         self.lane_walls_s = []
         self.lane_entries = []
         self.lane_dispatches = 0
+        self.route_wall_s = 0.0  # stage resolver.route: the router's split
+        for c in ROUTE_COUNTERS:
+            setattr(self, c, 0)
         # fallback-cause taxonomy
         self.fallback_causes = {c: 0 for c in FALLBACK_CAUSES}
         # kernel-route dispatch records: which per-batch step body
@@ -233,6 +245,14 @@ class DeviceProfile:
                 self.lane_entries[i] += int(c)
             self.lane_dispatches += 1
 
+    def count(self, **counters):
+        """Add to :data:`ROUTE_COUNTERS`."""
+        if not _enabled:
+            return
+        with self._lock:
+            for c, n in counters.items():
+                setattr(self, c, getattr(self, c) + int(n))
+
     def record_verdict_reduce(self, wall_s):
         if not _enabled:
             return
@@ -279,6 +299,8 @@ class DeviceProfile:
                 "lane_dispatches": other.lane_dispatches,
                 "fallback_causes": dict(other.fallback_causes),
                 "kernel_routes": dict(other.kernel_routes),
+                "route_wall_s": other.route_wall_s,
+                **{c: getattr(other, c) for c in ROUTE_COUNTERS},
             }
         with self._lock:
             self.dispatches += o["dispatches"]
@@ -316,6 +338,8 @@ class DeviceProfile:
             for i, c in enumerate(o["lane_entries"]):
                 self.lane_entries[i] += c
             self.lane_dispatches += o["lane_dispatches"]
+            for c in ("route_wall_s",) + ROUTE_COUNTERS:
+                setattr(self, c, getattr(self, c) + o[c])
             for c, v in o["fallback_causes"].items():
                 self.fallback_causes[c] = (
                     self.fallback_causes.get(c, 0) + v)
@@ -376,6 +400,8 @@ class DeviceProfile:
                 "lane_walls_ms": [round(w * 1e3, 3) for w in lanes],
                 "lane_entries": entries,
                 "lane_skew_pct": lane_skew,
+                "route_wall_ms": round(self.route_wall_s * 1e3, 3),
+                **{c: getattr(self, c) for c in ROUTE_COUNTERS},
                 "fallback_causes": dict(sorted(
                     self.fallback_causes.items())),
                 "kernel_routes": dict(sorted(
