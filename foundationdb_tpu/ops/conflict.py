@@ -157,13 +157,17 @@ class ShardBatch(NamedTuple):
     conflict side, this layout pools each side into a flat slot array of
     per-lane capacity Q with an explicit owning-txn index: the host
     router sends each entry ONLY to the lane(s) whose key range it
-    touches, so per-lane work shrinks as the lane count grows (the dense
-    layout replicates every entry to every lane and shrinks nothing).
-    Point entries go to exactly ``lane(key)``; range entries get one
-    slot in EVERY lane their span overlaps, carrying the FULL unclipped
-    range (the overlap checks stay exact; duplicates only re-derive the
-    same verdict). ``rv``/``txn_mask``/``cv``/``new_window_start`` stay
-    replicated — the verdict fold needs them on every lane.
+    touches, so a lane's history checks (table gathers, the [Q, KR]
+    ring scan) and its history run over its own keys alone. Point
+    entries go to exactly ``lane(key)``; range entries get one slot in
+    EVERY lane their span overlaps, carrying the FULL unclipped range
+    (the overlap checks stay exact; duplicates only re-derive the same
+    verdict). So one txn holds at most K slots a side in one lane (K
+    the side's per-txn width): the step puts a lane's slots back on a
+    dense [T, K] grid for the intra-batch matrix, which costs each lane
+    what it costs the one-lane step. ``rv``/``txn_mask``/``cv``/
+    ``new_window_start`` stay replicated — the verdict fold needs them
+    on every lane.
     """
 
     rv: jnp.ndarray  # uint32[T] read-version offsets (replicated)
@@ -251,6 +255,60 @@ def _range_max(levels, lo, hi):
 def _point_in(k, b, e):
     """bool: limb key k in [b, e). Broadcasting over leading dims."""
     return (~lex_lt(k, b)) & lex_lt(k, e)
+
+
+def _overlap_matrix(T, pw, pr, rw, rr):
+    """The intra-batch conflict matrix of dense ``[T, K]`` sides:
+    O[t1, t2] = some write of t1 hits some read of t2 (not yet cut to
+    t1 < t2 or to live txns). Both device steps build theirs here.
+
+    A side is falsy where the program has none. Reads are ``(hash, key,
+    mask)`` / ``(b, e, mask)``; writes ``(hash, key, ok)`` / ``(b, e,
+    ok)``, ``ok()`` giving the writes this lane answers for. ``ok`` is
+    called in each block it masks, which is how the one-lane step
+    always traced its ownership masks: that step has to lower to the
+    program it was (tests/test_presharded_structure.py holds the hash
+    of its text), and XLA folds the repeats.
+    """
+    u32 = jnp.uint32
+    if pw:
+        pw_hash, pw_key, pw_ok = pw
+    if pr:
+        pr_hash, pr_key, pr_mask = pr
+    if rw:
+        rw_b, rw_e, rw_ok = rw
+    if rr:
+        rr_b, rr_e, rr_mask = rr
+    O = jnp.zeros((T, T), bool)
+    if pw and pr:
+        wh = jnp.where(pw_ok(), pw_hash, u32(0xFFFFFFFF))  # [T, PW]
+        rh = jnp.where(pr_mask, pr_hash, u32(0xFFFFFFFE))  # [T, PR]
+        eq = wh[:, :, None, None] == rh[None, None, :, :]  # [T1, PW, T2, PR]
+        O |= jnp.any(eq, axis=(1, 3))
+    if pw and rr:
+        inr = _point_in(
+            pw_key[:, :, None, None, :], rr_b[None, None], rr_e[None, None]
+        )  # [T1, PW, T2, RR]
+        m = pw_ok()[:, :, None, None] & rr_mask[None, None]
+        O |= jnp.any(inr & m, axis=(1, 3))
+    if rw and pr:
+        inr = _point_in(
+            pr_key[None, None],  # [1, 1, T2, PR, W]
+            rw_b[:, :, None, None, :],  # [T1, RW, 1, 1, W]
+            rw_e[:, :, None, None, :],
+        )  # [T1, RW, T2, PR]
+        m = rw_ok()[:, :, None, None] & pr_mask[None, None]
+        O |= jnp.any(inr & m, axis=(1, 3))
+    if rw and rr:
+        ov = ranges_overlap(
+            rr_b[None, None],  # [1, 1, T2, RR, W]
+            rr_e[None, None],
+            rw_b[:, :, None, None, :],  # [T1, RW, 1, 1, W]
+            rw_e[:, :, None, None, :],
+        )
+        m = rw_ok()[:, :, None, None] & rr_mask[None, None]
+        O |= jnp.any(ov & m, axis=(1, 3))
+    return O
 
 
 def resolve_batch(
@@ -493,40 +551,18 @@ def resolve_batch(
         # O[t1, t2]: an accepted t1 < t2 would abort t2 (t1's writes hit
         # t2's reads). Each shard builds rows only from writes it owns;
         # the Jacobi loop OR-reduces the kill vectors.
-        O = jnp.zeros((T, T), bool)
-        if params.point_writes and params.point_reads:
-            w_ok = batch.pw_mask & hash_owned(batch.pw_hash)
-            wh = jnp.where(w_ok, batch.pw_hash, u32(0xFFFFFFFF))  # [T, PW]
-            rh = jnp.where(batch.pr_mask, batch.pr_hash, u32(0xFFFFFFFE))  # [T, PR]
-            eq = wh[:, :, None, None] == rh[None, None, :, :]  # [T1, PW, T2, PR]
-            O |= jnp.any(eq, axis=(1, 3))
-        if params.point_writes and params.range_reads:
-            inr = _point_in(
-                batch.pw_key[:, :, None, None, :], batch.rr_b[None, None], batch.rr_e[None, None]
-            )  # [T1, PW, T2, RR]
-            w_ok = batch.pw_mask & hash_owned(batch.pw_hash)
-            m = w_ok[:, :, None, None] & batch.rr_mask[None, None]
-            O |= jnp.any(inr & m, axis=(1, 3))
-        if params.range_writes and params.point_reads:
-            inr = _point_in(
-                batch.pr_key[None, None],  # [1, 1, T2, PR, W]
-                batch.rw_b[:, :, None, None, :],  # [T1, RW, 1, 1, W]
-                batch.rw_e[:, :, None, None, :],
-            )  # [T1, RW, T2, PR]
-            w_ok = batch.rw_mask & bucket_owned(batch.rw_lo)
-            m = w_ok[:, :, None, None] & batch.pr_mask[None, None]
-            O |= jnp.any(inr & m, axis=(1, 3))
-        if params.range_writes and params.range_reads:
-            ov = ranges_overlap(
-                batch.rr_b[None, None],  # [1, 1, T2, RR, W]
-                batch.rr_e[None, None],
-                batch.rw_b[:, :, None, None, :],  # [T1, RW, 1, 1, W]
-                batch.rw_e[:, :, None, None, :],
-            )
-            w_ok = batch.rw_mask & bucket_owned(batch.rw_lo)
-            m = w_ok[:, :, None, None] & batch.rr_mask[None, None]
-            O |= jnp.any(ov & m, axis=(1, 3))
-
+        O = _overlap_matrix(
+            T,
+            params.point_writes and (
+                batch.pw_hash, batch.pw_key,
+                lambda: batch.pw_mask & hash_owned(batch.pw_hash)),
+            params.point_reads and (
+                batch.pr_hash, batch.pr_key, batch.pr_mask),
+            params.range_writes and (
+                batch.rw_b, batch.rw_e,
+                lambda: batch.rw_mask & bucket_owned(batch.rw_lo)),
+            params.range_reads and (batch.rr_b, batch.rr_e, batch.rr_mask),
+        )
         strict_lower = jnp.tril(jnp.ones((T, T), bool), k=-1).T  # [t1 < t2]
         O &= strict_lower & batch.txn_mask[:, None] & batch.txn_mask[None, :]
 
@@ -709,6 +745,36 @@ def validate_params(params: ResolverParams):
             )
 
 
+def _dense_side(T, K, txn, mask, *fields):
+    """One compacted lane side ``[Q]`` back on the dense ``[T, K]`` grid
+    of the one-lane step → ``((*fields[T, K, ...], live[T, K]), over)``,
+    the first None for a side with no slots.
+
+    A live slot's column is its ordinal among the live slots of its txn
+    (counted here, whatever order the router emitted them in); padding
+    slots (txn 0, mask False) land nowhere. ``over``: some txn holds
+    more than K live slots here, which no routed batch does (a point has
+    one lane, a range one slot a lane) — the caller keeps it safe.
+    """
+    Q = txn.shape[0]
+    if not Q:
+        return None, False
+    q = jnp.arange(Q, dtype=jnp.int32)
+    earlier = (
+        (txn[None, :] == txn[:, None]) & mask[None, :]
+        & (q[None, :] < q[:, None])
+    )  # [Q, Q']: q' is a live slot of q's txn ahead of q
+    ordinal = jnp.sum(earlier, axis=1, dtype=jnp.int32)
+    fits = mask & (ordinal < K)
+    cell = jnp.where(fits, txn * K + ordinal, T * K)
+    slot = (
+        jnp.full((T * K,), Q, jnp.int32).at[cell].set(q, mode="drop")
+    ).reshape(T, K)
+    src = jnp.minimum(slot, Q - 1)
+    dense = (*(f[src] for f in fields), slot < Q)
+    return dense, jnp.any(mask & ~fits)
+
+
 def resolve_batch_presharded(
     state: ResolverState,
     sb: ShardBatch,
@@ -719,9 +785,11 @@ def resolve_batch_presharded(
 
     Semantics match ``resolve_batch``'s sharded mode, but ownership is
     established HOST-side by the router instead of in-kernel masks: each
-    lane sees only the entries whose keys it owns, so the dominant cost
-    terms — the [Q, KR] ring scan and the [Qw, Qr] pairwise matrix —
-    shrink with the lane count instead of being replicated n times.
+    lane sees only the entries whose keys it owns. The history checks
+    run over the lane's Q compacted slots (the [Q, KR] ring scan is
+    the term that shrinks with the lane count); the intra-batch matrix
+    is ``resolve_batch``'s own dense compare (``_overlap_matrix``) over
+    the lane's slots put back on a [T, K] grid (``_dense_side``).
 
     Correctness rests on the routing invariants (ShardBatch docstring):
     any read/write pair that overlaps shares a key point p, and both
@@ -808,45 +876,34 @@ def resolve_batch_presharded(
     hist = por(hist_i > 0)
 
     # ─────────────────────── intra-batch conflict matrix ───────────────────
-    # O[t1, t2] accumulates by 2-D scatter-add over (write_txn, read_txn)
-    # pairs; cross-lane duplicates (a spanning write × spanning read seen
-    # on two lanes) just add twice before the >0 threshold.
-    O_i = jnp.zeros((T, T), jnp.int32)
-    if Qpw and Qpr:
-        wh = jnp.where(sb.pw_mask, sb.pw_hash, u32(0xFFFFFFFF))
-        rh = jnp.where(sb.pr_mask, sb.pr_hash, u32(0xFFFFFFFE))
-        eq = wh[:, None] == rh[None, :]  # [Qpw, Qpr]
-        O_i = O_i.at[sb.pw_txn[:, None], sb.pr_txn[None, :]].add(
-            eq.astype(jnp.int32), mode="promise_in_bounds"
-        )
-    if Qpw and Qrr:
-        inr = _point_in(
-            sb.pw_key[:, None, :], sb.rr_b[None], sb.rr_e[None]
-        )  # [Qpw, Qrr]
-        m = sb.pw_mask[:, None] & sb.rr_mask[None, :]
-        O_i = O_i.at[sb.pw_txn[:, None], sb.rr_txn[None, :]].add(
-            (inr & m).astype(jnp.int32), mode="promise_in_bounds"
-        )
-    if Qrw and Qpr:
-        inr = _point_in(
-            sb.pr_key[None], sb.rw_b[:, None, :], sb.rw_e[:, None, :]
-        )  # [Qrw, Qpr]
-        m = sb.rw_mask[:, None] & sb.pr_mask[None, :]
-        O_i = O_i.at[sb.rw_txn[:, None], sb.pr_txn[None, :]].add(
-            (inr & m).astype(jnp.int32), mode="promise_in_bounds"
-        )
-    if Qrw and Qrr:
-        ov = ranges_overlap(
-            sb.rr_b[None], sb.rr_e[None],
-            sb.rw_b[:, None, :], sb.rw_e[:, None, :],
-        )  # [Qrw, Qrr]
-        m = sb.rw_mask[:, None] & sb.rr_mask[None, :]
-        O_i = O_i.at[sb.rw_txn[:, None], sb.rr_txn[None, :]].add(
-            (ov & m).astype(jnp.int32), mode="promise_in_bounds"
-        )
+    # Each compacted side goes back onto a dense [T, K] grid (one
+    # scatter of Q slot indices, a gather of its fields) and the matrix
+    # is the dense compare of the one-lane step, over this lane's
+    # entries only; cross-lane duplicates (a spanning write × spanning
+    # read seen on two lanes) re-derive the same bit, and the Jacobi
+    # loop's psum folds the lanes.
+    pw, pw_over = _dense_side(
+        T, params.point_writes, sb.pw_txn, sb.pw_mask, sb.pw_hash, sb.pw_key)
+    pr, pr_over = _dense_side(
+        T, params.point_reads, sb.pr_txn, sb.pr_mask, sb.pr_hash, sb.pr_key)
+    rw, rw_over = _dense_side(
+        T, params.range_writes, sb.rw_txn, sb.rw_mask, sb.rw_b, sb.rw_e)
+    rr, rr_over = _dense_side(
+        T, params.range_reads, sb.rr_txn, sb.rr_mask, sb.rr_b, sb.rr_e)
+    O = _overlap_matrix(
+        T,
+        pw and (*pw[:2], lambda: pw[2]),
+        pr,
+        rw and (*rw[:2], lambda: rw[2]),
+        rr,
+    )
+    # a txn with more than K slots a side in one lane is not a ShardBatch
+    # the router builds, and its surplus is not on the grid: such a batch
+    # stays on the safe side, every txn dying behind any earlier one
+    O |= pw_over | pr_over | rw_over | rr_over
 
     strict_lower = jnp.tril(jnp.ones((T, T), bool), k=-1).T  # [t1 < t2]
-    O = (O_i > 0) & strict_lower & sb.txn_mask[:, None] & sb.txn_mask[None, :]
+    O &= strict_lower & sb.txn_mask[:, None] & sb.txn_mask[None, :]
 
     # Jacobi fixpoint — identical to resolve_batch: the kill vector is
     # psum-reduced per iteration (d small [T] reductions beat one [T,T]

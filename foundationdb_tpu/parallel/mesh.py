@@ -174,8 +174,10 @@ class PreshardedResolverKernel(ShardedResolverKernel):
     every lane and carves ownership in-kernel — per-lane work never
     shrinks, so k lanes cost k× the FLOPs of one. Here the host router
     (resolver/packing.ShardRouter) sends each entry only to the lane(s)
-    owning its keys, so the ring scan and the pairwise conflict matrix
-    shrink ~1/n per lane while history capacity still scales n×. State
+    owning its keys, so a lane's history checks (the ring scan above
+    all) run over ~1/n of the batch's entries while history capacity
+    still scales n×; the intra-batch matrix is rebuilt per lane on a
+    dense [T, K] grid and costs every lane what it costs one. State
     layout and placement are inherited unchanged (``ring_capacity`` is
     the PER-LANE ring size, as before); only the batch specs and the
     kernel body differ. Ref: CommitProxyServer.actor.cpp's resolution

@@ -1,0 +1,89 @@
+"""What the four-lane step is made of, read off its traced program on
+the CPU (no clock): at the cell's shape (default ``Knobs()``, four
+lanes: T = 1024, 1,792 point and 896 range slots a side a lane) no
+scatter writes a ``[T, T]`` operand or takes more updates than the
+largest slot array the step holds; the programs keep the names the
+benchmark's trace metrics find them by; and the one-lane step, which
+shares ``_overlap_matrix`` with it, lowers to the text it had before.
+"""
+
+import hashlib
+import math
+
+import jax
+import numpy as np
+import pytest
+
+from foundationdb_tpu.core.options import Knobs
+from foundationdb_tpu.ops import conflict as ck
+from foundationdb_tpu.parallel import mesh as pm
+from foundationdb_tpu.resolver.packing import BatchPacker, ShardRouter
+from foundationdb_tpu.resolver.resolver import (
+    fast_params_of, params_from_knobs)
+
+LANES = 4
+
+# sha256 of make_resolve_fn(params).lower(state, batch).as_text() on
+# the parent of PR 31 (commit 11e6431), under the jax it was taken with
+ONE_LANE_TEXT = {
+    "jax": "0.9.0",
+    "full": "dba61d84a0882618f01c5d1039892663defb1b8a249af1578c0d03170f1b24ab",
+    "fast": "c1d846a504d6e595bb55ae56e0b29a6701b90cdb4b164061630f64f9f4cc6335",
+}
+
+
+@pytest.fixture(scope="module")
+def mesh_step():
+    """(params, kernel, one routed ShardBatch of shapes) at the cell's."""
+    params = params_from_knobs(Knobs())
+    kern = pm.PreshardedResolverKernel(
+        params, mesh=pm.default_mesh(LANES), make_state=False)
+    empty = BatchPacker(params).pack_empty(0, 1, 0)
+    sb, _, _ = ShardRouter(params, LANES).split(
+        jax.tree.map(lambda a: np.asarray(a)[None], empty))
+    return params, kern, sb
+
+
+def _scatters(jaxpr):
+    """Every scatter of a jaxpr and of the jaxprs inside it →
+    (operand shape, rows of indices = updates it applies)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            operand, indices = eqn.invars[0].aval, eqn.invars[1].aval
+            yield operand.shape, math.prod(indices.shape[:-1])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scatters(sub)
+
+
+@pytest.mark.parametrize("program", ["step", "scan"])
+def test_no_scatter_over_slot_pairs_and_the_programs_keep_their_names(
+        mesh_step, program):
+    params, kern, sb = mesh_step
+    state = jax.eval_shape(kern.init_state)
+    if program == "step":
+        fn, name = kern._step, "jit_resolve_batch_presharded"
+        batch = jax.tree.map(lambda a: a[0], sb)
+    else:
+        fn, name = kern._scan_step, "jit_scan_step"
+        batch = sb
+    T = params.txns
+    # the widest slot array a lane holds: its point sides, or its ring
+    most = max(sb.pr_hash.shape[-1] // LANES, params.ring_capacity)
+    found = list(_scatters(jax.make_jaxpr(fn)(state, batch).jaxpr))
+    assert len(found) > 8  # the walk reached the step's body
+    assert not [s for s in found if s[0] == (T, T) or s[1] > most], found
+    assert fn.lower(state, batch).as_text().startswith(f"module @{name} ")
+
+
+@pytest.mark.parametrize("variant", ["full", "fast"])
+def test_the_one_lane_step_lowers_to_the_text_it_had(variant):
+    if jax.__version__ != ONE_LANE_TEXT["jax"]:
+        pytest.skip(f"the text was hashed under jax {ONE_LANE_TEXT['jax']}; "
+                    "another jax writes other text for the same program")
+    params = params_from_knobs(Knobs())
+    if variant == "fast":
+        params = fast_params_of(params)
+    state = jax.eval_shape(lambda: ck.init_state(params))
+    batch = BatchPacker(params).pack_empty(0, 1, 0)
+    text = ck.make_resolve_fn(params).lower(state, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == ONE_LANE_TEXT[variant]

@@ -20,6 +20,7 @@ asserted only on k == 1 workloads, the steady-state shape.
 
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -166,6 +167,153 @@ def test_chunked_overflow_is_conservative_only():
     conservative = (st == st_ref) | (
         (st == CONFLICT) & (st_ref == COMMITTED))
     assert bool(np.all(conservative))
+
+
+# ── the cell's own shape: default Knobs(), four lanes ──────────────────
+# T = 1024, a lane takes 1,792 point and 896 range slots a side. The
+# matrix of the presharded step comes from a [T, K] grid rebuilt on the
+# device, so the inputs that would show a slot lost on the way are here.
+
+CELL_LANES = 4
+
+
+def _user(i):
+    return b"user%08d" % i
+
+
+def _one_hot_key():
+    """(a) the cell's traffic: nine attempts at one key, one lane."""
+    return [TxnRequest(read_version=5, point_reads=[_user(7)],
+                       point_writes=[_user(7)]) for _ in range(9)]
+
+
+def _full_lane():
+    """(b) 448 txns × 4 fill all 1,792 point slots a side of the one
+    lane every ``user…`` key has. 447 accepted writers put 1,788 slots
+    on the last txn's four reads (a count kept in bf16, or in 8 bits,
+    is wrong long before that); txn 200 dies by one pair alone, the
+    K-th write of txn 100 against its own K-th read."""
+    hot = [_user(i) for i in range(4)]
+    fresh = iter(range(10, 10_000))
+    txns = []
+    for t in range(447):
+        reads = [_user(next(fresh)) for _ in range(4)]
+        writes = list(hot)
+        if t == 100:
+            writes[3] = b"user-kth"
+        if t == 200:
+            reads[3] = b"user-kth"
+        txns.append(TxnRequest(read_version=5, point_reads=reads,
+                               point_writes=writes))
+    txns.append(TxnRequest(
+        read_version=5, point_reads=hot,
+        point_writes=[_user(next(fresh)) for _ in range(4)]))
+    return txns
+
+
+def _k_a_side_and_ranges_in_every_lane():
+    """(c) K entries a side of one txn in one lane, ranges with a slot
+    in all four lanes, and a kill through each of the four blocks."""
+    everywhere = (b"\x00", b"\xff\xff")
+    return [
+        TxnRequest(read_version=5,
+                   point_reads=[_user(i) for i in range(4)],
+                   point_writes=[_user(i) for i in range(4, 8)],
+                   range_reads=[(_user(100), _user(200)),
+                                (_user(300), _user(400))],
+                   range_writes=[(_user(500), _user(600)), everywhere]),
+        TxnRequest(read_version=5, point_reads=[_user(7)]),   # pw × pr
+        TxnRequest(read_version=5,
+                   range_reads=[(_user(6), _user(9))]),       # pw × rr
+        TxnRequest(read_version=5, point_reads=[b"\x90zz"]),  # rw × pr
+        TxnRequest(read_version=5, range_reads=[everywhere]),  # rw × rr
+        TxnRequest(read_version=5, point_reads=[b"\xff\xff\x01"],
+                   point_writes=[b"\xff\xff\x01"]),           # untouched
+    ]
+
+
+def _padding_beside_a_live_txn_0():
+    """(d) every padding slot names txn 0 with the all-zero key (the
+    empty key's limbs) and mask False. Txn 1 reads the empty key: it
+    dies if padding is taken for writes of the live txn 0."""
+    return [TxnRequest(read_version=5, point_reads=[_user(1)],
+                       point_writes=[_user(1)],
+                       range_writes=[(_user(2), _user(3))]),
+            TxnRequest(read_version=5, point_reads=[b""],
+                       range_reads=[(b"", b"\x00")])]
+
+
+CELL_CASES = {
+    "one_hot_key": (_one_hot_key, [1]),
+    "full_lane": (_full_lane, [446]),
+    "k_a_side_ranges_everywhere": (_k_a_side_and_ranges_in_every_lane,
+                                   [2]),
+    "padding_beside_live_txn_0": (_padding_beside_a_live_txn_0, [2]),
+}
+
+
+@pytest.fixture(scope="module")
+def cell_shape():
+    from foundationdb_tpu.resolver.resolver import params_from_knobs
+
+    params = params_from_knobs(Knobs())
+    router = ShardRouter(params, CELL_LANES)
+    assert (params.txns, router.caps["pr"], router.caps["rr"]) == (
+        1024, 1792, 896)
+    kern = pm.PreshardedResolverKernel(
+        params, mesh=pm.default_mesh(CELL_LANES), donate=False)
+    dense = ck.make_resolve_scan_fn(params, donate=False)
+    return params, router, kern, dense
+
+
+def _stack(params, txns):
+    b = BatchPacker(params, use_native=False).pack(txns, 0, 10, 0)
+    return jax.tree.map(lambda a: np.asarray(a)[None], b)
+
+
+@pytest.mark.parametrize("case", sorted(CELL_CASES))
+def test_presharded_step_at_the_cells_shape_matches_dense(cell_shape, case):
+    from foundationdb_tpu.core.status import COMMITTED
+
+    params, router, kern, dense = cell_shape
+    build, committed = CELL_CASES[case]
+    txns = build()
+    stacked = _stack(params, txns)
+    _, st_ref = dense(ck.init_state(params), stacked)
+    sb, k, lane_counts = router.split(stacked)
+    assert k == 1
+    if case == "full_lane":
+        assert lane_counts.tolist() == [0, 2 * 1792, 0, 0]
+    single = jax.tree.map(lambda a: a[0], sb)
+    st, _, _ = kern._step(kern.state, single)
+    st, st_ref = np.asarray(st), np.asarray(st_ref)[0]
+    assert np.array_equal(st, st_ref)
+    # and the verdicts are the ones the case was built to draw
+    assert committed == [int(np.sum(st[:len(txns)] == COMMITTED))]
+    _, st_scan = kern._scan_step(kern.state, sb)
+    assert np.array_equal(np.asarray(st_scan)[0], st_ref)
+
+
+def test_a_txn_with_more_than_k_slots_in_a_lane_loses_no_conflict(
+        cell_shape):
+    """No routed batch holds more than K slots a side of one txn in one
+    lane; a ShardBatch that does still misses nothing. Txn 1's write of
+    ``z`` is handed to txn 0 as its fifth: txn 2, which reads ``z``,
+    must not commit."""
+    from foundationdb_tpu.core.status import COMMITTED, CONFLICT
+
+    params, router, kern, _ = cell_shape
+    txns = [TxnRequest(read_version=5,
+                       point_writes=[_user(i) for i in range(4)]),
+            TxnRequest(read_version=5, point_writes=[b"user-z"]),
+            TxnRequest(read_version=5, point_reads=[b"user-z"])]
+    sb, k, _ = router.split(_stack(params, txns))
+    single = jax.tree.map(lambda a: a[0], sb)
+    moved = single.pw_txn.copy()
+    moved[(single.pw_txn == 1) & single.pw_mask] = 0
+    st, _, _ = kern._step(kern.state, single._replace(pw_txn=moved))
+    st = np.asarray(st)
+    assert st[0] == COMMITTED and st[2] == CONFLICT
 
 
 def _scripted_outcomes(cluster, seed=13, steps=60):

@@ -4,31 +4,36 @@ The sandbox has no chip but it has the chip's compiler: these tests
 hand it the five programs a default-knob resolver can dispatch (the
 single-step full variant on the jnp lanes and with the Pallas ring
 kernel, the B=8 backlog scan in its fast and full variants, and the
-fused Pallas scan kernel) at ``Knobs()`` shapes. Four must compile; the
-fused kernel is refused by Mosaic, which is why ``pallas_scan="auto"``
-no longer selects it (resolver/resolver.py). A compile that passes is
-not a chip run — ``chip_smoke.py`` is.
+fused Pallas scan kernel) at ``Knobs()`` shapes, and the four-lane
+``shard_map`` step of ``--resolvers 4`` over the described 2x2 mesh.
+Five must compile; the fused kernel is refused by Mosaic, which is why
+``pallas_scan="auto"`` no longer selects it (resolver/resolver.py). A
+compile that passes is not a chip run — ``chip_smoke.py`` is.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every xdist
 worker imports every test file.
 """
 
+import math
 import os
+import re
 
 import jax
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from foundationdb_tpu.core.options import Knobs
 from foundationdb_tpu.ops import conflict as ck
-from foundationdb_tpu.resolver.packing import BatchPacker
+from foundationdb_tpu.parallel import mesh as pm
+from foundationdb_tpu.resolver.packing import BatchPacker, ShardRouter
 from foundationdb_tpu.resolver.resolver import (
     BACKLOG_B, fast_params_of, params_from_knobs)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,9 +48,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -95,6 +105,40 @@ def test_default_knob_programs_compile_for_v5e(one_chip, tpu_branch, program):
                             lead=(BACKLOG_B,))
         assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+def test_the_four_lane_step_compiles_for_v5e_with_no_scatter_into_t_by_t(
+        topo):
+    """``PreshardedResolverKernel._step`` as ``fdbserver --resolvers 4``
+    dispatches it: T = 1024, 1,792 point and 896 range slots a lane."""
+    params = _default_params()
+    mesh = Mesh(np.array(topo.devices), (pm.AXIS,))
+    lanes = mesh.devices.size
+    kern = pm.PreshardedResolverKernel(params, mesh=mesh, make_state=False)
+
+    def placed(x, spec, lead=1):
+        shape = tuple(x.shape)
+        if spec != pm.P():  # a lane's share → the global array
+            shape = (lead * (shape[0] if shape else 1),) + shape[1:]
+        return jax.ShapeDtypeStruct(
+            shape, x.dtype, sharding=NamedSharding(mesh, spec))
+
+    state = ck.ResolverState(*(
+        placed(x, spec, lanes) for x, spec in zip(
+            jax.eval_shape(lambda: ck.init_state(params)),
+            pm._state_specs(pm.AXIS))))
+    empty = BatchPacker(params).pack_empty(0, 1, 0)
+    routed, _, _ = ShardRouter(params, lanes).split(
+        jax.tree.map(lambda a: np.asarray(a)[None], empty))
+    batch = ck.ShardBatch(*(
+        placed(a[0], spec) for a, spec in zip(
+            routed, pm._shard_batch_specs(pm.AXIS))))
+    text = kern._step.lower(state, batch).compile().as_text()
+    assert "HloModule jit_resolve_batch_presharded" in text
+    assert "all-reduce" in text  # the verdict fold over ICI
+    scattered = [math.prod(map(int, dims.split(",")))
+                 for dims in re.findall(r"= \w+\[([\d,]+)\]\S* scatter\(", text)]
+    assert scattered and params.txns ** 2 not in scattered
 
 
 @pytest.mark.xfail(strict=True, reason=(
