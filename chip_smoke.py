@@ -106,7 +106,7 @@ def emit(**facts):
 
 
 def key(i):
-    return b"user%08d" % i  # as bench.py:make_key_table encodes them
+    return b"user%08d" % i  # the keys of benchmark/configs/ycsb_1kb_100k.json
 
 
 class Records:
@@ -452,7 +452,7 @@ def drive(name, db, sizes, seed, want, rehearse, scans_backlogs,
     # scan run the jnp lanes ("jit"); on a TPU the single-step full
     # variant, which a lone batch rides once there is range history,
     # runs the ring kernel — except in a mesh, which has no Pallas
-    # lanes. Nothing selects the fused kernel.
+    # lanes.
     ring = on_tpu and not sharded
     allowed = {"jit", "pallas_ring"} if ring else {"jit"}
     required = allowed if not scans_backlogs else {"jit"}

@@ -291,21 +291,6 @@ def split_by_baseline(findings, baseline):
     return new, old, stale
 
 
-def count_findings(paths=None):
-    """Total findings (suppressions honored, baseline IGNORED) over the
-    package — the bench's ``flowlint_findings`` lint-debt gauge."""
-    findings = lint_paths(paths or [package_dir()])
-    return len(findings)
-
-
-def count_findings_by_rule(paths=None):
-    """Per-rule split of :func:`count_findings` — the bench summary
-    carries it as ``flowlint_by_rule`` so a regression names its rule
-    without a rerun."""
-    findings = lint_paths(paths or [package_dir()])
-    return dict(sorted(Counter(f.rule for f in findings).items()))
-
-
 # ─────────────────────────────── CLI ────────────────────────────────
 def main(argv=None):
     ap = argparse.ArgumentParser(

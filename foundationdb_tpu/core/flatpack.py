@@ -202,11 +202,6 @@ class FlatTxnBatch:
     def __len__(self):
         return len(self.rv)
 
-    @property
-    def pack_bytes(self):
-        return (len(self.pr_blob) + len(self.pw_blob)
-                + len(self.rr_blob) + len(self.rw_blob))
-
     def point_limbs(self, blob):
         return point_limbs(blob, self.num_limbs)
 

@@ -28,21 +28,12 @@ class Knobs:
     # "auto" = on TPU backends, "on" = everywhere (interpreter off-TPU,
     # for differential tests), "off" = always the jnp lanes
     pallas_ring: str = "auto"
-    # the FULL per-batch accept step as one fused Pallas kernel
-    # (ops/pallas_scan.py): exact ring check + intra-batch segment
-    # intersection + greedy acceptance in VMEM, subsuming pallas_ring's
-    # lane when engaged. Only "on" engages it (interpreter off-TPU, for
-    # the differential tests); "auto" and "off" leave it out, because
-    # the v5e's compiler refuses the kernel (tests/test_tpu_compile.py)
-    # and a refusal at run time costs a fenced restart under the
-    # pallas_to_jit taxonomy.
-    pallas_scan: str = "auto"
     # mesh lane ownership (resolver/meshresolver.py, multi-lane tpu
     # fleets only): "range" routes each packed entry host-side to the
     # lane(s) owning its key range (resolver/packing.ShardRouter) and
-    # runs the compacted single-dispatch kernel — per-lane work shrinks
-    # ~1/n, the path that makes k lanes faster than one. "hash"
-    # replicates the batch and carves ownership in-kernel (hash-sharded
+    # runs the compacted single-dispatch kernel: a lane's ring scan and
+    # history shrink ~1/n (what four lanes cost beside one: PERF.md).
+    # "hash" replicates the batch and carves ownership in-kernel (hash-sharded
     # point table, bucket-sharded ring): no host routing pass, no work
     # reduction.
     resolver_sharding: str = "range"
@@ -140,8 +131,6 @@ class Knobs:
     # --- workload attribution (utils/heatmap.py) ---
     # default-ON key sampling: conflict heat charged at the proxy's
     # abort-fabrication site, read/write heat sampled storage-side.
-    # BENCH_MODE=heatmap_smoke measures the enabled-vs-kill-switch cost
-    # and gates it at <=2%.
     workload_sampling: bool = True
     # bounded histogram state: adjacent-range coalescing keeps each
     # heatmap at most this many buckets no matter how long the run
@@ -263,7 +252,7 @@ class Knobs:
     # "ping-cadence" deterministic stream); 0 disables the pinger
     rpc_ping_interval_s: float = 2.0
     # chaos transport arming (rpc/chaos.py): a non-empty seed wraps
-    # every NEW client socket in the seeded fault injector — test/bench
+    # every NEW client socket in the seeded fault injector — tests
     # only; "" keeps chaos entirely un-imported (the default path)
     rpc_chaos_seed: str = ""
 

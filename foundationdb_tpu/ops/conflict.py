@@ -94,15 +94,6 @@ class ResolverParams(NamedTuple):
     # which is what bounds range-heavy throughput on-device. 0 = flat
     # ring (the mesh-sharded path always uses the flat ring).
     ring_partition_bits: int = 0
-    # the FULL accept step as one fused Pallas kernel
-    # (ops/pallas_scan.py): exact ring check + all four intra-batch
-    # segment-intersection lanes + greedy acceptance in VMEM, with only
-    # the verdict bits leaving the kernel. Subsumes use_pallas's ring
-    # lane when set (the ring check moves inside the fused kernel); the
-    # jnp history epilogue is shared, so both routes update state
-    # identically. Single-device flat-ring layout only, T <= 1024
-    # (validate_params enforces both).
-    use_pallas_scan: bool = False
 
 
 class ResolverState(NamedTuple):
@@ -408,24 +399,16 @@ def resolve_batch(
         # query's MIDDLE partitions (its end partitions get exact checks)
         part_max = jnp.max(jnp.where(rm_p, rv_p, u32(0)), axis=1)
 
-    # the Pallas kernels run the single-shard flat-ring path only (each
-    # shard_map lane is its own program; the jnp lanes stay canonical
-    # there; the partitioned ring has its own gather-based layout)
-    # — interpret mode keeps them runnable (and differential-testable)
-    # on CPU. The fused scan kernel subsumes the ring kernel: when it is
-    # on, the exact ring check happens INSIDE the fused accept step and
-    # the standalone ring lanes here are skipped entirely.
-    pallas_scan_on = (
-        params.use_pallas_scan and axis_name is None and not PB
-    )
-    pallas_ring_on = (
-        params.use_pallas and axis_name is None and not PB
-        and not pallas_scan_on
-    )
-    if pallas_ring_on or pallas_scan_on:
-        interp = jax.default_backend() != "tpu"
+    # the Pallas ring kernel runs the single-shard flat-ring path only
+    # (each shard_map lane is its own program; the jnp lanes stay
+    # canonical there; the partitioned ring has its own gather-based
+    # layout) — interpret mode keeps it runnable (and
+    # differential-testable) on CPU.
+    pallas_ring_on = params.use_pallas and axis_name is None and not PB
     if pallas_ring_on:
         from foundationdb_tpu.ops.pallas_ring import ring_hits
+
+        interp = jax.default_backend() != "tpu"
 
     # point reads vs point-write hash table (exact lane)
     if params.point_reads:
@@ -437,10 +420,7 @@ def resolve_batch(
             # lane counts come from the arrays: packers may statically
             # zero-width lanes a workload never uses
             PR = batch.pr_key.shape[1]
-            if pallas_scan_on:
-                # exact ring lane fused into the accept kernel below
-                ring_hit = None
-            elif pallas_ring_on and PR:
+            if pallas_ring_on and PR:
                 flat_k = batch.pr_key.reshape(T * PR, params.key_width)
                 rv_q = jnp.broadcast_to(rv[:, None], (T, PR)).reshape(-1)
                 ring_hit = ring_hits(
@@ -464,8 +444,7 @@ def resolve_batch(
                 )  # [T, PR, KR]
                 newer = (state.ring_v[None, None] > rv[:, None, None]) & state.ring_mask[None, None]
                 ring_hit = jnp.any(in_rng & newer, axis=2)
-            if ring_hit is not None:
-                hit |= ring_hit & batch.pr_mask
+            hit |= ring_hit & batch.pr_mask
             # point reads vs evicted range-writes (coarse interval summary)
             coarse = jnp.minimum(pref_L[batch.pr_bucket], suf_R[batch.pr_bucket])
             hit |= (coarse > rv[:, None]) & batch.pr_mask
@@ -476,10 +455,7 @@ def resolve_batch(
         hit = jnp.zeros((T, params.range_reads), bool)
         if params.range_writes:
             RR = batch.rr_b.shape[1]
-            if pallas_scan_on:
-                # exact ring lane fused into the accept kernel below
-                ring_hit = None
-            elif pallas_ring_on and RR:
+            if pallas_ring_on and RR:
                 rv_q = jnp.broadcast_to(rv[:, None], (T, RR)).reshape(-1)
                 ring_hit = ring_hits(
                     batch.rr_b.reshape(T * RR, params.key_width),
@@ -522,8 +498,7 @@ def resolve_batch(
                 )  # [T, RR, KR]
                 newer = (state.ring_v[None, None] > rv[:, None, None]) & state.ring_mask[None, None]
                 ring_hit = jnp.any(ov & newer, axis=2)
-            if ring_hit is not None:
-                hit |= ring_hit & batch.rr_mask
+            hit |= ring_hit & batch.rr_mask
             coarse_rng = jnp.minimum(pref_L[batch.rr_hi], suf_R[batch.rr_lo])
             hit |= (coarse_rng > rv[:, None]) & batch.rr_mask
         if params.point_writes:
@@ -537,59 +512,49 @@ def resolve_batch(
     # a0: admissible before intra-batch ordering (history + window + mask)
     a0 = (~too_old) & (~hist) & batch.txn_mask
 
-    if pallas_scan_on:
-        # ── fused accept kernel: exact ring check + intra-batch
-        # segment intersection + greedy acceptance in one pallas_call.
-        # Greedy sequential acceptance is the unique fixpoint of the
-        # Jacobi map below (induction on txn index), so this route is
-        # bit-identical to the jnp one.
-        from foundationdb_tpu.ops.pallas_scan import fused_accept
+    # ───────────────── intra-batch conflict matrix ─────────────────
+    # O[t1, t2]: an accepted t1 < t2 would abort t2 (t1's writes hit
+    # t2's reads). Each shard builds rows only from writes it owns;
+    # the Jacobi loop OR-reduces the kill vectors.
+    O = _overlap_matrix(
+        T,
+        params.point_writes and (
+            batch.pw_hash, batch.pw_key,
+            lambda: batch.pw_mask & hash_owned(batch.pw_hash)),
+        params.point_reads and (
+            batch.pr_hash, batch.pr_key, batch.pr_mask),
+        params.range_writes and (
+            batch.rw_b, batch.rw_e,
+            lambda: batch.rw_mask & bucket_owned(batch.rw_lo)),
+        params.range_reads and (batch.rr_b, batch.rr_e, batch.rr_mask),
+    )
+    strict_lower = jnp.tril(jnp.ones((T, T), bool), k=-1).T  # [t1 < t2]
+    O &= strict_lower & batch.txn_mask[:, None] & batch.txn_mask[None, :]
 
-        accepted = fused_accept(state, batch, params, a0, interpret=interp)
-    else:
-        # ───────────────── intra-batch conflict matrix ─────────────────
-        # O[t1, t2]: an accepted t1 < t2 would abort t2 (t1's writes hit
-        # t2's reads). Each shard builds rows only from writes it owns;
-        # the Jacobi loop OR-reduces the kill vectors.
-        O = _overlap_matrix(
-            T,
-            params.point_writes and (
-                batch.pw_hash, batch.pw_key,
-                lambda: batch.pw_mask & hash_owned(batch.pw_hash)),
-            params.point_reads and (
-                batch.pr_hash, batch.pr_key, batch.pr_mask),
-            params.range_writes and (
-                batch.rw_b, batch.rw_e,
-                lambda: batch.rw_mask & bucket_owned(batch.rw_lo)),
-            params.range_reads and (batch.rr_b, batch.rr_e, batch.rr_mask),
+    # ───────── Jacobi fixpoint for sequential acceptance ─────────
+    # The kill vector is psum-reduced per iteration rather than
+    # OR-folding the whole [T,T] matrix up front: d small [T]
+    # reductions measure cheaper than one [T,T] all-reduce for the
+    # shallow conflict chains real batches carry (d is the chain
+    # depth, typically 1-3).
+    Of = O.astype(jnp.bfloat16)
+
+    def cond(carry):
+        _, changed = carry
+        return changed
+
+    def body(carry):
+        a, _ = carry
+        killed_local = jnp.dot(
+            a.astype(jnp.bfloat16), Of, preferred_element_type=jnp.float32
         )
-        strict_lower = jnp.tril(jnp.ones((T, T), bool), k=-1).T  # [t1 < t2]
-        O &= strict_lower & batch.txn_mask[:, None] & batch.txn_mask[None, :]
+        if axis_name is not None:
+            killed_local = jax.lax.psum(killed_local, axis_name)
+        killed = killed_local > 0.5
+        a_new = a0 & ~killed
+        return a_new, jnp.any(a_new != a)
 
-        # ───────── Jacobi fixpoint for sequential acceptance ─────────
-        # The kill vector is psum-reduced per iteration rather than
-        # OR-folding the whole [T,T] matrix up front: d small [T]
-        # reductions measure cheaper than one [T,T] all-reduce for the
-        # shallow conflict chains real batches carry (d is the chain
-        # depth, typically 1-3).
-        Of = O.astype(jnp.bfloat16)
-
-        def cond(carry):
-            _, changed = carry
-            return changed
-
-        def body(carry):
-            a, _ = carry
-            killed_local = jnp.dot(
-                a.astype(jnp.bfloat16), Of, preferred_element_type=jnp.float32
-            )
-            if axis_name is not None:
-                killed_local = jax.lax.psum(killed_local, axis_name)
-            killed = killed_local > 0.5
-            a_new = a0 & ~killed
-            return a_new, jnp.any(a_new != a)
-
-        accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
+    accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
 
     status = jnp.where(too_old, TOO_OLD, jnp.where(accepted, COMMITTED, CONFLICT))
     status = jnp.where(batch.txn_mask, status, CONFLICT)
@@ -715,15 +680,6 @@ def validate_params(params: ResolverParams):
         )
     if params.bucket_bits > 30 or params.hash_bits > 28:
         raise ValueError("bucket_bits/hash_bits unreasonably large")
-    if params.use_pallas_scan:
-        from foundationdb_tpu.ops.pallas_scan import MAX_TXNS
-
-        if params.txns > MAX_TXNS:
-            raise ValueError(
-                f"use_pallas_scan requires txns <= {MAX_TXNS}: the fused "
-                "kernel's txn-tile loops unroll at trace time (got "
-                f"{params.txns})"
-            )
     pb = params.ring_partition_bits
     if pb:
         if pb > params.bucket_bits:
@@ -736,12 +692,12 @@ def validate_params(params: ResolverParams):
                 "ring_capacity must divide evenly into 2^ring_partition_bits "
                 "sub-rings"
             )
-        if params.use_pallas or params.use_pallas_scan:
+        if params.use_pallas:
             raise ValueError(
-                "ring_partition_bits and use_pallas/use_pallas_scan are "
-                "mutually exclusive: the Pallas VMEM kernels implement "
-                "the FLAT ring layout (silently ignoring the explicit "
-                "pallas request would misattribute benchmarks)"
+                "ring_partition_bits and use_pallas are mutually "
+                "exclusive: the Pallas VMEM kernel implements the FLAT "
+                "ring layout (silently ignoring the explicit pallas "
+                "request would misattribute benchmarks)"
             )
 
 
@@ -1013,10 +969,10 @@ def validate_presharded_params(params: ResolverParams):
     T*RW <= KR wrap check does not apply: the kernel detects per-lane
     ring overflow at trace shapes and folds the excess into the coarse
     summaries instead of wrapping."""
-    if params.use_pallas or params.use_pallas_scan:
+    if params.use_pallas:
         raise ValueError(
-            "presharded resolve has no Pallas lanes: the VMEM kernels "
-            "implement the dense [T, K] layout (silently ignoring the "
+            "presharded resolve has no Pallas lanes: the VMEM kernel "
+            "implements the dense [T, K] layout (silently ignoring the "
             "explicit pallas request would misattribute benchmarks)"
         )
     if params.ring_partition_bits:
@@ -1052,35 +1008,28 @@ def scan_of(step_fn):
     return scan_step
 
 
-def make_resolve_scan_fn(params: ResolverParams, donate=True,
-                         keep_pallas=False):
+def make_resolve_scan_fn(params: ResolverParams, donate=True):
     """jit-compiled *multi-batch* resolver step: ``lax.scan`` threads the
     history through a stack of batches (leading axis B) in one dispatch.
 
-    By default the scan path runs the jnp ring lanes: measured on v5e,
-    the Pallas ring kernel wins the single-step latency path (~1.65x
-    faster kernel step — it is what make_resolve_fn uses) but loses
-    inside lax.scan on POINT workloads, where XLA overlaps the fused jnp
-    lanes across scan iterations better than it schedules repeated
-    pallas_call launches. ``keep_pallas=True`` keeps the Pallas ring
-    inside the scan — the right call when the ring walk dominates the
-    step (range-heavy workloads), where its VMEM tiling beats the
-    overlap XLA loses.
+    The scan runs the jnp ring lanes whatever ``use_pallas`` says: the
+    Pallas ring kernel belongs to the single-step program
+    (``make_resolve_fn``) only. The reason on record is that XLA overlaps
+    the fused jnp lanes across scan iterations where it would serialise
+    repeated ``pallas_call`` launches, so the ring kernel was expected to
+    win inside a scan only where the ring walk dominates the step
+    (range-heavy traffic); no cell has measured either (PERF.md §7,
+    "ring kernel against jnp lanes").
 
     Semantics are identical to calling ``resolve_batch`` B times in order
     — the scan carry is the same sequential state dependency — but one
-    dispatch covers the stack. ``use_pallas_scan`` is NOT stripped: the
-    fused accept kernel replaces the whole step body (ring + intra-batch
-    + acceptance), so there is no jnp/pallas split for XLA to schedule
-    around — the scan path keeps it whenever the params carry it. One
-    dispatch amortizes the host→device launch cost across B batches.
-    This is the proxy's throughput path; single-batch
-    ``make_resolve_fn`` is the latency path.
+    dispatch covers the stack and amortizes the host→device launch
+    cost across B batches. This is the proxy's throughput path;
+    single-batch ``make_resolve_fn`` is the latency path.
     Returns (state, statuses[B, T]).
     """
     validate_params(params)
-    if not keep_pallas:
-        params = params._replace(use_pallas=False)
+    params = params._replace(use_pallas=False)
     scan_step = scan_of(lambda s, b: resolve_batch(s, b, params))
     return jax.jit(scan_step, donate_argnums=(0,) if donate else ())
 

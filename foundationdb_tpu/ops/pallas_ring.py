@@ -18,13 +18,6 @@ transposed once to ``[W, Q]`` so the minor axis is the 128-lane axis.
 
 On non-TPU backends the kernel runs in interpreter mode — bit-identical,
 slow, which is exactly what the differential tests want.
-
-The fused whole-batch kernel (ops/pallas_scan.py, the ``pallas_scan``
-knob) imports this module's shared compare helpers — ``LANES``,
-``_signed``, ``_pairwise_lex``, ``_pad_axis`` — so the two kernels
-agree limb-for-limb on key ordering; when ``pallas_scan`` engages it
-subsumes these ring lanes and this kernel stands down for that
-resolver.
 """
 
 import functools

@@ -18,8 +18,8 @@ Two lane-ownership schemes, selected by ``knobs.resolver_sharding``:
   the lane(s) owning its key range (resolver/packing.ShardRouter — a
   vectorized cumsum pass over the packed arrays, no TxnRequest decode)
   and the device runs the COMPACTED per-lane slots
-  (ops/conflict.resolve_batch_presharded). Per-lane scan and pairwise
-  work shrink ~1/n — the path that makes k lanes faster than one.
+  (ops/conflict.resolve_batch_presharded). A lane's ring scan and
+  history shrink ~1/n; what four lanes cost beside one is in PERF.md.
 - ``"hash"``: the batch is replicated and each lane carves ownership
   in-kernel (hash-sharded point table, bucket-sharded ring). No host
   routing pass, but per-lane work never shrinks. No resolver
@@ -147,10 +147,6 @@ class MeshResolver(Resolver):
             (2, 4, BACKLOG_B)
             if jax.default_backend() == "cpu" else (BACKLOG_B,)
         )
-        # the fused-scan ladder extension is single-device only (the
-        # mesh never carries a Pallas route), so the chunk bound stays
-        # at the classic BACKLOG_B
-        self._scan_max_backlog = self._scan_pad_buckets[-1]
         self.adopt_profile(self.profile)  # attach the packer hooks
 
     def _split_counted(self, stacked):

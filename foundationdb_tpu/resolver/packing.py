@@ -333,7 +333,6 @@ class BatchPacker:
         self._row = np.dtype((np.void, 4 * params.key_width))
         self._iota = np.arange(4 * params.txns)
         self.flat_reuse_hits = 0
-        self.flat_reuse_misses = 0
         # device-path profiler hook (utils/deviceprofile.py): the
         # owning resolver attaches its DeviceProfile so staging-ring
         # reuse-vs-realloc events land in the cluster.device doc
@@ -386,7 +385,6 @@ class BatchPacker:
             ring = self._flat_rings[B] = []
             self._flat_ring_next[B] = 0
         if len(ring) < self.STAGING_RING:
-            self.flat_reuse_misses += 1
             if self.profile is not None:
                 self.profile.record_staging(hit=False)
             T, W = p.txns, p.key_width

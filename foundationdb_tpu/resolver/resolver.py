@@ -81,7 +81,7 @@ class ResolveHandle:
         return self._result
 
 
-def params_from_knobs(knobs, use_pallas=False, use_pallas_scan=False):
+def params_from_knobs(knobs, use_pallas=False):
     """The one knobs→ResolverParams mapping (Resolver and MeshResolver
     must size their kernels identically or verdicts drift)."""
     return ck.ResolverParams(
@@ -96,7 +96,6 @@ def params_from_knobs(knobs, use_pallas=False, use_pallas_scan=False):
         bucket_bits=knobs.coarse_buckets_bits,
         ring_partition_bits=knobs.ring_partition_bits,
         use_pallas=use_pallas,
-        use_pallas_scan=use_pallas_scan,
     )
 
 
@@ -104,7 +103,7 @@ def fast_params_of(params):
     """The point-specialized variant's params: range lanes statically
     off, point writes still recorded into the coarse summary the full
     kernel's future range reads consult. None when the config has no
-    range lanes to specialize away. Both Pallas routes are stripped:
+    range lanes to specialize away. The Pallas ring route is stripped:
     the point-only jnp step is a handful of gathers, and keeping the
     fallback machinery scoped to the FULL variant keeps its safety
     argument simple."""
@@ -112,7 +111,7 @@ def fast_params_of(params):
         return None
     return params._replace(
         range_reads=0, range_writes=0, use_pallas=False,
-        use_pallas_scan=False, record_point_coarse=True,
+        record_point_coarse=True,
     )
 
 
@@ -155,17 +154,7 @@ class Resolver:
                 # partitioned ring under "auto" downgrades to the jnp
                 # lanes (an explicit "on" is rejected by validate_params)
                 use_pallas = False
-            # the fused accept kernel (ops/pallas_scan.py) subsumes the
-            # ring kernel's lane when engaged, but only an explicit
-            # "on" engages it: the v5e's compiler refuses the kernel
-            # (tests/test_tpu_compile.py), so "auto" leaves the ring
-            # kernel serving the single-step full variant
-            use_pallas_scan = getattr(knobs, "pallas_scan", "auto") == "on"
-            if use_pallas_scan:
-                use_pallas = False  # mutually exclusive; scan wins
-            self.params = params_from_knobs(
-                knobs, use_pallas=use_pallas,
-                use_pallas_scan=use_pallas_scan)
+            self.params = params_from_knobs(knobs, use_pallas=use_pallas)
             self.packer = BatchPacker(self.params)
             self.state = ck.init_state(self.params)
             self._resolve = ck.make_resolve_fn(self.params)
@@ -194,19 +183,11 @@ class Resolver:
             # compute, so on an interpreter-hosted (cpu) device — where
             # a scan compile is cheap — small backlogs pay a fraction of
             # the fixed B=8 dispatch cost; the TPU's compiler takes
-            # seconds per scan, so one bucket only there. The
-            # fused-kernel path extends the ladder to 16/32: the PR 8
-            # bucket_histogram showed deep backlogs chunked into 8s pay
-            # repeated dispatch overhead the single wider scan avoids,
-            # and pad waste on the odd sizes stays bounded (gated by
-            # BENCH_MODE=kernel_smoke's pad_waste_pct threshold).
+            # seconds per scan, so one bucket only there.
             self._scan_pad_buckets = (
-                ((2, 4, 8, 16, 32) if use_pallas_scan else (2, 4, BACKLOG_B))
+                (2, 4, BACKLOG_B)
                 if jax.default_backend() == "cpu" else (BACKLOG_B,)
             )
-            # deep-backlog chunk bound for resolve_many: the widest
-            # bucket the ladder will pad to in one scan dispatch
-            self._scan_max_backlog = self._scan_pad_buckets[-1]
         elif self.backend == "cpu":
             self.cset = CpuConflictSet()
             self.cset.window_start = base_version
@@ -390,7 +371,7 @@ class Resolver:
                 status = np.asarray(status)
             return status[:n].tolist(), enq.seconds + rdb.seconds
         except Exception as e:
-            if (not (self.params.use_pallas or self.params.use_pallas_scan)
+            if (not self.params.use_pallas
                     or resolve_fn is not self._resolve
                     or not _is_pallas_fallback_error(e)):
                 raise  # pallas only runs in the full variant; non-JAX
@@ -399,36 +380,29 @@ class Resolver:
             return None, enq.seconds + rdb.seconds
 
     def _engage_pallas_fallback(self, commit_version):
-        """A Pallas kernel (ring lane or the fused scan) failed to
-        build/run on this backend: fall back to the jnp path for the
-        life of the resolver rather than failing every commit. The
-        device history may be donated/poisoned by the failed dispatch,
-        so restart fenced exactly like a recruited resolver — the
-        in-flight batch (and any read version from before the fence)
-        retries TOO_OLD with fresh reads."""
+        """The Pallas ring kernel failed to build/run on this backend:
+        fall back to the jnp path for the life of the resolver rather
+        than failing every commit. The device history may be
+        donated/poisoned by the failed dispatch, so restart fenced
+        exactly like a recruited resolver — the in-flight batch (and
+        any read version from before the fence) retries TOO_OLD with
+        fresh reads."""
         from foundationdb_tpu.utils.trace import TraceEvent
 
-        name = ("PallasScanFallback" if self.params.use_pallas_scan
-                else "PallasRingFallback")
-        TraceEvent(name, severity=30).detail(
+        TraceEvent("PallasRingFallback", severity=30).detail(
             fenced_at=commit_version).log()
         self._m_pallas_fallbacks.inc()
         self.profile.record_fallback("pallas_to_jit")
-        self.params = self.params._replace(use_pallas=False,
-                                           use_pallas_scan=False)
+        self.params = self.params._replace(use_pallas=False)
         self._resolve = ck.make_resolve_fn(self.params)
-        self._scan_fns = {}  # compiled scans baked the pallas step in
         self.state = ck.init_state(self.params)
         self.base_version = commit_version
 
     def _kernel_route(self, use_fast, scan=False):
         """Which per-batch step body actually serves this dispatch —
         the device profiler's kernel-route taxonomy. The fast variant
-        strips both Pallas flags (fast_params_of); the multi-batch scan
-        strips use_pallas (make_resolve_scan_fn) but keeps the fused
-        scan kernel."""
-        if not use_fast and self.params.use_pallas_scan:
-            return "pallas_scan"
+        (fast_params_of) and the multi-batch scan (make_resolve_scan_fn)
+        both strip use_pallas."""
         if not use_fast and not scan and self.params.use_pallas:
             return "pallas_ring"
         return "jit"
@@ -603,15 +577,14 @@ class Resolver:
             result = [self.resolve(t, cv, ws) for t, cv, ws in batches]
             self.dispatch_wall_s += _time.perf_counter() - t0
             return ResolveHandle(result=result)
-        if len(batches) > self._scan_max_backlog:
+        if len(batches) > BACKLOG_B:
             # Oversized backlog — the overload case this path exists for.
-            # Chunk into max-bucket-wide scans (each one dispatch) instead
+            # Chunk into BACKLOG_B-wide scans (each one dispatch) instead
             # of collapsing to per-batch round trips: throughput stays
             # scan-bound, not RTT-bound, no matter how deep the queue.
-            chunk_b = self._scan_max_backlog
             handles = [
-                self._dispatch_many(batches[i:i + chunk_b])
-                for i in range(0, len(batches), chunk_b)
+                self._dispatch_many(batches[i:i + BACKLOG_B])
+                for i in range(0, len(batches), BACKLOG_B)
             ]
             return ResolveHandle(materialize=lambda: [
                 statuses for h in handles for statuses in h.wait()
@@ -674,14 +647,8 @@ class Resolver:
         # noise against the round trip this dispatch saves; pads come
         # from the packer's cached template, not a fresh pack). The
         # flat path buckets instead (_dispatch_flat) — variable padded
-        # shapes are part of its staging design. The fused-scan path
-        # rides the full ladder both ways: deep backlogs pad up
-        # (16/32) instead of chunking, shallow ones pad down (2/4) —
-        # pad batches are whole wasted kernel launches there, and the
-        # kernel ladder only widens on cpu where compiles are cheap.
-        B = self._pad_bucket(len(packed))
-        if not self.params.use_pallas_scan:
-            B = max(BACKLOG_B, B)
+        # shapes are part of its staging design.
+        B = BACKLOG_B
         last_cv, last_ws = batches[-1][1], batches[-1][2]
         if len(packed) < B:
             pad = packer.pack_empty(self.base_version, last_cv, last_ws)
@@ -702,19 +669,8 @@ class Resolver:
         t0 = _time.perf_counter()
         try:
             self.state, st = scan_fn(self.state, stacked)
-        except Exception as e:
-            # the scan bakes the fused Pallas step into its body
-            # (make_resolve_scan_fn strips only use_pallas): a lowering
-            # error here engages the same fenced fallback as the
-            # single-batch route, and the whole backlog answers TOO_OLD
+        finally:
             self.dispatch_wall_s += _time.perf_counter() - t0
-            if (use_fast or not self.params.use_pallas_scan
-                    or not _is_pallas_fallback_error(e)):
-                raise
-            self._engage_pallas_fallback(last_cv)
-            return ResolveHandle(
-                result=[[TOO_OLD] * len(s) for s, _, _, _ in per_batch])
-        self.dispatch_wall_s += _time.perf_counter() - t0
         self.profile.record_kernel_route(
             self._kernel_route(use_fast, scan=True), n=len(per_batch))
         if prof:
@@ -798,16 +754,8 @@ class Resolver:
         t0 = _time.perf_counter()
         try:
             self.state, st = scan_fn(self.state, stacked)
-        except Exception as e:
-            # same fenced Pallas fallback as _dispatch_many's scan site
+        finally:
             self.dispatch_wall_s += _time.perf_counter() - t0
-            if (use_fast or not self.params.use_pallas_scan
-                    or not _is_pallas_fallback_error(e)):
-                raise
-            self._engage_pallas_fallback(batches[-1][1])
-            return ResolveHandle(
-                result=[[TOO_OLD] * len(f) for f in flats])
-        self.dispatch_wall_s += _time.perf_counter() - t0
         self.profile.record_kernel_route(
             self._kernel_route(use_fast, scan=True), n=len(flats))
         if prof:

@@ -10,8 +10,8 @@ attempts rather than hammered.
 One :class:`FailureMonitor` per process (``monitor()``), keyed by
 ``"host:port"`` address. The read router (`service._RemoteStorage`)
 filters known-failed workers, the keepalive pinger marks idle links,
-and the monitor's snapshot surfaces in ``cluster.health`` + the bench
-e2e lines (``rpc_timeouts`` / ``endpoints_failed``).
+and the monitor's snapshot surfaces in ``cluster.health``
+(``rpc_timeouts`` / ``endpoints_failed``).
 
 Probe timing reads the injected clock (core/deterministic.py), so a
 simulated monitor — if one is ever driven — replays with the seed.
@@ -40,7 +40,7 @@ class FailureMonitor:
         self.probe_max_s = float(probe_max_s)
         self._lock = lockdep.lock("FailureMonitor._lock")
         self._failed = {}  # addr -> {since, reason, probe_at, probe_delay}
-        # cumulative counters for bench/health (never reset by marks)
+        # cumulative counters for cluster.health (never reset by marks)
         self._rpc_timeouts = 0
         self._endpoints_failed = 0
 
@@ -131,7 +131,7 @@ class FailureMonitor:
             }
 
     def reset(self):
-        """Test/bench isolation: forget marks AND counters."""
+        """Test isolation: forget marks AND counters."""
         with self._lock:
             self._failed.clear()
             self._rpc_timeouts = 0

@@ -402,7 +402,7 @@ def serve_cluster(cluster, host="127.0.0.1", port=0, max_workers=16,
     non-loopback interface (the surface includes management access)."""
     from foundationdb_tpu.rpc.storageworker import LogFeed
 
-    # test/bench chaos arming by knob: a non-empty seed wraps every NEW
+    # test chaos arming by knob: a non-empty seed wraps every NEW
     # client socket this process opens in the seeded fault injector
     # (rpc/chaos.py stays un-imported on the default "" path)
     chaos_seed = getattr(cluster.knobs, "rpc_chaos_seed", "")

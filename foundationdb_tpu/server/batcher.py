@@ -9,7 +9,7 @@ story: a 1-txn batch pads the kernel's T-lane to 0.1% occupancy.
 
 Two drive modes:
 
-- **thread** (live deployments, the e2e bench): a daemon batcher thread
+- **thread** (live deployments): a daemon batcher thread
   collects submissions for up to ``interval_s`` (or until ``max_batch``),
   then drives the inner proxy. Clients block on a CommitFuture. With
   ``knobs.commit_pipeline_depth > 1`` the drain loop is a bounded
@@ -177,10 +177,6 @@ class BatchingCommitProxy:
         self.stages = StageStats(registry=self.metrics)
         self._inflight = deque()  # [(chunks, _PipelinedGroup)] FIFO
         self._inflight_cv = lockdep.condition("BatchingCommitProxy._inflight_cv")
-        self._occ_level = 0
-        self._occ_t = time.perf_counter()
-        self._occ_busy = 0.0  # seconds with >=1 group in flight
-        self._occ_area = 0.0  # integral of in-flight count over busy time
         self._apply_thread = None
         if mode == "thread" and self.pipeline_depth > 1 \
                 and hasattr(inner, "commit_batches_begin"):
@@ -402,85 +398,6 @@ class BatchingCommitProxy:
                 )
 
     # ─────────────────────── pipeline executor ──────────────────────
-    def _occ_transition(self, new_level):
-        """Time-weighted in-flight accounting (under _inflight_cv):
-        ``pipeline_depth_effective`` is the average number of groups in
-        flight while the pipeline was busy — 1.0 means the stages never
-        actually overlapped, ~depth means the pipe stayed full."""
-        now = time.perf_counter()
-        if self._occ_level > 0:
-            dt = now - self._occ_t
-            self._occ_busy += dt
-            self._occ_area += self._occ_level * dt
-        self._occ_t = now
-        self._occ_level = new_level
-
-    @property
-    def pipeline_depth_effective(self):
-        with self._inflight_cv:
-            if self._occ_busy <= 0:
-                return 1.0
-            return round(self._occ_area / self._occ_busy, 2)
-
-    def stage_summary(self):
-        """Per-stage mean wall time (ms) + occupancy for the bench
-        artifact: pack (stage A host work: grant + batch build + limb
-        staging), dispatch (stage B's device scan call), resolve (the
-        host sync stall in stage C), apply (tlog push + storage apply +
-        settlement) — plus the pack-path split (flat columnar vs legacy
-        request batches), the mean flat bytes per packed batch, and the
-        packer's staging-buffer reuse hit rate."""
-        out = {
-            "stage_pack_ms": round(self.stages.mean_ms("pack"), 3),
-            "stage_dispatch_ms": round(self.stages.mean_ms("dispatch"),
-                                       3),
-            "stage_resolve_ms": round(self.stages.mean_ms("resolve"), 3),
-            "stage_apply_ms": round(self.stages.mean_ms("apply"), 3),
-            "pipeline_depth": self.pipeline_depth,
-            "pipeline_depth_effective": self.pipeline_depth_effective,
-        }
-        inner = self.inner
-        flat = getattr(inner, "pack_flat_batches", 0)
-        legacy = getattr(inner, "pack_legacy_batches", 0)
-        out["pack_path"] = (
-            "flat" if flat and not legacy else
-            "legacy" if legacy and not flat else
-            "mixed" if flat else "legacy"
-        )
-        out["pack_flat_batches"] = flat
-        out["pack_legacy_batches"] = legacy
-        # abort-aware batch scheduling decisions (server/scheduler.py):
-        # zero across the board when the knob is off — the fields ride
-        # anyway so a bench line always states whether scheduling ran
-        out["sched_batches"] = getattr(inner, "sched_batches", 0)
-        out["sched_reordered"] = getattr(inner, "sched_reordered_total", 0)
-        out["sched_deferred"] = getattr(inner, "sched_deferred_total", 0)
-        # which resolve path served this run: "range" (single-dispatch
-        # presharded mesh), "hash" (replicated-batch mesh), or "local"
-        # (single-lane / host fan-out) — so a bench line always states
-        # the path behind its lane_skew_pct numbers
-        resolvers = getattr(inner, "resolvers", ())
-        out["resolver_sharding"] = next(
-            (r.sharding for r in resolvers if hasattr(r, "sharding")),
-            "local")
-        out["resolver_lanes"] = sum(
-            getattr(r, "n_lanes", 1) for r in resolvers)
-        out["pack_bytes"] = round(
-            getattr(inner, "pack_bytes_total", 0) / max(flat, 1)
-        )
-        hits = misses = 0
-        for r in getattr(inner, "resolvers", ()):
-            fast = getattr(r, "_fast", None)
-            for pk in (getattr(r, "packer", None),
-                       fast[0] if fast else None):
-                if pk is not None:
-                    hits += pk.flat_reuse_hits
-                    misses += pk.flat_reuse_misses
-        out["pack_reuse_rate"] = (
-            round(hits / (hits + misses), 3) if hits + misses else 0.0
-        )
-        return out
-
     def _dispatch_wall(self):
         """The resolvers' cumulative device-dispatch wall time (the
         scan call inside resolve_many) — subtracted from the stage-A+B
@@ -510,11 +427,10 @@ class BatchingCommitProxy:
         # this thread dies; the stage timers record after the handoff
         with self._inflight_cv:
             self._inflight.append((group_chunks, pgroup))
-            self._occ_transition(len(self._inflight))
             self._inflight_cv.notify_all()
         # dispatch (stage B's scan call) accumulated on this same
         # thread inside begin: report it as its own stage so
-        # stage_pack_ms measures HOST PACKING (grant + batch build +
+        # the pack stage measures HOST PACKING (grant + batch build +
         # staging scatter), the stage the flat path exists to cut
         dispatch_s = max(0.0, self._dispatch_wall() - d0)
         self.stages.add("pack", max(0.0, pack_s - dispatch_s))
@@ -568,7 +484,6 @@ class BatchingCommitProxy:
             finally:
                 with self._inflight_cv:
                     self._inflight.popleft()
-                    self._occ_transition(len(self._inflight))
                     self._inflight_cv.notify_all()
 
     def _finish_group(self, group_chunks, pgroup):

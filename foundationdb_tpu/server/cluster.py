@@ -227,7 +227,7 @@ class Cluster:
         self.change_feeds = ChangeFeedRegistry()
         # ── cross-client batching (ref: CommitProxyServer commitBatcher) ──
         # "thread": a daemon batcher collects concurrent commits into
-        # shared-version batches (live deployments / e2e bench).
+        # shared-version batches (live deployments).
         # "manual": deterministic batching driven by the sim scheduler.
         # "sync": 1-txn batches, the degenerate pipeline.
         self.commit_pipeline = commit_pipeline

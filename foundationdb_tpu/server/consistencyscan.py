@@ -41,8 +41,7 @@ Four properties the scanner guarantees:
 * **Bounded cost.** ``consistency_scan_batch_keys`` bounds one batch,
   ``scan_rate_bytes_per_s`` defers the next batch until the last one's
   bytes have drained, and ``set_enabled(False)`` is the module kill
-  switch (BENCH_MODE=scan_smoke measures the enabled-vs-disabled
-  delta); the status doc stays readable when disabled.
+  switch; the status doc stays readable when disabled.
 """
 
 import collections

@@ -114,7 +114,7 @@ class ProxyFleet:
         for p in self.inners:
             p.close()
 
-    # ── aggregated counters (status json, bench) ──
+    # ── aggregated counters (status json) ──
     @property
     def commit_count(self):
         return sum(p.commit_count for p in self.inners)
@@ -151,30 +151,6 @@ class ProxyFleet:
         members section; each member shares its inner proxy's registry
         so batcher spans and proxy counters land in one document)."""
         return [p.metrics.snapshot() for p in self.inners]
-
-    def stage_summary(self):
-        """Fleet view of the members' commit-pipeline stage timings:
-        means across members, worst-case configured depth."""
-        sums = [m.stage_summary() for m in self.members
-                if hasattr(m, "stage_summary")]
-        if not sums:
-            return {}
-        out = {}
-        for k in sums[0]:
-            vals = [s[k] for s in sums]
-            if k == "pipeline_depth":
-                out[k] = max(vals)
-            elif k in ("pack_path", "resolver_sharding"):
-                # the members' dominant value; "mixed" when they differ
-                out[k] = vals[0] if len(set(vals)) == 1 else "mixed"
-            elif k == "resolver_lanes":
-                # every member fronts the same resolver fleet
-                out[k] = max(vals)
-            elif k in ("pack_flat_batches", "pack_legacy_batches"):
-                out[k] = sum(vals)
-            else:
-                out[k] = round(sum(vals) / len(vals), 3)
-        return out
 
     def __len__(self):
         return len(self.inners)

@@ -29,10 +29,9 @@ Three pieces, all cluster-owned so they survive txn-system recoveries:
   doc carrying a doctor verdict (``healthy | degraded | unavailable``),
   sorted reasons, and FDB-style ``messages``.
 
-``set_enabled(False)`` is the module kill switch (the health_smoke
-bench measures enabled-vs-disabled cost): the prober stops firing and
-``maybe_probe`` becomes a cheap no-op; the health DOC stays readable —
-turning off probes must not blind the doctor.
+``set_enabled(False)`` is the module kill switch: the prober stops
+firing and ``maybe_probe`` becomes a cheap no-op; the health DOC stays
+readable — turning off probes must not blind the doctor.
 """
 
 import threading
@@ -345,8 +344,7 @@ def build_health(cluster):
         "target_tps": rk.target_tps,
         "max_tps": rk.max_tps,
         "saturation": saturation,
-        # per-reason denial counters (registry-backed: survive recovery
-        # and show in benchdiff trajectories)
+        # per-reason denial counters (registry-backed: survive recovery)
         "admit_denied_tag": rk.metrics.counter("admit_denied_tag").value,
         "admit_denied_budget": rk.metrics.counter(
             "admit_denied_budget").value,

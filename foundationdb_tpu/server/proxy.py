@@ -157,16 +157,15 @@ class CommitProxy:
         self.change_feeds = change_feeds  # ChangeFeedRegistry | None
         self.commit_count = 0
         self.conflict_count = 0
-        # commit pack-path observability (ISSUE 3): how many request
-        # batches packed columnar vs legacy, and the flat bytes moved —
-        # stage_summary()/bench lines report these per run
+        # commit pack-path observability: how many request batches
+        # packed columnar vs legacy (tests/test_packing_flat.py holds
+        # the knob matrix to these)
         self.pack_flat_batches = 0
         self.pack_legacy_batches = 0
-        self.pack_bytes_total = 0
         # abort-aware batch scheduling (server/scheduler.py, knob
-        # commit_batch_scheduling): plain totals ride stage_summary /
-        # bench lines even with the metrics kill switch off; the
-        # registry counters feed status rollups
+        # commit_batch_scheduling): plain totals that count even with
+        # the metrics kill switch off; the registry counters feed
+        # status rollups
         self.sched_batches = 0
         self.sched_reordered_total = 0
         self.sched_deferred_total = 0
@@ -1058,7 +1057,6 @@ class CommitProxy:
         flat = self._try_build_flat(requests)
         if flat is not None:
             self.pack_flat_batches += 1
-            self.pack_bytes_total += flat.pack_bytes
             return flat
         self.pack_legacy_batches += 1
         if not all(getattr(r_, "wants_point_split", True)

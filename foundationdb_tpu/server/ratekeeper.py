@@ -82,8 +82,8 @@ class Ratekeeper:
         self.metrics = metrics_mod.MetricsRegistry("ratekeeper")
         # per-reason denial COUNTERS (not snapshot-time gauges): the
         # registry survives recovery, so throttle causes accumulate
-        # across incarnations and show in benchdiff trajectories — the
-        # signal the cluster doctor's saturation rollup reads
+        # across incarnations — the signal the cluster doctor's
+        # saturation rollup reads
         self._m_denied_tag = self.metrics.counter("admit_denied_tag")
         self._m_denied_budget = self.metrics.counter("admit_denied_budget")
 

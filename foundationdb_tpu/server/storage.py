@@ -169,7 +169,8 @@ class StorageServer(RangeReadInterface):
         self._m_range_reads = self.metrics.counter("range_reads")
         # multiplexed read batches (txn/futures.py ReadBatcher →
         # rpc read_batch endpoint): serve latency band, reads-per-RPC
-        # histogram, and the coalesce-rate counters bench lines report
+        # histogram, and the coalesce-rate counters (status json's
+        # cluster.metrics.rollups)
         self._m_read_batch = self.metrics.latency("read_batch")
         self._m_read_batch_keys = self.metrics.latency("read_batch_keys")
         self._m_read_batches = self.metrics.counter("read_batches")

@@ -74,7 +74,7 @@ def run_txn_repair(db, fn, stats=None):
 
 def tpcc_workload(db, n_districts, n_ops, rng, stats, prefix=b"tpcc/",
                   repair=True):
-    """New-order-shaped contention (the bench's tpcc client as a sim
+    """New-order-shaped contention (a tpcc client as a sim
     actor): RMW on a hot district counter + an order-row insert keyed
     by the read value + a blind stock update. The value-dependent hot
     read is exactly the shape the repair engine's digest check must

@@ -69,7 +69,7 @@ def site_covered(site, table):
 
 
 def coverage_report(fired_counts, table):
-    """The diff both the CLI and the bench gauges read:
+    """The diff the CLI reads:
 
     ``sites_total``/``sites_fired``/``coverage_pct`` count STATIC
     table entries (a wildcard entry counts fired when any of its codes

@@ -132,11 +132,9 @@ def main(argv=None):
     # Read-RPC latency under commit load: CPython schedules a waiting
     # thread only every sys.getswitchinterval() (default 5ms), so a
     # read RPC landing while a commit batch holds this process's GIL
-    # waits out the slice. Measured on the multiproc bench harness:
-    # 223us/read idle, 5.6ms under write load at the default interval,
-    # 4.2ms at 0.5ms — the residue is GIL convoy on both ends of the
-    # synchronous read (see bench.py e2e_multiproc_bottleneck). Commit
-    # throughput is unaffected (its hot sections are numpy/C calls).
+    # waits out the slice; what is left at a shorter interval is the
+    # convoy on both ends of the synchronous read (what a read waits
+    # for on the chip: PERF.md, rpc.read.queue_wait_ms).
     # Tunable as the server_switch_interval_s knob / --switch-interval.
     switch_s = args.switch_interval
     if switch_s is None:

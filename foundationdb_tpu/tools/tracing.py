@@ -7,7 +7,7 @@ span trees and answers "where did the slow commits spend their time" —
 per-hop count/p50/p99/total, the hottest parent→child EDGE by total
 wall time, and the hottest pipeline STAGE (the ``stage.*`` spans mirror
 server/batcher.py's StageStats split, so the attribution here is
-cross-checkable against ``stage_summary()``'s hottest stage).
+cross-checkable against status json's ``hottest_stage`` rollup).
 
 Usage::
 
@@ -158,7 +158,7 @@ def hottest_edge(spans):
 def hottest_stage(spans):
     """Among the ``stage.*`` spans (the batcher's pack/dispatch/
     resolve/apply split), the stage with the most total wall time —
-    comparable 1:1 with stage_summary()'s hottest-stage attribution."""
+    comparable 1:1 with status json's ``hottest_stage`` rollup."""
     totals = {}
     for ev in spans:
         name = ev["span"]

@@ -11,42 +11,22 @@ manual-backoff extension).
 Jitter rides the ``"backoff-jitter"`` named deterministic stream
 (core/deterministic.py), so same-seed sims draw identical retry
 schedules and production gets real desynchronization for free.
-
-The module-level retry counter feeds the bench e2e lines
-(``backoff_retries``): a cheap, lock-guarded tally of every jittered
-sleep actually taken, snapshot-deltaed per run.
 """
 
 import time
 
 from foundationdb_tpu.core import deterministic
-from foundationdb_tpu.utils import lockdep
 
 _JITTER_STREAM = "backoff-jitter"
-
-_count_lock = lockdep.lock("backoff._count_lock")
-_retries = 0
-
-
-def retry_count():
-    """Cumulative process-wide count of backoff sleeps taken."""
-    with _count_lock:
-        return _retries
-
-
-def _note_retry():
-    global _retries
-    with _count_lock:
-        _retries += 1
 
 
 class Backoff:
     """Exponential backoff with seeded jitter, cap, reset-on-success.
 
     ``delay()`` returns the next jittered delay and advances the
-    schedule; ``sleep()`` additionally takes the sleep and bumps the
-    process retry counter. ``reset()`` re-arms the schedule after a
-    success, matching flow's ``Backoff::onSuccess``.
+    schedule; ``sleep()`` additionally takes the sleep. ``reset()``
+    re-arms the schedule after a success, matching flow's
+    ``Backoff::onSuccess``.
     """
 
     def __init__(self, initial_s=0.01, max_s=1.0, growth=2.0,
@@ -80,7 +60,6 @@ class Backoff:
     def sleep(self):
         """Take the next backoff sleep; returns the delay slept."""
         d = self.delay()
-        _note_retry()
         if d > 0.0:
             time.sleep(d)
         return d

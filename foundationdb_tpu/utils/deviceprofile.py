@@ -46,7 +46,7 @@ from foundationdb_tpu.utils import lockdep
 _enabled = True
 
 # the closed taxonomy: snapshot() emits every cause (zeros included) so
-# the doc's shape is stable and benchdiff can align rounds field-field
+# the doc's shape is stable for a reader that diffs two documents
 FALLBACK_CAUSES = (
     "pallas_to_jit",   # pallas ring kernel unavailable/failed -> jit
     "flat_to_legacy",  # flat batch mixed with legacy / width mismatch
@@ -146,10 +146,10 @@ class DeviceProfile:
         # fallback-cause taxonomy
         self.fallback_causes = {c: 0 for c in FALLBACK_CAUSES}
         # kernel-route dispatch records: which per-batch step body
-        # actually executed ("pallas_scan" | "pallas_ring" | "jit"),
-        # counted per live batch served — the ground truth behind
-        # bench.py's pallas_kernel_step stamp (the params flag alone is
-        # the REQUEST; a silent pallas_to_jit fallback must flip it)
+        # actually executed ("pallas_ring" | "jit"), counted per live
+        # batch served (the params flag alone is the REQUEST; a
+        # pallas_to_jit fallback flips the route) — status json's
+        # cluster.device.aggregate.kernel_routes, held by chip_smoke.py
         self.kernel_routes = {}
 
     # ── capture sites (all host-side, all gated) ──
@@ -476,7 +476,7 @@ def compile_log():
 
 def enter_process():
     """Called once by each process entry point (chip_smoke.py's
-    children, tools/fdbserver.py, bench.py) before JAX builds anything:
+    children, tools/fdbserver.py) before JAX builds anything:
     place the persistent compile cache, start counting builds, and hand
     ``utils/span.stage`` the profiler's annotation, so that a
     ``jax.profiler`` trace of this process carries ``fdb.<stage>``

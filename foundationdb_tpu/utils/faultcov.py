@@ -30,7 +30,7 @@ Design, mirroring ``utils/lockdep.py``:
   ``__init__`` are plumbing, not fabrication). Comprehension and
   lambda frames are skipped outward so attribution lands on the
   enclosing ``def`` — the same owner the static enumerator assigns.
-  Frames outside the package (tests, bench) and the excluded
+  Frames outside the package (tests) and the excluded
   propagation seam ``rpc/wire.py`` (it *deserializes* coded errors
   arriving off the wire — fabricated elsewhere) are not counted.
 * **Deterministic witness.** :func:`witness_doc` is canonical (sorted,
@@ -93,7 +93,7 @@ def disable():
 
 
 def reset():
-    """Drop all recorded fires (tests; between bench arms). The lazy
+    """Drop all recorded fires (tests). The lazy
     qualname cache survives — it is derived from source, not runs."""
     _counts.clear()
 
@@ -126,7 +126,7 @@ def qualname_index(tree):
 
 def _module_id(filename):
     """``server.storage`` for a file under the package dir, else None
-    (tests, bench, site-packages — not fabrication we enumerate)."""
+    (tests, site-packages — not fabrication we enumerate)."""
     mid = _module_ids.get(filename)
     if mid is not None or filename in _module_ids:
         return mid

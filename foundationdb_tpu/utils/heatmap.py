@@ -14,9 +14,7 @@ sim's step clock when seeded) and the storage sampling draws ride the
 byte-identical hot-range snapshots (FL001: no ambient entropy here).
 
 Overhead: the module-level ``set_enabled(False)`` kill switch turns
-every ``charge`` into an early return — ``BENCH_MODE=heatmap_smoke``
-runs the ycsb e2e both ways (interleaved pairs, medians compared) and
-gates the difference at 2%.
+every ``charge`` into an early return.
 """
 
 import heapq

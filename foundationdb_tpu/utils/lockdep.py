@@ -89,7 +89,7 @@ def disable():
 
 
 def reset():
-    """Drop all recorded state (tests; between bench arms)."""
+    """Drop all recorded state (tests)."""
     global _acquisitions, _quiet_streak, _frozen, _epoch
     with _graph_mu:
         _edges.clear()

@@ -148,8 +148,7 @@ class LatencySample:
 
     def bands_ms(self):
         """{count, mean_ms, p50_ms, p90_ms, p99_ms, max_ms} — the
-        latency-band snapshot every consumer (status json, bench lines)
-        shares. Monotone: percentiles index one sorted reservoir and
+        latency-band snapshot every consumer of status json shares. Monotone: percentiles index one sorted reservoir and
         max is the exact running max (≥ any reservoir entry)."""
         with self._lock:
             res = sorted(self._res)

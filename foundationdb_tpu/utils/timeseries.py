@@ -33,11 +33,9 @@ verdict — gets a trajectory here:
   byte-identically across same-seed sims: every stamp is
   injected-clock time and serialization is sorted-key.
 
-``set_enabled(False)`` is the module kill switch (BENCH_MODE=
-history_smoke measures the enabled-vs-disabled cost against the ≤2%
-budget): ``maybe_collect`` becomes a cheap no-op while already-
-collected windows stay readable — turning history off must not blind
-the reader.
+``set_enabled(False)`` is the module kill switch: ``maybe_collect``
+becomes a cheap no-op while already-collected windows stay readable —
+turning history off must not blind the reader.
 """
 
 import json

@@ -38,7 +38,7 @@ class TraceLog:
     File sinks ROLL (ref: flow/Trace.cpp rolled trace files): when the
     open file passes ``max_file_bytes``, it rotates to ``path.1`` (older
     rolls shift to ``.2`` … ``.roll_count``, the oldest is deleted) so a
-    long bench or sim run never grows one unbounded file. The in-memory
+    long sim run never grows one unbounded file. The in-memory
     ring buffer is kept ALONGSIDE any open file sink, so ``events()``
     keeps working for tests even when a path is set.
     """
@@ -149,7 +149,7 @@ class TraceLog:
         if event["severity"] < self.min_severity:
             return
         # serialization is deferred until a file sink provably needs a
-        # line: ring-only sinks (tests, benches) skip json.dumps — a
+        # line: ring-only sinks (tests) skip json.dumps — a
         # measured per-event cost at tracing-level volumes
         line = None
         if self._path is not None:
@@ -210,16 +210,15 @@ class StageStats:
     commit path's pack / resolve / apply stages). The batcher feeds it
     from two threads — the producer times stage A+B, the apply worker
     times stage C — so accumulation is lock-protected; reads take a
-    consistent snapshot. The bench surfaces ``summary()`` so per-stage
-    cost (and which stage is critical-path) lands in the artifact."""
+    consistent snapshot."""
 
     def __init__(self, registry=None):
         self._lock = lockdep.lock("StageStats._lock")
         self._total_s = {}
         self._count = {}
         # optional metrics registry: every add() also records into a
-        # per-stage LatencySample, so the bench's stage means gain
-        # latency BANDS in status json without a second timing site
+        # per-stage LatencySample: the ``stage_*`` latency BANDS of
+        # status json, which the benchmark's readers take deltas of
         self._registry = registry
         self._bands = {}
 
@@ -236,11 +235,6 @@ class StageStats:
                     "stage_" + stage.replace(".", "_")
                 )
             band.record(seconds)
-
-    def mean_ms(self, stage):
-        with self._lock:
-            n = self._count.get(stage, 0)
-            return (self._total_s.get(stage, 0.0) / n * 1e3) if n else 0.0
 
     def summary(self):
         """{stage: mean ms per observation} for every recorded stage."""

@@ -40,7 +40,7 @@ def test_snapshot_shape_and_taxonomy_zeros():
     assert snap["lane_skew_pct"] == 0.0
     assert snap["staging_reuse_rate"] == 0.0
     # the taxonomy is CLOSED and fully emitted: zeros included, so the
-    # doc's shape is stable and benchdiff aligns rounds field-by-field
+    # doc's shape is stable for readers that diff two status documents
     assert set(snap["fallback_causes"]) == set(FALLBACK_CAUSES)
     assert all(v == 0 for v in snap["fallback_causes"].values())
     json.dumps(snap)  # JSON-ready

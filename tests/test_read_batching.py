@@ -93,6 +93,61 @@ def test_async_reads_match_sync_reads(remote_db):
     assert rc.read_batcher.batches_sent >= 1
 
 
+def test_a_window_of_async_reads_goes_out_in_fewer_rpcs_than_reads(tmp_path):
+    """Over a real ``fdbserver`` process: windows of ``get_async``
+    issued before any wait ride multiplexed ``read_batch`` RPCs — the
+    client sends fewer batches than reads, the values are the sync
+    path's, and the server's rollups saw multi-key batches."""
+    import signal
+    import subprocess
+
+    import foundationdb_tpu as fdb
+
+    cluster_file = str(tmp_path / "fdb.cluster")
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "foundationdb_tpu.tools.fdbserver",
+         "--listen", "127.0.0.1:0", "--cluster-file", cluster_file,
+         "--dir", str(tmp_path / "data"), "--resolver-backend", "cpu"],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    db = None
+    try:
+        line = proc.stdout.readline()
+        assert "FDBD listening" in line, line
+        # the thread pipeline is what gives a remote client its flusher
+        db = fdb.open(cluster_file=cluster_file, commit_pipeline="thread")
+        keys = [b"win%03d" % i for i in range(64)]
+
+        def load(tr):
+            for i, k in enumerate(keys):
+                tr[k] = b"v%03d" % i
+
+        db.run(load)
+        rb = db._cluster.read_batcher
+        ops0, batches0 = rb.ops_sent, rb.batches_sent
+        for _ in range(4):
+            tr = db.create_transaction()
+            futs = [tr.get_async(k) for k in keys]
+            assert [f.wait() for f in futs] == [
+                b"v%03d" % i for i in range(64)]
+        ops, batches = rb.ops_sent - ops0, rb.batches_sent - batches0
+        assert ops == 4 * len(keys)
+        assert 0 < batches < ops
+        roll = db.status()["cluster"]["metrics"]["rollups"]
+        assert roll["batched_reads"] >= ops
+        assert roll["read_batch_coalesce_rate"] > 1.0
+        assert roll["read_batch_size_p99"] > 1.0
+    finally:
+        if db is not None:
+            db._cluster.close()
+        proc.send_signal(signal.SIGTERM)
+        try:
+            proc.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
+
+
 def test_async_reads_see_own_writes(remote_db):
     """RYW through the async forms: a key set in this txn resolves
     from the write set without touching the wire."""

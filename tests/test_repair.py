@@ -240,7 +240,8 @@ def test_wire_roundtrips_conflict_version():
 
 
 # ──────────────────── sim differential (ISSUE 6) ───────────────────
-def _run_tpcc_sim(seed, tmp_path, tag, repair, engine="memory"):
+def _run_tpcc_sim(seed, tmp_path, tag, repair, engine="memory",
+                  rollups=None):
     sim = Simulation(
         seed=seed, buggify=False, crash_p=0.0, engine=engine,
         datadir=str(tmp_path / f"tpcc-{tag}"),
@@ -260,6 +261,8 @@ def _run_tpcc_sim(seed, tmp_path, tag, repair, engine="memory"):
     sim.quiesce()
     tpcc_check(sim.db, n_districts, stats)
     state = tuple(sim.db.get_range(b"tpcc/", b"tpcc0"))
+    if rollups is not None:
+        rollups.update(sim.cluster.metrics_status()["rollups"])
     sim.close()
     return stats, state
 
@@ -281,6 +284,24 @@ def test_repair_differential_serializability_equivalent(engine, tmp_path):
     # conflicts and at least some were repaired
     assert s_rep.get("conflicts", 0) > 0
     assert s_rep.get("repairs", 0) > 0
+
+
+def test_repair_counters_reach_status_on_the_contended_tpcc_sim(tmp_path):
+    """End to end on the contended tpcc shape: every conflict the
+    clients met was a repair attempt the cluster counted, the
+    value-dependent district read made some of them fall back to a
+    re-run, and with repair off the counters stay at zero."""
+    roll = {}
+    stats, _ = _run_tpcc_sim(5, tmp_path, "roll-on", repair=True,
+                             rollups=roll)
+    assert stats["conflicts"] > 0
+    assert roll["repair_attempts"] > 0
+    assert roll["repair_attempts"] <= stats["conflicts"]
+    assert 0 < roll["repair_fallbacks"] <= roll["repair_attempts"]
+    assert roll["repair_commits"] > 0
+    off = {}
+    _run_tpcc_sim(5, tmp_path, "roll-off", repair=False, rollups=off)
+    assert off["repair_attempts"] == off["repair_fallbacks"] == 0
 
 
 def test_repair_sim_is_deterministic(tmp_path):

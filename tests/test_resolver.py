@@ -505,42 +505,124 @@ def test_resolve_many_chunks_oversized_backlog():
     assert resolved["n"] <= 3
 
 
-def test_pallas_scan_matches_jnp_scan():
-    """keep_pallas=True keeps the Pallas ring inside lax.scan (the
-    range-mode throughput path): statuses must be bit-identical to the
-    jnp-lane scan on mixed workloads (interpret mode off-TPU)."""
-    import jax
+RING_KNOBS = dict(
+    resolver_backend="tpu", batch_txn_capacity=8, point_reads_per_txn=2,
+    point_writes_per_txn=2, range_reads_per_txn=1, range_writes_per_txn=1,
+    key_limbs=2, hash_table_bits=12, range_ring_capacity=32,
+    coarse_buckets_bits=6,
+)
 
-    rng = random.Random(13)
-    version = 100
-    batches = []
-    for _ in range(6):
-        txns = []
-        for _ in range(rng.randrange(2, SMALL.txns + 1)):
-            t = rand_txn(rng, 25, version - rng.randrange(0, 20))
-            if rng.random() < 0.5:
-                a, b = sorted([b"k%04d" % rng.randrange(25),
-                               b"k%04d" % rng.randrange(25)])
-                t.range_writes.append((a, b + b"\xff"))
-            if rng.random() < 0.5:
-                a, b = sorted([b"k%04d" % rng.randrange(25),
-                               b"k%04d" % rng.randrange(25)])
-                t.range_reads.append((a, b + b"\xff"))
-            txns.append(t)
-        version += rng.randrange(1, 8)
-        batches.append((txns, version, max(0, version - 50)))
 
-    def run_scan(keep_pallas):
-        params = SMALL._replace(use_pallas=True)
-        packer = BatchPacker(params)
-        packed = [packer.pack(t, 0, cv, ws) for t, cv, ws in batches]
-        stacked = jax.tree.map(lambda *xs: np.stack(xs), *packed)
-        scan = ck.make_resolve_scan_fn(params, donate=False,
-                                       keep_pallas=keep_pallas)
-        _, st = scan(ck.init_state(params), stacked)
-        return np.asarray(st).tolist()
+def _ring_resolver(mode, **over):
+    from foundationdb_tpu.core.options import Knobs
+    from foundationdb_tpu.resolver.resolver import Resolver
 
-    assert run_scan(True) == run_scan(False)
+    return Resolver(Knobs(**dict(RING_KNOBS, **over), pallas_ring=mode))
+
+
+def test_forced_lowering_error_lands_in_pallas_to_jit(monkeypatch):
+    """A ring kernel that fails to build engages the fenced fallback:
+    the in-flight batch answers TOO_OLD, the failure is counted once
+    under the pallas_to_jit cause, the Pallas flag strips, and the
+    resolver goes on resolving on the jnp lanes with the verdicts of
+    the host oracle (resolver/skiplist.py)."""
+    from foundationdb_tpu.ops import pallas_ring
+
+    def boom(*a, **kw):
+        raise NotImplementedError("forced mosaic lowering failure")
+
+    monkeypatch.setattr(pallas_ring, "ring_hits", boom)
+    r = _ring_resolver("on")
+    assert r.params.use_pallas
+    # a range write forces the FULL variant (the only one with Pallas)
+    first = [TxnRequest(read_version=100, range_writes=[(b"a", b"b")])]
+    assert r.resolve(first, 110, 0) == [TOO_OLD]
+    assert not r.params.use_pallas
+    assert r.profile.snapshot()["fallback_causes"]["pallas_to_jit"] == 1
+    # fenced at the failed batch's commit version; from there on the
+    # jnp lanes answer as the oracle does, range lanes included
+    oracle = CpuConflictSet()
+    oracle.window_start = 110
+    later = [
+        ([TxnRequest(read_version=110, point_writes=[b"hot"]),
+          TxnRequest(read_version=110, range_writes=[(b"r0", b"r5")])],
+         120, 0),
+        ([TxnRequest(read_version=110, point_reads=[b"hot"]),
+          TxnRequest(read_version=120, point_reads=[b"hot"]),
+          TxnRequest(read_version=110, range_reads=[(b"r1", b"r2")]),
+          TxnRequest(read_version=120, range_reads=[(b"r1", b"r2")]),
+          TxnRequest(read_version=105, point_reads=[b"cold"])],
+         130, 0),
+    ]
+    got = [r.resolve(*b) for b in later]
+    assert got == [oracle.resolve(*b) for b in later]
+    assert got[1] == [CONFLICT, COMMITTED, CONFLICT, COMMITTED, TOO_OLD]
+    snap = r.profile.snapshot()
+    assert snap["fallback_causes"]["pallas_to_jit"] == 1  # counted once
+    assert "pallas_ring" not in snap["kernel_routes"]
+
+
+def test_ring_overflow_conservative_direction():
+    """Overflowing the version ring may only ever ABORT MORE (the
+    evicted entries fall into the coarse lanes): a stale read
+    overlapping an evicted range write must CONFLICT, and the ring
+    kernel must match the jnp lanes exactly while doing so."""
+
+    def run(mode):
+        r = _ring_resolver(mode, range_ring_capacity=16)
+        v = 100
+        # 3 batches x 8 txns x 2 range writes = 48 ring entries >> 16
+        for b in range(3):
+            txns = [
+                TxnRequest(
+                    read_version=v,
+                    range_writes=[
+                        (b"w%02d" % (b * 16 + 2 * i),
+                         b"w%02d" % (b * 16 + 2 * i + 1)),
+                        (b"x%02d" % (b * 16 + 2 * i),
+                         b"x%02d" % (b * 16 + 2 * i + 1)),
+                    ],
+                )
+                for i in range(8)
+            ]
+            v += 5
+            r.resolve(txns, v, 0)
+        # stale reader overlapping the FIRST (long-evicted) write span
+        stale = TxnRequest(read_version=100, range_reads=[(b"w00", b"w01")])
+        fresh = TxnRequest(read_version=v, range_reads=[(b"w00", b"w01")])
+        return r, r.resolve([stale, fresh], v + 5, 0)
+
+    r_on, got_on = run("on")
+    assert got_on == run("off")[1]
+    assert got_on[0] == CONFLICT  # never a missed conflict
+    assert got_on[1] == COMMITTED  # read version above every write
+    snap = r_on.profile.snapshot()
+    assert snap["kernel_routes"].get("pallas_ring", 0) > 0
+    assert snap["fallback_causes"]["pallas_to_jit"] == 0
+
+
+def test_scanned_backlog_never_engages_the_ring_kernel(monkeypatch):
+    """``resolve_many`` scans a backlog on the jnp lanes whatever
+    ``pallas_ring`` says: with the ring kernel made to fail, a backlog
+    that carries ranges resolves, nothing falls back and every batch is
+    counted under the ``jit`` route."""
+    from foundationdb_tpu.ops import pallas_ring
+
+    def boom(*a, **kw):
+        raise NotImplementedError("the scan must not call the ring kernel")
+
+    monkeypatch.setattr(pallas_ring, "ring_hits", boom)
+    r = _ring_resolver("on")
+    mk = lambda v: [TxnRequest(read_version=v, range_writes=[(b"a", b"b")]),
+                    TxnRequest(read_version=v, range_reads=[(b"a", b"b")]),
+                    TxnRequest(read_version=v, point_writes=[b"p"])]
+    backlog = [(mk(100), 110, 0), (mk(105), 115, 0), (mk(115), 120, 0)]
+    oracle = CpuConflictSet()
+    assert r.resolve_many(backlog) == [oracle.resolve(*b) for b in backlog]
+    assert r.params.use_pallas  # still requested for the single step
+    snap = r.profile.snapshot()
+    assert snap["kernel_routes"] == {"jit": len(backlog)}
+    assert snap["fallback_causes"]["pallas_to_jit"] == 0
 
 
 def test_partitioned_ring_serializability_and_liveness():

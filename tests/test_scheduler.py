@@ -221,8 +221,8 @@ def test_stage_summary_carries_scheduler_counters():
     futs = [proxy.submit(w), proxy.submit(t)]
     proxy.flush()
     assert all(f.done() for f in futs)
-    s = proxy.stage_summary()
-    assert s["sched_batches"] == 1
-    assert s["sched_reordered"] == 2
-    assert s["sched_deferred"] == 0
+    inner = proxy.inner  # the batcher's window reached the proxy's plan
+    assert inner.sched_batches == 1
+    assert inner.sched_reordered_total == 2
+    assert inner.sched_deferred_total == 0
     cl.close()

@@ -1,14 +1,12 @@
 """The main path's device programs, compiled for a described v5e.
 
 The sandbox has no chip but it has the chip's compiler: these tests
-hand it the five programs a default-knob resolver can dispatch (the
+hand it the four programs a default-knob resolver can dispatch (the
 single-step full variant on the jnp lanes and with the Pallas ring
-kernel, the B=8 backlog scan in its fast and full variants, and the
-fused Pallas scan kernel) at ``Knobs()`` shapes, and the four-lane
-``shard_map`` step of ``--resolvers 4`` over the described 2x2 mesh.
-Five must compile; the fused kernel is refused by Mosaic, which is why
-``pallas_scan="auto"`` no longer selects it (resolver/resolver.py). A
-compile that passes is not a chip run — ``chip_smoke.py`` is.
+kernel, the B=8 backlog scan in its fast and full variants) at
+``Knobs()`` shapes, and the four-lane ``shard_map`` step of
+``--resolvers 4`` over the described 2x2 mesh. All five must compile.
+A compile that passes is not a chip run — ``chip_smoke.py`` is.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every xdist
@@ -139,13 +137,3 @@ def test_the_four_lane_step_compiles_for_v5e_with_no_scatter_into_t_by_t(
     scattered = [math.prod(map(int, dims.split(",")))
                  for dims in re.findall(r"= \w+\[([\d,]+)\]\S* scatter\(", text)]
     assert scattered and params.txns ** 2 not in scattered
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "Mosaic refuses ops/pallas_scan.py's fused kernel: infer-vector-layout:"
-    " unsupported shape cast, tpu.reshape (vector<128xi1>) ->"
-    " vector<128x1xi1>. The day this compiles, decide ROADMAP C2."))
-def test_fused_scan_kernel_compiles_for_v5e(one_chip, tpu_branch):
-    params = params_from_knobs(Knobs(batch_txn_capacity=128),
-                               use_pallas_scan=True)
-    _compile(ck.make_resolve_fn, params, one_chip)
