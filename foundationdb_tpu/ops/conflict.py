@@ -55,6 +55,8 @@ Versions are uint32 offsets from a host-held base (core/versions.py);
 version 0 means "no write recorded".
 """
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -984,8 +986,144 @@ def validate_presharded_params(params: ResolverParams):
         raise ValueError("bucket_bits/hash_bits unreasonably large")
 
 
+# ShardBatch fields every lane holds whole (everything else is a lane's
+# own compacted slot array, split on its leading axis)
+SHARD_REPLICATED = frozenset({"rv", "txn_mask", "cv", "new_window_start"})
+
+
+class ArgLayout(NamedTuple):
+    """What one packed row holds: the batch type, the lanes it is split
+    over (0: not split) and, for every field in order, its dtype char
+    and the shape one row carries (a lane's share of a split field).
+    Hashable: the jitted programs take it as a static argument."""
+
+    cls: type
+    lanes: int
+    fields: tuple
+
+
+def arg_layout(batch, lanes=0):
+    """The :class:`ArgLayout` of ``batch`` (a ``ResolveBatch`` or a
+    ``ShardBatch``, arrays or shapes), read off its fields' shapes;
+    axes ahead of ``rv``'s ``[T]`` are batch axes and belong to no row."""
+    lead = len(batch.rv.shape) - 1
+    fields = []
+    for name, a in zip(batch._fields, batch):
+        shape = tuple(a.shape)[lead:]
+        if lanes and name not in SHARD_REPLICATED:
+            shape = (shape[0] // lanes,) + shape[1:]
+        fields.append((np.dtype(a.dtype).char, shape))
+    return ArgLayout(type(batch), lanes, tuple(fields))
+
+
+@functools.lru_cache(maxsize=None)
+def _arg_spans(layout):
+    """((word offset, words, bytes) of every field, words a row): each
+    field starts on a word, so a bool field (one byte a slot) is padded
+    to a whole one."""
+    spans, at = [], 0
+    for char, shape in layout.fields:
+        nbytes = math.prod(shape) * np.dtype(char).itemsize
+        words = -(-nbytes // 4)
+        spans.append((at, words, nbytes))
+        at += words
+    return tuple(spans), at
+
+
+def arg_words(layout):
+    """uint32 words in one packed row."""
+    return _arg_spans(layout)[1]
+
+
+def pack_args(batch, layout):
+    """``batch`` as ONE host array: ``uint32[*lead, N]``, or
+    ``uint32[*lead, lanes, N]`` where row j is lane j's slots and the
+    replicated fields are written into every row. A field's bytes lie at
+    its static word offset as they lie in memory (int32 as its bits,
+    four mask bytes a word); ``unpack_args`` is the inverse, traced.
+
+    One ``bytes.join`` makes the copy, and the result is a fresh
+    read-only buffer nothing else writes (a CPU backend may alias host
+    memory). A numpy store of more than a few hundred elements gives the
+    interpreter lock up, and on the dispatching thread every such store
+    is a wait behind the request threads (PERF.md §6, PR 33); ``join``
+    over buffers that are not ``bytes`` never does."""
+    spans, N = _arg_spans(layout)
+    lead = tuple(batch.rv.shape[:-1])
+    rows, lanes = math.prod(lead), layout.lanes
+    cols = []
+    for name, a, (_, words, nbytes) in zip(batch._fields, batch, spans):
+        if not nbytes:
+            continue
+        a = np.ascontiguousarray(a)
+        split = lanes and name not in SHARD_REPLICATED
+        cols.append((a.reshape((rows, lanes, -1) if split else (rows, -1)),
+                     split, bytes(4 * words - nbytes)))
+    pieces = []
+    for r in range(rows):
+        for j in range(lanes or 1):
+            for a, split, pad in cols:
+                pieces.append(a[r, j] if split else a[r])
+                if pad:
+                    pieces.append(pad)
+    buf = np.frombuffer(b"".join(pieces), np.uint32)
+    return buf.reshape(lead + ((lanes,) if lanes else ()) + (N,))
+
+
+def unpack_args(buf, layout):
+    """The batch ``pack_args`` packed, from ``uint32[..., N]`` inside a
+    jitted function: static slices, a bitcast for int32, shifts for the
+    mask bytes. Leading axes of ``buf`` lead every field."""
+    lead = tuple(buf.shape[:-1])
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.uint32)
+    out = []
+    for (char, shape), (at, words, nbytes) in zip(
+            layout.fields, _arg_spans(layout)[0]):
+        x = buf[..., at:at + words]
+        if char == "?":
+            x = (x[..., None] >> shifts) & jnp.uint32(0xFF)
+            x = x.reshape(lead + (4 * words,))[..., :nbytes] != 0
+        elif char == "i":
+            x = jax.lax.bitcast_convert_type(x, jnp.int32)
+        out.append(x.reshape(lead + shape))
+    return layout.cls(*out)
+
+
+class PackedProgram:
+    """A jitted ``(state, buf, layout)`` resolve program behind the call
+    its callers make, ``(state, batch)``: the batch goes to the device
+    as the one array ``pack_args`` builds, whatever its fields."""
+
+    def __init__(self, jitted, lanes=0):
+        self.jitted = jitted
+        self.lanes = lanes
+
+    def __call__(self, state, batch):
+        layout = arg_layout(batch, self.lanes)
+        return self.jitted(state, pack_args(batch, layout), layout)
+
+    def trace(self, state, batch):
+        """``jitted.trace`` for a batch of arrays or of shapes."""
+        layout = arg_layout(batch, self.lanes)
+        lead = tuple(batch.rv.shape[:-1])
+        row = ((self.lanes,) if self.lanes else ()) + (arg_words(layout),)
+        return self.jitted.trace(
+            state, jax.ShapeDtypeStruct(lead + row, jnp.uint32), layout)
+
+    def lower(self, state, batch):
+        return self.trace(state, batch).lower()
+
+
 def make_resolve_fn(params: ResolverParams, donate=True):
-    """jit-compiled resolver step with the history buffers donated."""
+    """jit-compiled resolver step with the history buffers donated.
+
+    It takes the batch's fields as they are. One device pays 0.04 ms an
+    argument; handed the batch as one packed array the served step gave
+    no more operations a second and the read tail grew by a third: the
+    dispatching thread waits at this call for the request threads' turn
+    at the interpreter, not for the arguments (PERF.md §6, PR 33). The
+    mesh programs, where an argument costs 0.55 ms, take it packed
+    (parallel/mesh.py, :class:`PackedProgram`)."""
     validate_params(params)
     fn = lambda state, batch: resolve_batch(state, batch, params)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())

@@ -60,29 +60,6 @@ def _state_specs(axes=AXIS):
     )
 
 
-def _batch_specs():
-    return jax.tree.map(lambda _: P(), ck.ResolveBatch(*ck.ResolveBatch._fields))
-
-
-# ShardBatch fields that stay replicated across lanes (everything else
-# is a per-lane compacted slot array, sharded on its leading axis)
-_SHARD_REPLICATED = {"rv", "txn_mask", "cv", "new_window_start"}
-
-
-def _shard_batch_specs(axes=AXIS, scan=False):
-    """PartitionSpecs for a ShardBatch: entry slot arrays split on the
-    lane axis (leading dim n*Q → per-lane Q), verdict-fold inputs
-    replicated. ``scan=True`` shifts the lane axis behind the batch
-    axis (stacked [B, n*Q, ...] inputs for the scan path)."""
-
-    def spec(name):
-        if name in _SHARD_REPLICATED:
-            return P()
-        return P(None, axes) if scan else P(axes)
-
-    return ck.ShardBatch(*(spec(f) for f in ck.ShardBatch._fields))
-
-
 class ShardedResolverKernel:
     """The resolver fleet as one SPMD program.
 
@@ -107,28 +84,49 @@ class ShardedResolverKernel:
             ck.resolve_batch, params=params, axis_name=self.spec_axes,
             n_shards=self.n,
         )
-        sharded = jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(_state_specs(self.spec_axes), _batch_specs()),
-            out_specs=(P(), P(), _state_specs(self.spec_axes)),
-            check_vma=False,
-        )
-        self._step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
-        scan_sharded = jax.shard_map(
-            ck.scan_of(fn),
-            mesh=self.mesh,
-            in_specs=(_state_specs(self.spec_axes), _batch_specs()),
-            out_specs=(_state_specs(self.spec_axes), P()),
-            check_vma=False,
-        )
-        self._scan_step = jax.jit(
-            scan_sharded, donate_argnums=(0,) if donate else ()
-        )
+        # the batch is replicated: every lane unpacks the same row
+        def resolve_batch(state, row, layout):
+            return fn(state, ck.unpack_args(row, layout))
+
+        self._step, self._scan_step = self._packed_programs(
+            resolve_batch, (), donate)
         # make_state=False: a caller sharing state with a twin kernel
         # (MeshResolver's point-fast variant) skips the throwaway arrays
         self.state = self.init_state() if make_state else None
+
+    def _packed_programs(self, step, row_spec, donate, lanes=0):
+        """The kernel's two programs from ``step(state, row, layout)``,
+        a lane's view of one packed batch (``ck.pack_args``): the step
+        over ``P(*row_spec)`` and its scan over a stack of rows, each
+        ``jit(shard_map(...))`` with the layout static, behind
+        ``ck.PackedProgram``. A mesh program takes ONE host array
+        because sharding a host array over n devices costs per
+        argument, contended or not (0.55 ms each over four chips:
+        PERF.md §6, PR 33). The XLA modules keep the names ``step`` and
+        ``scan_step`` give them: the benchmark finds them by name."""
+        state_specs = _state_specs(self.spec_axes)
+
+        def scan_step(state, rows, layout):
+            return ck.scan_of(functools.partial(step, layout=layout))(
+                state, rows)
+
+        def program(body, buf_spec, out_specs):
+            @functools.wraps(body)
+            def sharded(state, buf, layout):
+                return jax.shard_map(
+                    functools.partial(body, layout=layout), mesh=self.mesh,
+                    in_specs=(state_specs, buf_spec), out_specs=out_specs,
+                    check_vma=False,
+                )(state, buf)
+
+            return ck.PackedProgram(
+                jax.jit(sharded, static_argnums=2,
+                        donate_argnums=(0,) if donate else ()),
+                lanes=lanes)
+
+        return (program(step, P(*row_spec), (P(), P(), state_specs)),
+                program(scan_step, P(None, *row_spec), (state_specs, P())))
 
     def init_state(self):
         p, n = self.params, self.n
@@ -197,25 +195,13 @@ class PreshardedResolverKernel(ShardedResolverKernel):
             ck.resolve_batch_presharded, params=params,
             axis_name=self.spec_axes,
         )
-        sharded = jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(_state_specs(self.spec_axes),
-                      _shard_batch_specs(self.spec_axes)),
-            out_specs=(P(), P(), _state_specs(self.spec_axes)),
-            check_vma=False,
-        )
-        self._step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
-        scan_sharded = jax.shard_map(
-            ck.scan_of(fn),
-            mesh=self.mesh,
-            in_specs=(_state_specs(self.spec_axes),
-                      _shard_batch_specs(self.spec_axes, scan=True)),
-            out_specs=(_state_specs(self.spec_axes), P()),
-            check_vma=False,
-        )
-        self._scan_step = jax.jit(
-            scan_sharded, donate_argnums=(0,) if donate else ()
-        )
+        # a lane's share of the packed batch is its own row: [1, N] of
+        # [n, N] for a step, [B, 1, N] of [B, n, N] for a scan
+        def resolve_batch_presharded(state, row, layout):
+            return fn(state, ck.unpack_args(row[0], layout))
+
+        self._step, self._scan_step = self._packed_programs(
+            resolve_batch_presharded, (self.spec_axes,), donate,
+            lanes=self.n)
         self.state = self.init_state() if make_state else None

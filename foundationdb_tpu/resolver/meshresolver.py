@@ -198,6 +198,11 @@ class MeshResolver(Resolver):
         kernel = self._fast_kernel if use_fast else self._kernel
         return kernel._scan_step
 
+    def _h2d_args(self, batch):
+        """One: every mesh program takes its batch as the single array
+        ``ops/conflict.pack_args`` builds (parallel/mesh.py)."""
+        return 1
+
     def _profile_lanes(self, statuses):
         """Per-lane dispatch wall for one mesh dispatch (ROADMAP item
         4's lane-utilization skew, measured). The verdicts are

@@ -363,6 +363,7 @@ class Resolver:
             with enq:
                 status, _accepted, self.state = resolve_fn(self.state,
                                                            batch)
+            self.profile.count(h2d_args=self._h2d_args(batch))
             # materialize INSIDE the try: dispatch is async, so a
             # kernel that compiles but faults at runtime only raises
             # here — outside, the fallback would never engage and
@@ -482,6 +483,12 @@ class Resolver:
                 or flat.rwc.max(initial=0) > p.range_writes):
             return "over_capacity"
         return "flat_to_legacy"  # limb-width mismatch
+
+    def _h2d_args(self, batch):
+        """Host arrays one dispatch hands its jitted program: the one
+        device's step takes the batch's fields as they are (MeshResolver
+        packs them into one: PERF.md §6, PR 33)."""
+        return len(batch)
 
     def _profile_lanes(self, statuses):
         """Per-lane dispatch-wall capture hook, called host-side at
@@ -674,6 +681,7 @@ class Resolver:
         self.profile.record_kernel_route(
             self._kernel_route(use_fast, scan=True), n=len(per_batch))
         if prof:
+            self.profile.count(h2d_args=self._h2d_args(stacked))
             self.profile.record_dispatch(
                 bucket=B, live_batches=len(per_batch),
                 live_txns=len(all_live), txn_slots=B * pp.txns,
@@ -759,6 +767,7 @@ class Resolver:
         self.profile.record_kernel_route(
             self._kernel_route(use_fast, scan=True), n=len(flats))
         if prof:
+            self.profile.count(h2d_args=self._h2d_args(stacked))
             self.profile.record_dispatch(
                 bucket=B, live_batches=len(flats),
                 live_txns=sum(len(f) for f in flats),

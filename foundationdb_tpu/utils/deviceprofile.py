@@ -66,12 +66,16 @@ STAGE_WALLS = {
     "resolver.route": "route_wall_s",
 }
 
-# the mesh router's plain counters (:meth:`DeviceProfile.count`, fed by
-# ``MeshResolver._split_counted``), each summed over dispatches: the
-# dispatches routed and their slices (k: 1 unless a lane overflowed),
-# the entries routed and, of them, those of the dispatch's fullest lane
-ROUTE_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
-                  "lane_entries_fullest")
+# the plain counters (:meth:`DeviceProfile.count`), each summed over
+# dispatches. The mesh router's, fed by ``MeshResolver._split_counted``:
+# the dispatches routed and their slices (k: 1 unless a lane
+# overflowed), the entries routed and, of them, those of the dispatch's
+# fullest lane. ``h2d_args``, counted by the resolver beside each
+# jitted call: the host arrays the dispatch handed its program (the
+# state is on the device already) — the batch's 22 fields on one
+# device, one array on a mesh (``ops/conflict.pack_args``)
+PLAIN_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
+                  "lane_entries_fullest", "h2d_args")
 
 
 def set_enabled(on):
@@ -141,7 +145,7 @@ class DeviceProfile:
         self.lane_entries = []
         self.lane_dispatches = 0
         self.route_wall_s = 0.0  # stage resolver.route: the router's split
-        for c in ROUTE_COUNTERS:
+        for c in PLAIN_COUNTERS:
             setattr(self, c, 0)
         # fallback-cause taxonomy
         self.fallback_causes = {c: 0 for c in FALLBACK_CAUSES}
@@ -246,7 +250,7 @@ class DeviceProfile:
             self.lane_dispatches += 1
 
     def count(self, **counters):
-        """Add to :data:`ROUTE_COUNTERS`."""
+        """Add to :data:`PLAIN_COUNTERS`."""
         if not _enabled:
             return
         with self._lock:
@@ -300,7 +304,7 @@ class DeviceProfile:
                 "fallback_causes": dict(other.fallback_causes),
                 "kernel_routes": dict(other.kernel_routes),
                 "route_wall_s": other.route_wall_s,
-                **{c: getattr(other, c) for c in ROUTE_COUNTERS},
+                **{c: getattr(other, c) for c in PLAIN_COUNTERS},
             }
         with self._lock:
             self.dispatches += o["dispatches"]
@@ -338,7 +342,7 @@ class DeviceProfile:
             for i, c in enumerate(o["lane_entries"]):
                 self.lane_entries[i] += c
             self.lane_dispatches += o["lane_dispatches"]
-            for c in ("route_wall_s",) + ROUTE_COUNTERS:
+            for c in ("route_wall_s",) + PLAIN_COUNTERS:
                 setattr(self, c, getattr(self, c) + o[c])
             for c, v in o["fallback_causes"].items():
                 self.fallback_causes[c] = (
@@ -401,7 +405,7 @@ class DeviceProfile:
                 "lane_entries": entries,
                 "lane_skew_pct": lane_skew,
                 "route_wall_ms": round(self.route_wall_s * 1e3, 3),
-                **{c: getattr(self, c) for c in ROUTE_COUNTERS},
+                **{c: getattr(self, c) for c in PLAIN_COUNTERS},
                 "fallback_causes": dict(sorted(
                     self.fallback_causes.items())),
                 "kernel_routes": dict(sorted(

@@ -1,6 +1,6 @@
 """The mesh router under YCSB's keys, and what it counts
 (resolver/meshresolver.py ``_split_counted``: stage ``resolver.route``
-and utils/deviceprofile.py ``ROUTE_COUNTERS``), on the 8 host devices
+and utils/deviceprofile.py ``PLAIN_COUNTERS``), on the 8 host devices
 conftest forces.
 
 ``ShardRouter`` splits the first limb uniformly, so every ``user%08d``

@@ -55,7 +55,7 @@ def _scatters(jaxpr):
             yield from _scatters(sub)
 
 
-@pytest.mark.parametrize("program", ["step", "scan"])
+@pytest.mark.parametrize("program", ["step", "scan", "one_lane_step"])
 def test_no_scatter_over_slot_pairs_and_the_programs_keep_their_names(
         mesh_step, program):
     params, kern, sb = mesh_step
@@ -63,13 +63,19 @@ def test_no_scatter_over_slot_pairs_and_the_programs_keep_their_names(
     if program == "step":
         fn, name = kern._step, "jit_resolve_batch_presharded"
         batch = jax.tree.map(lambda a: a[0], sb)
-    else:
+    elif program == "scan":
         fn, name = kern._scan_step, "jit_scan_step"
         batch = sb
+    else:
+        fn, name = ck.make_resolve_fn(params), "jit__lambda"
+        state = jax.eval_shape(lambda: ck.init_state(params))
+        batch = BatchPacker(params).pack_empty(0, 1, 0)
     T = params.txns
     # the widest slot array a lane holds: its point sides, or its ring
     most = max(sb.pr_hash.shape[-1] // LANES, params.ring_capacity)
-    found = list(_scatters(jax.make_jaxpr(fn)(state, batch).jaxpr))
+    # (a mesh program packs its batch on the way in: trace it as it
+    # lowers, through the one array, not through ``make_jaxpr``)
+    found = list(_scatters(fn.trace(state, batch).jaxpr.jaxpr))
     assert len(found) > 8  # the walk reached the step's body
     assert not [s for s in found if s[0] == (T, T) or s[1] > most], found
     assert fn.lower(state, batch).as_text().startswith(f"module @{name} ")

@@ -128,10 +128,12 @@ def test_the_four_lane_step_compiles_for_v5e_with_no_scatter_into_t_by_t(
     empty = BatchPacker(params).pack_empty(0, 1, 0)
     routed, _, _ = ShardRouter(params, lanes).split(
         jax.tree.map(lambda a: np.asarray(a)[None], empty))
-    batch = ck.ShardBatch(*(
-        placed(a[0], spec) for a, spec in zip(
-            routed, pm._shard_batch_specs(pm.AXIS))))
-    text = kern._step.lower(state, batch).compile().as_text()
+    # the one array the step takes: row j is lane j's share of the batch
+    layout = ck.arg_layout(jax.tree.map(lambda a: a[0], routed), lanes)
+    batch = jax.ShapeDtypeStruct(
+        (lanes, ck.arg_words(layout)), np.uint32,
+        sharding=NamedSharding(mesh, pm.P(pm.AXIS)))
+    text = kern._step.jitted.lower(state, batch, layout).compile().as_text()
     assert "HloModule jit_resolve_batch_presharded" in text
     assert "all-reduce" in text  # the verdict fold over ICI
     scattered = [math.prod(map(int, dims.split(",")))
