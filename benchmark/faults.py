@@ -18,7 +18,14 @@ reachable from the benchmark's command without ``--fault``.
   that leaves its state unchanged): an acknowledged write is not there
   to read back.
 - ``alter_read``: storage alters one byte of every 50th value it
-  serves for a user key (an answer altered where it is produced).
+  serves for a user key, by a point read or as a row of a range read
+  (an answer altered where it is produced).
+- ``drop_range_row``: storage leaves out every 50th row of the range
+  reads it serves for user keys: a scan that skips a record which is
+  there (the read-back too, as any range read).
+- ``no_conflict`` on a mix that reads ranges and writes into them turns
+  the range conflicts off with the point conflicts: a committed scan
+  that missed an insert or a clear reads ``phantom_rows``.
 """
 
 import itertools
@@ -120,21 +127,49 @@ def drop_apply():
     StorageServer.apply = patched
 
 
+def _rows_served(change):
+    """Patch the rows storage serves a range read of user keys with:
+    ``StorageServer._iter_live`` is what every range read pulls its
+    rows from, the router's across shards too. ``change(row)`` → the
+    row to serve, or None to leave it out."""
+    from foundationdb_tpu.server.storage import StorageServer
+
+    iter_live = StorageServer._iter_live
+
+    def patched(self, begin, end, version, reverse=False):
+        rows = iter_live(self, begin, end, version, reverse=reverse)
+        if begin >= b"\xff":
+            return rows
+        return (row for row in map(change, rows) if row is not None)
+
+    StorageServer._iter_live = patched
+
+
 def alter_read():
     from foundationdb_tpu.server.storage import StorageServer
 
     get, n = StorageServer.get, itertools.count(1)
 
+    def altered(value):
+        value = bytes(value)
+        return value[:-1] + bytes([value[-1] ^ 1])
+
     def patched(self, key, version):
         value = get(self, key, version)
         if value is not None and key < b"\xff" and next(n) % EVERY == 0:
-            value = bytes(value)
-            value = value[:-1] + bytes([value[-1] ^ 1])
+            value = altered(value)
         return value
 
     StorageServer.get = patched
+    _rows_served(lambda row: (row[0], altered(row[1]))
+                 if next(n) % EVERY == 0 else row)
+
+
+def drop_range_row():
+    n = itertools.count(1)
+    _rows_served(lambda row: row if next(n) % EVERY else None)
 
 
 FAULTS = {"no_conflict": no_conflict, "no_intra_batch": no_intra_batch,
-          "drop_apply": drop_apply,
-          "alter_read": alter_read}
+          "drop_apply": drop_apply, "alter_read": alter_read,
+          "drop_range_row": drop_range_row}

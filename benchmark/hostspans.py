@@ -26,9 +26,9 @@ At every instant of an idle gap exactly one name holds, by precedence:
 A trace that holds no ``fdb.*`` event at all (the parent commit's
 program, which has no annotation) keeps ``host.unattributed``.
 
-Nothing of the accepted benchmark calls this module yet: wiring it in
-takes three edits to files a ``tracing`` PR may not touch (PERF.md §7
-gives them); ``name_gaps`` is what ``reduce_events`` would then call.
+``served.py`` hands ``load``'s spans to ``tracereduce.reduce_events``,
+which ends in ``name_gaps``; ``readers.trace_idle_name_pct`` reads
+``idle_by_name`` through ``idle_name_pct``.
 """
 
 import glob

@@ -10,13 +10,16 @@ Evidence (``ev``): ``status0`` / ``status1``, the status documents at
 the two ends of the traced seconds (fetched by the tracer thread, so
 they span exactly what the trace spans); ``ops``, the clients' log
 rows of the operations acknowledged in the window (``client.py``'s
-layout: [kind, t_begin, t_ack, …, reads, writes]); ``trace``, what
+layout: [kind, t_begin, t_ack, …, reads, writes] and, where the
+operation read ranges, those ranges); ``trace``, what
 ``tracereduce.reduce_events`` returned; ``peaks``, the entry of
 ``peaks.json`` for this device kind; ``config``, the cell's config.
 """
 
 
 import math
+
+import hostspans
 
 
 def percentile(sorted_values, q):
@@ -133,8 +136,28 @@ def client_latency_ms(ev, writes, q):
     return percentile(ms, q) if ms else None
 
 
+def client_range_rows_mean(ev):
+    """Mean number of rows a range read returned, over every range
+    read of the window's operations: that the mix sent is the mix
+    stated (YCSB-E's scans of 1–100 records: 50.5 less what the
+    table's end cuts off). Nothing where no operation read a range."""
+    rows = [len(r[2]) for op in ev["ops"] if len(op) > 10 for r in op[10]]
+    return sum(rows) / len(rows) if rows else None
+
+
+def trace_idle_name_pct(ev, prefixes):
+    """100 · the device's idle seconds under the host annotations that
+    start with ``prefixes`` / all its idle seconds
+    (``hostspans.idle_name_pct`` over ``name_gaps``' ``idle_by_name``).
+    Nothing where the trace carried no ``fdb.*`` annotation or no
+    device plane (a CPU)."""
+    return hostspans.idle_name_pct(ev.get("trace"), prefixes)
+
+
 READERS = {
     "client_latency_ms": client_latency_ms,
+    "client_range_rows_mean": client_range_rows_mean,
+    "trace_idle_name_pct": trace_idle_name_pct,
     "status_delta_mean": status_delta_mean,
     "status_delta_ratio": status_delta_ratio,
     "trace_program_mean_ms": trace_program_mean_ms,

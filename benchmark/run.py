@@ -16,6 +16,7 @@ seconds; a tail is taken over the raw samples of all clients.
 """
 
 import argparse
+import collections
 import concurrent.futures
 import glob
 import json
@@ -94,8 +95,19 @@ class Cell:
     def end_to_end(self):
         return [m for m in self.bench["end_to_end"] if self.reports(m)]
 
+    def metric_spec(self, name):
+        """``metrics/<name>.json``. A file with ``like`` gives another
+        metric's reading a name of its own, for the cells that report
+        another end-to-end metric for it to move: the reader and its
+        arguments are that metric's."""
+        spec = read_json(self.find("metrics", name))
+        if "like" in spec:
+            base = read_json(self.find("metrics", spec["like"]))
+            spec = {**spec, "reader": base["reader"], "args": base["args"]}
+        return spec
+
     def per_layer(self):
-        return [(m, read_json(self.find("metrics", m["name"])))
+        return [(m, self.metric_spec(m["name"]))
                 for m in self.bench["per_layer"] if self.reports(m)]
 
 
@@ -170,8 +182,8 @@ def load(db, table):
     → the number of such retries."""
     from foundationdb_tpu.core.errors import FDBError
 
-    ids = range(table.rows)
-    groups = [ids[i:i + LOAD_SETS] for i in range(0, table.rows, LOAD_SETS)]
+    slots = table.loaded()
+    groups = [slots[i:i + LOAD_SETS] for i in range(0, table.rows, LOAD_SETS)]
 
     def sets(group):
         def body(tr):
@@ -209,15 +221,15 @@ def load(db, table):
 
 
 def read_back(db, table):
-    """Every record, in pages through ordinary transactions → {record
-    id: token}; a record that is missing reads -1."""
-    ids = {table.key(i): i for i in range(table.rows)}
-    rows = dict.fromkeys(range(table.rows), -1)
-    begin, end = table.key(0), table.key(table.rows - 1) + b"\x00"
+    """The table's whole key range, in pages through ordinary
+    transactions → {slot: token}: every row that is there, and -1 for
+    a loaded record that is missing."""
+    rows = dict.fromkeys(table.loaded(), -1)
+    begin, end = table.key(0), table.end_key()
     while True:
         page = db.run(lambda tr: tr.get_range(begin, end, limit=PAGE_ROWS))
         for k, v in page:
-            rows[ids[bytes(k)]] = datagen.token(bytes(v))
+            rows[table.slot_of_key(bytes(k))] = datagen.token(bytes(v))
         if len(page) < PAGE_ROWS:
             return rows
         begin = bytes(page[-1][0]) + b"\x00"
@@ -240,11 +252,21 @@ def end_to_end_values(names, ops, t_start, t_end, setup_s):
     """The window's numbers from the raw log rows of every client:
     ``ops_per_s`` over every operation acknowledged in the window,
     ``update_p<q>_ms`` / ``read_p<q>_ms`` over every update / every
-    read-only transaction acknowledged in it."""
+    read-only transaction acknowledged in it; an operation that writes
+    (a set, an insert, a clear) is an update. ``acked_by_kind`` counts
+    them by their place in the mix's ``operations``, and
+    ``ops_per_s_by_fifth`` gives the rate in each fifth of the window."""
     acked = [op for op in ops if op[check.STATUS] == check.OK
              and t_start <= op[check.T1] < t_end]
     out = {"setup_s": setup_s,
            "ops_per_s": len(acked) / (t_end - t_start)}
+    counts = collections.Counter(op[check.KIND] for op in acked)
+    out["acked_by_kind"] = [counts[k] for k in
+                            range(max(counts, default=-1) + 1)]
+    fifth = (t_end - t_start) / 5  # is the window steady? the rate by fifths
+    fifths = collections.Counter(int((op[check.T1] - t_start) / fifth)
+                                 for op in acked)
+    out["ops_per_s_by_fifth"] = [fifths[n] / fifth for n in range(5)]
     by_kind = {True: [], False: []}
     for op in acked:
         by_kind[bool(op[check.WRITES])].append(
@@ -341,10 +363,13 @@ def main(argv=None):
 
         final_rows = read_back(db, table)
         t_read = time.monotonic()
+        loaded = table.loaded()
+        held = set(loaded)
         numbers, examples = check.replay(
-            ops, lambda i: datagen.token(table.initial(i)), final_rows,
-            counted_token=(lambda i, n: datagen.token(table.counted(i, n)))
-            if table.kind == "counted" else None)
+            ops, lambda s: datagen.token(table.initial(s))
+            if s in held else -1, final_rows,
+            counted_token=(lambda s, n: datagen.token(table.counted(s, n)))
+            if table.kind == "counted" else None, loaded=loaded)
         agg0 = status0["cluster"]["device"]
         agg1 = status1["cluster"]["device"]
         numbers["compiles_in_window"] = (

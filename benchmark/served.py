@@ -49,6 +49,7 @@ def tracer(trace_dir, cluster_file):
     import jax
 
     import foundationdb_tpu as fdb
+    import hostspans
     import tracereduce
 
     go = wait_for(os.path.join(trace_dir, "trace.go"))
@@ -77,7 +78,8 @@ def tracer(trace_dir, cluster_file):
         db._cluster.close()
         wait_for(os.path.join(trace_dir, "trace.reduce"))
         events, seen = tracereduce.load_events(xplane)
-        out.update(tracereduce.reduce_events(events, t1 - t0))
+        out.update(tracereduce.reduce_events(
+            events, t1 - t0, host_spans=hostspans.load(xplane)))
         out.update(status0=status0, status1=status1, t0=t0, t1=t1,
                    start_trace_s=t0 - t_req, stop_trace_s=t_stopped - t1,
                    reduce_s=time.monotonic() - t_stopped, planes=seen,

@@ -178,6 +178,11 @@ def test_benchmark_json_names_units_and_moves():
     assert all(0.01 <= m["bound"] <= 0.25 for m in e2e.values())
     for w in cells.values():
         assert w["config"] in configs and w["chips"] in (1, 4)
+        # setup_s, one more end-to-end metric, and a layer's
+        mine = [m["name"] for m in bench["end_to_end"]
+                if w["name"] in m.get("workloads", cells)]
+        assert "setup_s" in mine and len(mine) >= 2
+        assert any(w["name"] in m["workloads"] for m in bench["per_layer"])
         assert os.path.exists(os.path.join(
             BENCH_DIR, "traffic", w["traffic"] + ".json"))
     for c in configs.values():
@@ -189,6 +194,16 @@ def test_benchmark_json_names_units_and_moves():
         spec = read_json(os.path.join(BENCH_DIR, "metrics",
                                       m["name"] + ".json"))
         assert {k: spec[k] for k in m} == m  # the file says the same
+        if "like" in spec:  # another metric's reading, moving another
+            base = read_json(os.path.join(BENCH_DIR, "metrics",
+                                          spec["like"] + ".json"))
+            assert m["name"] == "scan." + base["name"]
+            assert {k: base[k] for k in ("layer", "unit", "better", "source")
+                    } == {k: m[k] for k in ("layer", "unit", "better",
+                                            "source")}
+            assert base["moves"] != m["moves"] and "reader" not in spec
+            assert not set(m["workloads"]) & set(base["workloads"])
+            spec = base
         assert spec["reader"] in readers.READERS
         moved = e2e[m["moves"]]
         # every cell of the metric reports the metric it moves

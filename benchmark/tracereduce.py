@@ -20,7 +20,7 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-GAP_NAME = "host.unattributed"  # the program has no TraceAnnotation yet
+GAP_NAME = "host.unattributed"  # a trace without a single fdb.* annotation
 
 
 def load_events(trace_dir):
@@ -64,10 +64,13 @@ def _union(intervals):
     return merged
 
 
-def reduce_events(events, window_s):
+def reduce_events(events, window_s, host_spans=None):
     """→ {"window_s", "busy_s", "programs": {name: {"count",
     "total_s"}}, "device_ops": [[name, s]…], "idle_gaps": [[name, s]…],
     "events_span_s": first start to last end of what was recorded}.
+    With ``host_spans`` (``hostspans.load``) each of the ten gaps
+    carries the name of what the host was doing for most of it, and
+    ``idle_by_name`` holds the seconds of all idle time by that name.
 
     ``window_s`` is the traced span: from ``start_trace``'s return to
     ``stop_trace``'s call, which is when the profiler records (on the
@@ -104,4 +107,8 @@ def reduce_events(events, window_s):
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
     out["device_ops"] = [[name, ns / 1e9] for name, ns in top]
     out["idle_gaps"] = [[GAP_NAME, g] for g in sorted(gaps, reverse=True)[:10]]
+    if host_spans:
+        import hostspans  # it imports this module
+
+        hostspans.name_gaps(out, events, host_spans)
     return out
