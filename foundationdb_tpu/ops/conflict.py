@@ -23,9 +23,10 @@ arrays updated in one fused jit step:
    overlap, ops/intervals.py).
 
 3. **Coarse interval summary** ``(range_L, range_R)[C]`` over ``C``
-   order-contiguous key buckets, absorbing range-writes *evicted* from
-   the ring: scatter-max of the version at the interval's begin bucket
-   into L and end bucket into R. A query range [qlo,qhi] can only overlap
+   order-contiguous key buckets (cut by the host, below), absorbing
+   range-writes *evicted* from the ring: scatter-max of the version at
+   the interval's begin bucket into L and end bucket into R. A query
+   range [qlo,qhi] can only overlap
    a stored interval if that interval starts at or before qhi (so its
    version is ≤ prefix-max of L at qhi) *and* ends at or after qlo (≤
    suffix-max of R at qlo); ``min(prefmax_L[qhi], sufmax_R[qlo])`` is
@@ -35,6 +36,18 @@ arrays updated in one fused jit step:
 4. **Coarse point summary** ``point[C]``: per-bucket max version of all
    point writes, with a per-batch sparse table for O(1) range-max — used
    only by range reads (point reads use the exact hash table).
+
+How the ``C`` buckets are cut is the host's business
+(resolver/packing.py ``CoarseBuckets``): the device only ever sees
+bucket indices, and needs of them that the map from key to index is
+weakly monotone in the whole limb-encoded key and that a write is
+recorded and later read under the SAME map. Until the resolver has met a
+range the map is the top bits of a key's first limb; from then on it is a
+``searchsorted`` against ``C − 1`` sorted boundary rows, quantiles of a
+sample of the keys the resolver itself packs (upstream's resolvers are
+balanced from such a sample: Resolver.actor.cpp ``iopsSample``), so that
+keys sharing a prefix — every key of one application does — still spread
+over the buckets.
 
 Intra-batch ordering — the sequential part of the reference's resolver —
 becomes a **Jacobi fixpoint on the MXU**: build the strict-lower-
@@ -50,6 +63,30 @@ both to *record* accepted writes and to *check* reads, and each lane's
 check provably sees every write its record admitted (hash: same bucket;
 ring: exact; coarse: bucket monotonicity). Hence the accepted set is
 always mutually serializable — false positives only shrink it.
+
+A change of the bucket map (a *rebucket*) would break "same map" for
+what the summaries already hold, so the host runs :func:`fold_coarse`
+between the last step under the old map and the first under the new:
+``point_coarse``, ``range_L`` and ``range_R`` each become their own
+maximum in every bucket, and every ring entry's begin / end bucket
+becomes 0 / C − 1 (what eviction will scatter). Whatever index a later
+read computes, it then meets a version at least as new as any the old
+map would have shown it: a fold can add refusals, for the one round of
+read versions older than those maxima, and can never lose a write. The
+exact lanes (hash table, ring compare, intra-batch matrix) never look at
+a bucket. Two places *place* entries by bucket: with
+``ring_partition_bits`` an entry sits in the sub-ring of its old begin
+bucket and a query looks only in its own end partitions, so there the
+fold also empties the ring into the summaries; and the replicated-batch
+sharded step (``axis_name``) records a range write on the shard that
+``bucket_owned(rw_lo)`` names, but every shard checks every read against
+its own ring exactly and the verdicts are OR-reduced, so an entry
+recorded under the old map is still found wherever it sits.
+
+The full step also says which refusals only a coarse lane raised
+(``CONFLICT_COARSE``: the hash table, the ring and the intra-batch
+matrix found nothing): an upper bound on the false conflicts the
+summaries cost. The host counts them and answers CONFLICT.
 
 Versions are uint32 offsets from a host-held base (core/versions.py);
 version 0 means "no write recorded".
@@ -191,7 +228,15 @@ class ShardBatch(NamedTuple):
     new_window_start: jnp.ndarray  # uint32[] (replicated)
 
 
-from foundationdb_tpu.core.status import COMMITTED, CONFLICT, TOO_OLD  # noqa: E402
+from foundationdb_tpu.core.status import (  # noqa: E402
+    COMMITTED, CONFLICT, CONFLICT_COARSE, TOO_OLD)
+
+
+def has_coarse_lanes(params: ResolverParams):
+    """Whether the program ``params`` describes checks a read against a
+    coarse summary at all (the point-only variants do not)."""
+    return bool(params.range_writes
+                or (params.range_reads and params.point_writes))
 
 
 def init_state(params: ResolverParams) -> ResolverState:
@@ -304,6 +349,20 @@ def _overlap_matrix(T, pw, pr, rw, rr):
     return O
 
 
+def _mark_coarse_only(status, accepted, Of, coarse_hist, axis_name):
+    """``status`` with CONFLICT_COARSE where only a coarse summary stood
+    in the way: ``coarse_hist`` (a history hit no exact lane shares, read
+    version inside the window) and no accepted earlier transaction of the
+    batch writes what this one reads (``Of``: the intra-batch matrix the
+    fixpoint ran on, one more row-vector product at its fixpoint)."""
+    killed = jnp.dot(
+        accepted.astype(jnp.bfloat16), Of, preferred_element_type=jnp.float32
+    )
+    if axis_name is not None:
+        killed = jax.lax.psum(killed, axis_name)
+    return jnp.where(coarse_hist & (killed < 0.5), CONFLICT_COARSE, status)
+
+
 def resolve_batch(
     state: ResolverState,
     batch: ResolveBatch,
@@ -375,6 +434,10 @@ def resolve_batch(
     too_old = rv < state.window_start
 
     hist = jnp.zeros((T,), bool)
+    # what the exact lanes alone found: only a program with a coarse
+    # check keeps it (the point-only variants lower to the text they had)
+    coarse_lanes = has_coarse_lanes(params)
+    exact = jnp.zeros((T,), bool)
 
     # The ring + coarse interval summaries are populated ONLY by range
     # writes: with params.range_writes == 0 they are statically all-zero,
@@ -447,6 +510,9 @@ def resolve_batch(
                 newer = (state.ring_v[None, None] > rv[:, None, None]) & state.ring_mask[None, None]
                 ring_hit = jnp.any(in_rng & newer, axis=2)
             hit |= ring_hit & batch.pr_mask
+        if coarse_lanes:
+            exact |= jnp.any(hit, axis=1)
+        if params.range_writes:
             # point reads vs evicted range-writes (coarse interval summary)
             coarse = jnp.minimum(pref_L[batch.pr_bucket], suf_R[batch.pr_bucket])
             hit |= (coarse > rv[:, None]) & batch.pr_mask
@@ -501,6 +567,7 @@ def resolve_batch(
                 newer = (state.ring_v[None, None] > rv[:, None, None]) & state.ring_mask[None, None]
                 ring_hit = jnp.any(ov & newer, axis=2)
             hit |= ring_hit & batch.rr_mask
+            exact |= jnp.any(hit, axis=1)
             coarse_rng = jnp.minimum(pref_L[batch.rr_hi], suf_R[batch.rr_lo])
             hit |= (coarse_rng > rv[:, None]) & batch.rr_mask
         if params.point_writes:
@@ -510,6 +577,8 @@ def resolve_batch(
         hist |= jnp.any(hit, axis=1)
 
     hist = por(hist)
+    if coarse_lanes:
+        exact = por(exact)
 
     # a0: admissible before intra-batch ordering (history + window + mask)
     a0 = (~too_old) & (~hist) & batch.txn_mask
@@ -559,6 +628,9 @@ def resolve_batch(
     accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
 
     status = jnp.where(too_old, TOO_OLD, jnp.where(accepted, COMMITTED, CONFLICT))
+    if coarse_lanes:
+        status = _mark_coarse_only(
+            status, accepted, Of, hist & ~exact & ~too_old, axis_name)
     status = jnp.where(batch.txn_mask, status, CONFLICT)
 
     # ───────────────────────── history update ─────────────────────────────
@@ -789,6 +861,7 @@ def resolve_batch_presharded(
     # not portably lowered); padding slots point at txn 0 with mask False
     # so they add zero
     hist_i = jnp.zeros((T,), jnp.int32)
+    exact_i = jnp.zeros((T,), jnp.int32)  # the exact lanes' hits alone
 
     if params.range_writes:
         pref_L = jax.lax.associative_scan(jnp.maximum, state.range_L)
@@ -805,6 +878,10 @@ def resolve_batch_presharded(
             )  # [Qpr, KR]
             newer = (state.ring_v[None] > rv_q[:, None]) & state.ring_mask[None]
             hit |= jnp.any(in_rng & newer, axis=1) & sb.pr_mask
+        exact_i = exact_i.at[sb.pr_txn].add(
+            hit.astype(jnp.int32), mode="promise_in_bounds"
+        )
+        if params.range_writes:
             coarse = jnp.minimum(pref_L[sb.pr_bucket], suf_R[sb.pr_bucket])
             hit |= (coarse > rv_q) & sb.pr_mask
         hist_i = hist_i.at[sb.pr_txn].add(
@@ -821,6 +898,9 @@ def resolve_batch_presharded(
             )  # [Qrr, KR]
             newer = (state.ring_v[None] > rv_q[:, None]) & state.ring_mask[None]
             hit |= jnp.any(ov & newer, axis=1) & sb.rr_mask
+            exact_i = exact_i.at[sb.rr_txn].add(
+                hit.astype(jnp.int32), mode="promise_in_bounds"
+            )
             coarse_rng = jnp.minimum(pref_L[sb.rr_hi], suf_R[sb.rr_lo])
             hit |= (coarse_rng > rv_q) & sb.rr_mask
         if params.point_writes:
@@ -832,6 +912,7 @@ def resolve_batch_presharded(
         )
 
     hist = por(hist_i > 0)
+    exact = por(exact_i > 0)
 
     # ─────────────────────── intra-batch conflict matrix ───────────────────
     # Each compacted side goes back onto a dense [T, K] grid (one
@@ -887,6 +968,9 @@ def resolve_batch_presharded(
     accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
 
     status = jnp.where(too_old, TOO_OLD, jnp.where(accepted, COMMITTED, CONFLICT))
+    if has_coarse_lanes(params):
+        status = _mark_coarse_only(
+            status, accepted, Of, hist & ~exact & ~too_old, axis_name)
     status = jnp.where(sb.txn_mask, status, CONFLICT)
 
     # ───────────────────────── history update ─────────────────────────────
@@ -1114,6 +1198,54 @@ class PackedProgram:
         return self.trace(state, batch).lower()
 
 
+def fold_coarse(state: ResolverState, params: ResolverParams):
+    """What the coarse lanes hold, made true under ANY bucket map: the
+    host runs this between the last step under one map and the first
+    under the next (module text, "rebucket"). Each summary becomes its
+    own maximum everywhere; a ring entry's begin / end bucket, which only
+    its eviction reads, becomes 0 / C − 1. A partitioned ring is emptied
+    into the summaries, since its entries sit where their old begin
+    bucket put them."""
+    u32 = jnp.uint32
+    top_L, top_R = jnp.max(state.range_L), jnp.max(state.range_R)
+    ring_mask = state.ring_mask
+    if params.ring_partition_bits:
+        live = jnp.max(jnp.where(ring_mask, state.ring_v, u32(0)))
+        top_L, top_R = jnp.maximum(top_L, live), jnp.maximum(top_R, live)
+        ring_mask = jnp.zeros_like(ring_mask)
+    return state._replace(
+        range_L=jnp.full_like(state.range_L, top_L),
+        range_R=jnp.full_like(state.range_R, top_R),
+        point_coarse=jnp.full_like(
+            state.point_coarse, jnp.max(state.point_coarse)),
+        ring_lo=jnp.zeros_like(state.ring_lo),
+        ring_hi=jnp.full_like(state.ring_hi, state.range_L.shape[0] - 1),
+        ring_mask=ring_mask,
+    )
+
+
+def make_fold_fn(params: ResolverParams, like: ResolverState):
+    """jit-compiled :func:`fold_coarse`, the history donated, every
+    array placed where ``like``'s is (a mesh's state stays sharded as
+    its step programs take it, so that no step is built again). One
+    program a shape and placement, whoever asks."""
+    placed = None
+    if len(like.ht.sharding.device_set) > 1:
+        # (one device's arrays stay uncommitted, as the step made them:
+        # a committed input would be a new program to the step's jit)
+        placed = jax.tree.map(lambda a: a.sharding, like)
+    return _fold_fn(params, placed)
+
+
+@functools.lru_cache(maxsize=None)
+def _fold_fn(params, out_shardings):
+    def fold_coarse_state(state):
+        return fold_coarse(state, params)
+
+    placed = {} if out_shardings is None else {"out_shardings": out_shardings}
+    return jax.jit(fold_coarse_state, donate_argnums=(0,), **placed)
+
+
 def make_resolve_fn(params: ResolverParams, donate=True):
     """jit-compiled resolver step with the history buffers donated.
 
@@ -1126,6 +1258,11 @@ def make_resolve_fn(params: ResolverParams, donate=True):
     (parallel/mesh.py, :class:`PackedProgram`)."""
     validate_params(params)
     fn = lambda state, batch: resolve_batch(state, batch, params)
+    if params.range_reads or params.range_writes:
+        # the step with range lanes has a name of its own in a trace
+        # (``jit_resolve_full``); the point-only step keeps the lambda's
+        # (``jit__lambda``), which the benchmark's older cells read
+        fn.__name__ = "resolve_full"
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

@@ -104,7 +104,8 @@ class MeshResolver(Resolver):
         self.params = params_from_knobs(knobs, use_pallas=False)._replace(
             ring_partition_bits=0
         )
-        self.packer = BatchPacker(self.params)
+        self._init_buckets()
+        self.packer = BatchPacker(self.params, buckets=self.buckets)
         # "range" (the default) is the single-dispatch compacted path:
         # the host routes each entry to the lane(s) owning its keys
         # (ShardRouter), so per-lane scan/pairwise work shrinks ~1/n.
@@ -138,7 +139,8 @@ class MeshResolver(Resolver):
                     self._fast_params, mesh=self.mesh, make_state=False
                 )
                 self._fast = (
-                    BatchPacker(self._fast_params), self._fast_kernel._step
+                    BatchPacker(self._fast_params, buckets=self.buckets),
+                    self._fast_kernel._step,
                 )
         self.state = self._kernel.state
         self._kernel.state = None  # ownership moves here (donated per step)

@@ -34,7 +34,9 @@ def fnv_hash_np(limbs):
 
 
 def bucket_of(limbs, bucket_bits):
-    """Coarse bucket = top bits of the first limb (monotone in the key)."""
+    """The coarse bucket a resolver starts with: the top bits of the
+    first limb (monotone in the key, and one bucket for every key that
+    shares its first four bytes: :class:`CoarseBuckets` cuts finer)."""
     return (limbs[..., 0] >> np.uint32(32 - bucket_bits)).astype(np.int32)
 
 
@@ -46,15 +48,130 @@ def _slots(c):
     return t_idx, i_idx
 
 
-def _rows_struct(rows):
-    """uint32[N, W] limb rows → structured[N] whose searchsorted order
-    is exactly the limb-lexicographic key order (the host twin of
-    ops/intervals.lex_lt): per-field big-endian u4 fields compare
-    field-by-field numerically, i.e. limb-by-limb."""
+def _rows_sortable(rows):
+    """uint32[..., W] limb rows → bytes scalars[...] whose sort and
+    searchsorted order is exactly the limb-lexicographic key order (the
+    host twin of ops/intervals.lex_lt): a row's limbs big-endian, end to
+    end, compare as one byte string. (A structured dtype of W fields
+    gives the same order forty times slower: numpy compares its fields
+    one Python-visible step at a time.)"""
     W = rows.shape[-1]
-    dt = np.dtype([("l%d" % i, ">u4") for i in range(W)])
     be = np.ascontiguousarray(rows.astype(">u4"))
-    return be.view(dt).reshape(rows.shape[:-1])
+    return be.view("S%d" % (4 * W)).reshape(rows.shape[:-1])
+
+
+class CoarseBuckets:
+    """The bucket function of the coarse lanes (ops/conflict.py:
+    ``point_coarse``, ``range_L`` / ``range_R``, a ring entry's begin and
+    end bucket): an order-preserving map from a limb-encoded key to
+    ``[0, C)``, shared by every packer of one resolver.
+
+    It starts as :func:`bucket_of`, the top bits of the first limb, and
+    stays that for as long as the resolver meets no range: a server of
+    point transactions never reads a coarse lane, and pays one short
+    slice store a dispatch here. Real keys share prefixes by construction (tuple
+    layer, subspaces, ``user…``, ``mako…``), so under that map all keys
+    of an application have one bucket and a range read conflicts with
+    any point write anywhere. :meth:`recut` replaces it by ``C − 1``
+    sorted boundary rows and a ``searchsorted``: quantiles of a sample of
+    the keys this resolver packed (point writes and range begins,
+    duplicates kept, so a hot key gets a narrow bucket), as upstream
+    balances its resolvers from the keys they see (Resolver.actor.cpp
+    ``iopsSample``). Any sorted boundaries are weakly monotone in the
+    whole key, which is all safety needs; the resolver folds what the
+    summaries hold whenever the boundaries change
+    (``Resolver._maybe_rebucket``, ops/conflict.py ``fold_coarse``).
+
+    The sample is every noted row until its buffer of ``2 · capacity``
+    rows is full; then every second row is dropped, so it settles between
+    ``capacity`` and twice that, a row's weight halving with every
+    ``capacity`` rows noted after it. When to cut again has one constant, ``FACTOR``: a cut made
+    from fewer than ``capacity`` rows is made again once the rows noted
+    have grown by it; a cut made from a full sample only when, every
+    ``capacity`` rows, the fullest bucket holds ``FACTOR`` times the
+    share a fresh cut would give it (both measured on the half of the
+    sample neither cut was made from).
+    """
+
+    FACTOR = 2
+
+    def __init__(self, params: ResolverParams, capacity=None):
+        self.bits = params.bucket_bits
+        self.C = 1 << params.bucket_bits
+        # four sampled keys a bucket: a bucket's share is then known to
+        # within half of itself, and 2 · capacity rows of W limbs are a
+        # few megabytes
+        self.capacity = int(capacity or 4 * self.C)
+        self._bounds = None  # sortable[C − 1], or None: the first limb's bits
+        self._sample = None  # uint32[2 · capacity, W], made at the first row
+        self._held = 0  # rows of it in use
+        self.seen = 0  # rows ever noted
+        self._cut_seen = 0  # … when the bounds were last cut
+        self._checked = 0  # … when a full-sample cut was last held up
+
+    @property
+    def cut(self):
+        """Whether boundaries have been cut from a sample."""
+        return self._bounds is not None
+
+    def of(self, rows):
+        """int32[...] bucket of each limb row uint32[..., W]."""
+        if self._bounds is None:
+            return bucket_of(rows, self.bits)
+        return np.searchsorted(
+            self._bounds, _rows_sortable(rows), side="right"
+        ).astype(np.int32)
+
+    def note_rows(self, rows):
+        """Take limb rows uint32[N, W] into the sample. On the thread
+        that dispatches this is one short slice store, which keeps the
+        interpreter lock (a call that gives it up costs a herd's turn:
+        PERF.md §6, PR 29), and once every ``capacity`` rows a strided
+        copy of the buffer."""
+        if self._sample is None:
+            self._sample = np.empty(
+                (2 * self.capacity, rows.shape[-1]), np.uint32)
+        buf = self._sample
+        self.seen += len(rows)
+        while self._held + len(rows) > len(buf):
+            kept = buf[1:self._held:2]
+            buf[:len(kept)] = kept
+            self._held, rows = len(kept), rows[1::2]
+        buf[self._held:self._held + len(rows)] = rows
+        self._held += len(rows)
+
+    def recut_due(self):
+        """Whether :meth:`recut` has something to do (cheap)."""
+        if self._cut_seen < self.capacity:
+            return self.seen >= max(1, self.FACTOR * self._cut_seen)
+        return self.seen - self._checked >= self.capacity
+
+    def recut(self):
+        """Cut the boundaries again from the sample if the class text's
+        rule says so → whether they changed (the caller then folds the
+        device's summaries before its next step)."""
+        rows = self._sample[:self._held]
+        self._checked = self.seen
+        if self._cut_seen >= self.capacity and not self._stale(rows):
+            return False
+        self._bounds = self._quantiles(_rows_sortable(rows))
+        self._cut_seen = self.seen
+        return True
+
+    def _quantiles(self, keys):
+        keys = np.sort(keys)
+        return keys[(np.arange(1, self.C, dtype=np.int64) * len(keys))
+                    // self.C]
+
+    def _stale(self, rows):
+        fit, held = _rows_sortable(rows[0::2]), _rows_sortable(rows[1::2])
+
+        def fullest(bounds):
+            return np.bincount(
+                np.searchsorted(bounds, held, side="right")).max()
+
+        return fullest(self._bounds) >= self.FACTOR * fullest(
+            self._quantiles(fit))
 
 
 class ShardRouter:
@@ -101,7 +218,7 @@ class ShardRouter:
         self.bounds = np.ascontiguousarray(
             np.asarray(bounds, np.uint32).reshape(self.n - 1, W)
         )
-        self._bounds_s = _rows_struct(self.bounds)
+        self._bounds_s = _rows_sortable(self.bounds)
         T = params.txns
         self.caps = {
             "pr": self._cap(T, params.point_reads, headroom),
@@ -127,7 +244,7 @@ class ShardRouter:
     def lane_of_points(self, rows):
         """lane index per limb row (uint32[N, W])."""
         return np.searchsorted(
-            self._bounds_s, _rows_struct(rows), side="right"
+            self._bounds_s, _rows_sortable(rows), side="right"
         ).astype(np.int64)
 
     def lane_span(self, b_rows, e_rows):
@@ -135,10 +252,10 @@ class ShardRouter:
         lane is the one containing the greatest key < e, i.e. the count
         of bounds strictly below e."""
         lo = np.searchsorted(
-            self._bounds_s, _rows_struct(b_rows), side="right"
+            self._bounds_s, _rows_sortable(b_rows), side="right"
         ).astype(np.int64)
         hi = np.searchsorted(
-            self._bounds_s, _rows_struct(e_rows), side="left"
+            self._bounds_s, _rows_sortable(e_rows), side="left"
         ).astype(np.int64)
         return lo, np.maximum(hi, lo)  # degenerate ranges stay 1-lane
 
@@ -319,8 +436,13 @@ class BatchPacker:
     # dispatch that could still be reading it
     STAGING_RING = 4
 
-    def __init__(self, params: ResolverParams, use_native=True):
+    def __init__(self, params: ResolverParams, use_native=True,
+                 buckets=None):
         self.params = params
+        # the coarse lanes' bucket function: a resolver hands both its
+        # packers (full and point-only) the one it rebuckets
+        self.buckets = buckets if buckets is not None else CoarseBuckets(
+            params)
         self.codec = KeyCodec(num_limbs=params.key_width - 1)
         self._native = None
         self._empty = None  # cached zero-txn pad batch (pack_empty)
@@ -521,8 +643,11 @@ class BatchPacker:
                 rows = flatpack.point_limbs(blob, L)
                 flat[side + "_key"][at] = rows.view(self._row).reshape(-1)
                 flat[side + "_hash"][at] = fnv_hash_np(rows)
-                flat[side + "_bucket"][at] = bucket_of(rows, p.bucket_bits)
+                bucket = self.buckets.of(rows)
+                flat[side + "_bucket"][at] = bucket
                 flat[side + "_mask"][at] = True
+                if side == "pw":
+                    self._note_writes(rows, bucket)
         for side, counts, blob, lanes in (
                 ("rr", rrc, rr_blob, p.range_reads),
                 ("rw", rwc, rw_blob, p.range_writes)):
@@ -531,9 +656,10 @@ class BatchPacker:
                 lo, hi = flatpack.range_limbs(blob, L)
                 flat[side + "_b"][at] = lo.view(self._row).reshape(-1)
                 flat[side + "_e"][at] = hi.view(self._row).reshape(-1)
-                flat[side + "_lo"][at] = bucket_of(lo, p.bucket_bits)
-                flat[side + "_hi"][at] = bucket_of(hi, p.bucket_bits)
+                flat[side + "_lo"][at] = self.buckets.of(lo)
+                flat[side + "_hi"][at] = self.buckets.of(hi)
                 flat[side + "_mask"][at] = True
+                self.buckets.note_rows(lo)
         for b, (cv, ws) in enumerate(metas):
             bufs["cv"][b] = u32(cv - base_version)
             bufs["nws"][b] = u32(max(0, ws - base_version))
@@ -554,6 +680,66 @@ class BatchPacker:
             rw_mask=bufs["rw_mask"],
             cv=bufs["cv"], new_window_start=bufs["nws"],
         )
+
+    def _note_writes(self, rows, bucket):
+        """A pack's live point-write rows into the bucket sample and,
+        once boundaries are cut, into the profile: how many there were
+        and how many fell in the pack's fullest bucket (before a cut a
+        server of one application reads 100%, and is not asked)."""
+        self.buckets.note_rows(rows)
+        if self.buckets.cut and self.profile is not None and len(rows):
+            self.profile.count(
+                bucket_entries_routed=len(bucket),
+                bucket_entries_fullest=int(np.bincount(bucket).max()))
+
+    def _legacy_buckets(self, batch, n):
+        """``pack``'s bucket arrays under the boundaries in force, for a
+        batch of ``n`` transactions (the native pass writes the first
+        limb's bits: once boundaries are cut the live entries' buckets
+        are found again from their key rows, in ONE search, and stored
+        in place; a pad slot keeps bucket 0), and the batch's live point
+        writes and range begins into the sample. Nothing here walks the
+        pad or makes an array of its size: arrival order fills the
+        transaction slots ``0 … n-1``, and on the thread that dispatches
+        a large numpy call is a turn at the interpreter lock given away
+        (PERF.md §6, PR 29)."""
+        cut = self.buckets.cut
+        W = self.params.key_width
+        parts = []  # (side, which bound, the bucket array, live slots, rows)
+        for side, fields in (
+                ("pr", (("pr_key", "pr_bucket"),)),
+                ("pw", (("pw_key", "pw_bucket"),)),
+                ("rr", (("rr_b", "rr_lo"), ("rr_e", "rr_hi"))),
+                ("rw", (("rw_b", "rw_lo"), ("rw_e", "rw_hi")))):
+            if side == "pr" and not cut:
+                continue  # point reads are not sampled
+            mask = getattr(batch, side + "_mask")
+            live = np.flatnonzero(mask[:n]) if mask.size else ()
+            if not len(live):
+                continue
+            for k, (keys, name) in enumerate(fields):
+                if k and not cut:
+                    break  # a range's end is only bucketed
+                # (whole rows through ONE index into a 1-D view: half
+                # the time of a 2-D gather, and it keeps the lock)
+                rows = getattr(batch, keys).reshape(-1, W).view(
+                    self._row).reshape(-1)[live].view(np.uint32).reshape(
+                    -1, W)
+                parts.append((side, k, getattr(batch, name), live, rows))
+        if cut and parts:
+            found = self.buckets.of(np.concatenate([p[-1] for p in parts]))
+        at = 0
+        for side, k, out, live, rows in parts:
+            bucket = None
+            if cut:
+                bucket = found[at:at + len(rows)]
+                at += len(rows)
+                out.reshape(-1)[live] = bucket
+            if side == "pw":
+                self._note_writes(rows, bucket)
+            elif side != "pr" and k == 0:
+                self.buckets.note_rows(rows)
+        return batch
 
     def pack_flat(self, flat, base_version, commit_version,
                   new_window_start):
@@ -709,7 +895,7 @@ class BatchPacker:
             except TypeError:
                 batch = None  # e.g. bytearray keys; numpy path takes them
             if batch is not None:
-                return batch
+                return self._legacy_buckets(batch, len(txns))
         T, W = p.txns, p.key_width
         u32 = np.uint32
 
@@ -786,7 +972,7 @@ class BatchPacker:
             rw_e[rw_t, rw_i] = hi
             rw_mask[rw_t, rw_i] = True
 
-        return ResolveBatch(
+        return self._legacy_buckets(ResolveBatch(
             rv=rv,
             txn_mask=txn_mask,
             pr_hash=fnv_hash_np(pr_key),
@@ -809,4 +995,4 @@ class BatchPacker:
             rw_mask=rw_mask,
             cv=np.uint32(commit_version - base_version),
             new_window_start=np.uint32(max(0, new_window_start - base_version)),
-        )
+        ), n)

@@ -17,13 +17,14 @@ import numpy as np
 from foundationdb_tpu.core.flatpack import FlatTxnBatch
 from foundationdb_tpu.core.options import DEFAULT_KNOBS
 from foundationdb_tpu.ops import conflict as ck
-from foundationdb_tpu.resolver.packing import BatchPacker
+from foundationdb_tpu.resolver.packing import BatchPacker, CoarseBuckets
 from foundationdb_tpu.resolver.skiplist import CpuConflictSet
 from foundationdb_tpu.utils import deviceprofile
 from foundationdb_tpu.utils import metrics as metrics_mod
 from foundationdb_tpu.utils import span as span_mod
 
 COMMITTED, CONFLICT, TOO_OLD = ck.COMMITTED, ck.CONFLICT, ck.TOO_OLD
+CONFLICT_COARSE = ck.CONFLICT_COARSE
 
 # resolve_many's fixed scan width: backlog dispatches pad to a multiple
 # of this (server/batcher.py MAX_BACKLOG matches) so every backlog size
@@ -155,7 +156,8 @@ class Resolver:
                 # lanes (an explicit "on" is rejected by validate_params)
                 use_pallas = False
             self.params = params_from_knobs(knobs, use_pallas=use_pallas)
-            self.packer = BatchPacker(self.params)
+            self._init_buckets()
+            self.packer = BatchPacker(self.params, buckets=self.buckets)
             self.state = ck.init_state(self.params)
             self._resolve = ck.make_resolve_fn(self.params)
             # Static specialization (the XLA idiom for workload shapes):
@@ -171,7 +173,7 @@ class Resolver:
             self._range_history = False
             if self._fast_params is not None:
                 self._fast = (
-                    BatchPacker(self._fast_params),
+                    BatchPacker(self._fast_params, buckets=self.buckets),
                     ck.make_resolve_fn(self._fast_params),
                 )
             # scan fns for backlog dispatch (resolve_many), cached per
@@ -201,6 +203,42 @@ class Resolver:
         else:
             raise ValueError(f"unknown resolver_backend {self.backend!r}")
         self.adopt_profile(self.profile)  # attach the packer hooks
+
+    def _init_buckets(self):
+        """The coarse lanes' bucket function (resolver/packing.py
+        ``CoarseBuckets``), one for every packer of this resolver, and
+        what :meth:`_maybe_rebucket` needs: whether a range has been met
+        (until then no coarse lane is read and the first limb's bits
+        stay), and the jitted fold, built at the first rebucket."""
+        self.buckets = CoarseBuckets(self.params)
+        self._ranges_seen = False
+        self._fold = None
+
+    def _maybe_rebucket(self):
+        """Cut the coarse buckets again where the sample says so, and
+        fold what the device's summaries hold (ops/conflict.py
+        ``fold_coarse``) before the next step packs under the new
+        boundaries. Called between steps, on the dispatching thread; a
+        resolver that has met no range returns at once."""
+        if not (self._ranges_seen and self.buckets.recut_due()):
+            return
+        with span_mod.stage("resolver.rebucket", self.profile):
+            if self.buckets.recut():
+                if self._fold is None:
+                    self._fold = ck.make_fold_fn(self.params, self.state)
+                self.state = self._fold(self.state)
+                self.profile.count(rebuckets=1)
+
+    def _plain_statuses(self, statuses):
+        """A dispatch's verdicts as the proxy takes them: the device's
+        fourth code (a refusal only a coarse summary raised) is counted
+        and answered as the CONFLICT it is."""
+        n = statuses.count(CONFLICT_COARSE)
+        if n:
+            self.profile.count(conflicts_coarse_only=n)
+            statuses = [CONFLICT if s == CONFLICT_COARSE else s
+                        for s in statuses]
+        return statuses
 
     def adopt_profile(self, profile):
         """Adopt a cluster-owned :class:`DeviceProfile` (the registry
@@ -318,6 +356,7 @@ class Resolver:
             else:
                 live.append((i, t))
         use_fast = self._pick_fast(t for _, t in live)
+        self._maybe_rebucket()
         packer, resolve_fn = self._fast if use_fast else (
             self.packer, self._resolve
         )
@@ -337,6 +376,15 @@ class Resolver:
                 self.profile.record_dispatch(
                     bucket=1, live_batches=1, live_txns=len(chunk),
                     txn_slots=pp.txns,
+                    entries_live={
+                        "pr": sum(len(t.point_reads) for _, t in chunk),
+                        "pw": sum(len(t.point_writes) for _, t in chunk),
+                        "rr": sum(len(t.range_reads) for _, t in chunk),
+                        "rw": sum(len(t.range_writes) for _, t in chunk)},
+                    entry_slots={"pr": pp.txns * pp.point_reads,
+                                 "pw": pp.txns * pp.point_writes,
+                                 "rr": pp.txns * pp.range_reads,
+                                 "rw": pp.txns * pp.range_writes},
                     transfer_bytes=sum(
                         int(x.nbytes) for x in jax.tree.leaves(batch)),
                     wall_s=step_s)
@@ -370,7 +418,8 @@ class Resolver:
             # self.state would hold poisoned arrays
             with rdb:
                 status = np.asarray(status)
-            return status[:n].tolist(), enq.seconds + rdb.seconds
+            return (self._plain_statuses(status[:n].tolist()),
+                    enq.seconds + rdb.seconds)
         except Exception as e:
             if (not self.params.use_pallas
                     or resolve_fn is not self._resolve
@@ -437,6 +486,7 @@ class Resolver:
             return self.resolve(flat.to_txn_requests(), commit_version,
                                 new_window_start)
         use_fast = self._pick_fast_flat([flat])
+        self._maybe_rebucket()
         packer, resolve_fn = self._fast if use_fast else (
             self.packer, self._resolve
         )
@@ -501,8 +551,6 @@ class Resolver:
         (see __init__) — and the sticky _range_history update when a
         range write (or a point-write spill, which the packer records as
         ring history) appears."""
-        if self._fast is None:
-            return False
         point_only = True
         pr_cap = self.params.point_reads
         pw_cap = self.params.point_writes
@@ -513,14 +561,15 @@ class Resolver:
                 break
             if t.range_reads or len(t.point_reads) > pr_cap:
                 point_only = False  # needs range lanes this batch
-        return point_only and not self._range_history
+        if not point_only:
+            self._ranges_seen = True  # the coarse lanes are in use now
+        return (self._fast is not None and point_only
+                and not self._range_history)
 
     def _pick_fast_flat(self, flats):
         """_pick_fast's columnar twin — count maxima instead of per-txn
         walks. Callers route lane-overflowing batches to the legacy
         path first, so only range presence matters here."""
-        if self._fast is None:
-            return False
         point_only = True
         for f in flats:
             if f.rwc.max(initial=0) > 0:
@@ -529,7 +578,10 @@ class Resolver:
                 break
             if f.rrc.max(initial=0) > 0:
                 point_only = False
-        return point_only and not self._range_history
+        if not point_only:
+            self._ranges_seen = True
+        return (self._fast is not None and point_only
+                and not self._range_history)
 
     def resolve_many(self, batches, lazy=False):
         """Resolve a BACKLOG of batches in one device dispatch.
@@ -643,6 +695,7 @@ class Resolver:
             per_batch.append((statuses, live, cv, ws))
             all_live.extend(t for _, t in live)
         use_fast = self._pick_fast(all_live)
+        self._maybe_rebucket()
         packer = self._fast[0] if use_fast else self.packer
         packed = [
             packer.pack([t for _, t in live], self.base_version, cv, ws)
@@ -702,7 +755,7 @@ class Resolver:
                     deviceprofile.now() - rt0)
             out = []
             for b, (statuses, live, cv, ws) in enumerate(per_batch):
-                row = arr[b][: len(live)].tolist()
+                row = self._plain_statuses(arr[b][: len(live)].tolist())
                 for (i, _), s in zip(live, row):
                     statuses[i] = s
                 out.append(statuses)
@@ -739,6 +792,7 @@ class Resolver:
             ):
                 return None
         use_fast = self._pick_fast_flat(flats)
+        self._maybe_rebucket()
         packer = self._fast[0] if use_fast else self.packer
         B = self._pad_bucket(len(flats))
         stacked = packer.pack_flat_group(
@@ -788,7 +842,8 @@ class Resolver:
                 self.profile.record_verdict_reduce(
                     deviceprofile.now() - rt0)
             return [
-                arr[b][: len(f)].tolist() for b, f in enumerate(flats)
+                self._plain_statuses(arr[b][: len(f)].tolist())
+                for b, f in enumerate(flats)
             ]
 
         return ResolveHandle(materialize=materialize)

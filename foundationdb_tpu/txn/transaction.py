@@ -552,8 +552,11 @@ class Transaction:
         def record_conflict(out):
             if snapshot:
                 return
-            # conflict range covers what was actually observed
-            if limit and out:
+            # conflict range covers what was actually observed: up to
+            # the last row where the limit cut the scan short, and the
+            # whole of [b, e) where the range ran out first (a row that
+            # appears behind the last one would have been returned)
+            if limit and len(out) >= limit:
                 hi = key_successor(out[-1][0]) if not reverse else e
                 lo = b if not reverse else out[-1][0]
                 self._add_read_conflict(lo, hi)

@@ -33,8 +33,9 @@ commit, the traced pair in PERF.md), not a CPU gate's.
 The resolver's host stages reach the profile through
 ``utils/span.stage(name, stats=profile)``: :meth:`DeviceProfile.add`
 maps ``resolver.pack`` / ``resolver.enqueue`` / ``resolver.readback``
-/ ``resolver.route`` to ``pack_wall_ms`` / ``enqueue_wall_ms`` /
-``verdict_reduce_wall_ms`` / ``route_wall_ms``.
+/ ``resolver.route`` / ``resolver.rebucket`` to ``pack_wall_ms`` /
+``enqueue_wall_ms`` / ``verdict_reduce_wall_ms`` / ``route_wall_ms`` /
+``rebucket_wall_ms``.
 """
 
 import os
@@ -64,6 +65,7 @@ STAGE_WALLS = {
     "resolver.enqueue": "enqueue_wall_s",
     "resolver.readback": "verdict_reduce_wall_s",
     "resolver.route": "route_wall_s",
+    "resolver.rebucket": "rebucket_wall_s",
 }
 
 # the plain counters (:meth:`DeviceProfile.count`), each summed over
@@ -73,9 +75,21 @@ STAGE_WALLS = {
 # fullest lane. ``h2d_args``, counted by the resolver beside each
 # jitted call: the host arrays the dispatch handed its program (the
 # state is on the device already) — the batch's 22 fields on one
-# device, one array on a mesh (``ops/conflict.pack_args``)
+# device, one array on a mesh (``ops/conflict.pack_args``). The coarse
+# buckets' (resolver/packing.py ``CoarseBuckets``): ``rebuckets``, the
+# times ``Resolver._maybe_rebucket`` cut new boundaries and folded the
+# device's summaries; ``conflicts_coarse_only``, transactions refused
+# where only a coarse summary stood in the way (the device's fourth
+# status code: an upper bound on the false conflicts the summaries
+# cost); ``bucket_entries_routed`` / ``bucket_entries_fullest``, a
+# pack's live point writes and those of them in its fullest bucket,
+# counted once boundaries are cut
 PLAIN_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
-                  "lane_entries_fullest", "h2d_args")
+                  "lane_entries_fullest", "h2d_args", "rebuckets",
+                  "conflicts_coarse_only", "bucket_entries_routed",
+                  "bucket_entries_fullest")
+# the stage walls that ride beside them through absorb and snapshot
+PLAIN_WALLS = ("route_wall_s", "rebucket_wall_s")
 
 
 def set_enabled(on):
@@ -145,6 +159,8 @@ class DeviceProfile:
         self.lane_entries = []
         self.lane_dispatches = 0
         self.route_wall_s = 0.0  # stage resolver.route: the router's split
+        # stage resolver.rebucket: sample → boundaries → fold
+        self.rebucket_wall_s = 0.0
         for c in PLAIN_COUNTERS:
             setattr(self, c, 0)
         # fallback-cause taxonomy
@@ -303,8 +319,8 @@ class DeviceProfile:
                 "lane_dispatches": other.lane_dispatches,
                 "fallback_causes": dict(other.fallback_causes),
                 "kernel_routes": dict(other.kernel_routes),
-                "route_wall_s": other.route_wall_s,
-                **{c: getattr(other, c) for c in PLAIN_COUNTERS},
+                **{c: getattr(other, c)
+                   for c in PLAIN_WALLS + PLAIN_COUNTERS},
             }
         with self._lock:
             self.dispatches += o["dispatches"]
@@ -342,7 +358,7 @@ class DeviceProfile:
             for i, c in enumerate(o["lane_entries"]):
                 self.lane_entries[i] += c
             self.lane_dispatches += o["lane_dispatches"]
-            for c in ("route_wall_s",) + PLAIN_COUNTERS:
+            for c in PLAIN_WALLS + PLAIN_COUNTERS:
                 setattr(self, c, getattr(self, c) + o[c])
             for c, v in o["fallback_causes"].items():
                 self.fallback_causes[c] = (
@@ -405,6 +421,7 @@ class DeviceProfile:
                 "lane_entries": entries,
                 "lane_skew_pct": lane_skew,
                 "route_wall_ms": round(self.route_wall_s * 1e3, 3),
+                "rebucket_wall_ms": round(self.rebucket_wall_s * 1e3, 3),
                 **{c: getattr(self, c) for c in PLAIN_COUNTERS},
                 "fallback_causes": dict(sorted(
                     self.fallback_causes.items())),

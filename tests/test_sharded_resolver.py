@@ -17,6 +17,7 @@ from tests.test_resolver import (
     SMALL,
     exact_serializability_check,
     oracle_batches,
+    plain_status,
     run_batches,
 )
 
@@ -34,7 +35,7 @@ def run_sharded(batches, mesh, params=SMALL, base=0):
     for txns, cv, ws in batches:
         b = packer.pack(txns, base, cv, ws)
         status, _ = kern.resolve(b)
-        out.append(np.asarray(status)[: len(txns)].tolist())
+        out.append(plain_status(status)[: len(txns)].tolist())
     return out
 
 
@@ -145,11 +146,11 @@ def test_resolve_many_matches_sequential(mesh8):
     want = []
     for b, (txns, _, _) in zip(packed, batches):
         status, _ = kern1.resolve(b)
-        want.append(np.asarray(status)[: len(txns)].tolist())
+        want.append(plain_status(status)[: len(txns)].tolist())
 
     kern2 = ShardedResolverKernel(params, mesh=mesh8, donate=False)
     stacked = _jax.tree.map(lambda *xs: np.stack(xs), *packed)
-    statuses = np.asarray(kern2.resolve_many(stacked))
+    statuses = plain_status(kern2.resolve_many(stacked))
     got = [
         statuses[i][: len(batches[i][0])].tolist() for i in range(len(batches))
     ]
