@@ -38,6 +38,7 @@ from foundationdb_tpu.rpc.transport import (
     WEDGED_STRIKE_LIMIT,
     ConnectionLost,
     DeadlineExceeded,
+    Park,
     RpcServer,
     connect_any,
     rpc_class,
@@ -184,6 +185,27 @@ class ClusterService:
             "feed_list": self.cluster.change_feeds.list,
         }
 
+    # watch_wait parks until its watch fires: the blocking pool
+    LONG_METHODS = frozenset({"watch_wait"})
+
+    def inline_methods(self):
+        """The endpoints the RpcServer may answer on a connection's own
+        thread: their handlers wait on no other thread. ``ping`` is a
+        constant; a read takes the storage's mutex for one lookup —
+        where the read surface is this process's own (a surface over
+        the wire would park the connection in its round trip: then
+        reads stay on the pool); a GRV is granted now or hands the
+        server a ``Park`` (``get_read_version``). Commits wait for
+        their batch, watches for their key, admin calls for whatever
+        they manage: not declared, so they ride the pools."""
+        from foundationdb_tpu.server.storage import RangeReadInterface
+
+        inline = {"ping", "get_read_version"}
+        if isinstance(self.cluster.read_storage(), RangeReadInterface):
+            inline |= {"storage_get", "get_range", "resolve_selector",
+                       "read_batch"}
+        return inline
+
     def hello(self, client_protocol):
         if client_protocol != PROTOCOL_VERSION:
             raise FDBError.from_name("incompatible_protocol_version")
@@ -226,9 +248,17 @@ class ClusterService:
         return self.cluster.set_consistency_scan(bool(on))
 
     def get_read_version(self, priority="default", tags=()):
-        return self.cluster.grv_proxy.get_read_version(
-            priority, tags=tuple(tags)
-        )
+        proxy = self.cluster.grv_proxy
+        grant_now = getattr(proxy, "grant_now", None)
+        if grant_now is None:
+            # the synchronous proxy grants or refuses; it never waits
+            return proxy.get_read_version(priority, tags=tuple(tags))
+        v = grant_now(priority, tuple(tags))
+        if v is None:
+            # the request has to queue behind the grant loop: that wait
+            # must not hold the connection it came in on
+            return Park(lambda: proxy.wait_for_grant(priority))
+        return v
 
     def storage_get(self, key, rv):
         return self.cluster.read_storage(key).get(key, rv)
@@ -413,7 +443,9 @@ def serve_cluster(cluster, host="127.0.0.1", port=0, max_workers=16,
     service = ClusterService(cluster)
     server = RpcServer(host, port, service.handlers(),
                        max_workers=max_workers,
-                       long_methods={"watch_wait"}, secret=secret)
+                       long_methods=service.LONG_METHODS,
+                       inline_methods=service.inline_methods(),
+                       secret=secret)
     service.rpc_server = server
     # tlog_peek long-polls; it must not occupy the short-RPC pool
     server.add_handlers(LogFeed(cluster).handlers(),

@@ -5,8 +5,12 @@ length-prefixed packets addressed to endpoint tokens; replies are matched
 to requests by id; a connection failure fails every outstanding request
 on it. The reference multiplexes actor futures over one socket per peer;
 here a reader thread per connection completes `concurrent.futures`
-futures, and server handlers run on a shared pool so a blocking endpoint
-(a watch wait, a batched GRV) never stalls the socket.
+futures. The server reads a connection in bursts (one ``recv``, every
+complete frame in it) and answers a request on the thread that read it
+where its endpoint was declared unable to wait on another thread
+(``inline_methods``: a storage read, a GRV that can be granted now);
+every other handler runs on a shared pool, so a blocking endpoint (a
+commit, a watch wait, a queued GRV) never stalls the socket.
 
 Frame: 4-byte big-endian length + wire payload.
 Request: ("q", seq, method, args-tuple)  Reply: ("r", seq, ok, payload).
@@ -37,6 +41,7 @@ from foundationdb_tpu.utils import span as span_mod
 from foundationdb_tpu.utils.trace import SEV_ERROR, TraceEvent
 
 MAX_FRAME = 64 * 1024 * 1024
+_FRAME_LEN = struct.Struct(">I")
 _AUTH_CONTEXT = b"fdbtpu-rpc-auth-v1:"
 _AUTH_HANDSHAKE_TIMEOUT_S = 5.0
 # deadline-sweep cadence: the client reader blocks in recv at most this
@@ -82,7 +87,7 @@ def rpc_class(method):
 # system call there, 6 µs against a read handler of 36 µs, and two on
 # every request cost a tenth of the ycsb cell's ops_per_s.
 RPC_COUNTERS = ("requests", "timed_requests", "decode_us", "queue_wait_us",
-                "handler_wall_us", "reply_us")
+                "handler_wall_us", "reply_us", "inline_requests")
 TIME_EVERY = 4
 _RPC_STAGE = {c: "rpc." + c for c in RPC_CLASSES}
 
@@ -132,10 +137,14 @@ class ConnectionLost(ConnectionError):
     """The peer vanished with requests outstanding."""
 
 
-def _send_frame(sock, lock, payload: bytes):
+def _frame(payload: bytes):
     if len(payload) > MAX_FRAME:
         raise ValueError(f"frame too large: {len(payload)}")
-    msg = struct.pack(">I", len(payload)) + payload
+    return _FRAME_LEN.pack(len(payload)) + payload
+
+
+def _send_frame(sock, lock, payload: bytes):
+    msg = _frame(payload)
     with lock:
         # this per-socket lock EXISTS to serialize whole-frame sends —
         # interleaved partial frames would corrupt the stream; nothing
@@ -161,38 +170,67 @@ def _recv_frame(sock):
     return _recv_exact(sock, n)
 
 
-class _FrameReader:
-    """Buffered frame reader that survives ``socket.timeout`` mid-frame.
+class Park:
+    """What a handler returns in place of a result when the rest of its
+    request may wait on another thread: ``resume()`` gives the result.
+    On a pool thread the server runs it at once; on a connection's own
+    thread (an ``inline_methods`` endpoint) it goes to the pool, so a
+    request that has to wait never holds the connection."""
 
-    The client reader runs its socket with a short timeout so it can
-    sweep request deadlines between frames. ``_recv_exact`` would LOSE
-    partially-received bytes on a timeout and desync the stream; this
-    reader keeps partial state across ticks, so a timeout is always a
+    __slots__ = ("resume",)
+
+    def __init__(self, resume):
+        self.resume = resume
+
+
+class _FrameReader:
+    """Buffered frame reader: one ``recv`` of up to 64 KB, every
+    complete frame parsed out of the buffer.
+
+    It survives ``socket.timeout`` mid-frame. The client reader runs its
+    socket with a short timeout so it can sweep request deadlines
+    between frames; ``_recv_exact`` would LOSE partially-received bytes
+    on a timeout and desync the stream. A partial frame, header
+    included, stays in the buffer across ticks, so a timeout is always a
     clean "nothing complete yet — go sweep" signal.
     """
 
     def __init__(self, sock):
         self._sock = sock
         self._buf = bytearray()
-        self._need = None  # payload length once the header is parsed
+        self.recvs = 0  # socket reads that returned bytes
 
-    def recv_frame(self):
+    def recv_burst(self):
+        """Every complete frame buffered, at least one: blocks in
+        ``recv`` (which may raise ``socket.timeout``) until a frame is
+        whole. A length over ``MAX_FRAME`` fails the connection at its
+        header, before a byte of the payload is buffered."""
+        buf = self._buf
+        frames = []
         while True:
-            if self._need is None and len(self._buf) >= 4:
-                (n,) = struct.unpack(">I", bytes(self._buf[:4]))
+            off, have, want = 0, len(buf), 65536
+            while have - off >= 4:
+                (n,) = _FRAME_LEN.unpack_from(buf, off)
                 if n > MAX_FRAME:
+                    if frames:
+                        break  # the frames in front of it are served first
                     raise ConnectionLost(f"oversized frame: {n}")
-                del self._buf[:4]
-                self._need = n
-            if self._need is not None and len(self._buf) >= self._need:
-                payload = bytes(self._buf[: self._need])
-                del self._buf[: self._need]
-                self._need = None
-                return payload
-            chunk = self._sock.recv(65536)  # may raise socket.timeout
+                end = off + 4 + n
+                if end > have:
+                    # a large frame: ask for the rest of it in one read
+                    want = max(want, end - have)
+                    break
+                frames.append(bytes(memoryview(buf)[off + 4:end]))
+                off = end
+            if off:
+                del buf[:off]
+            if frames:
+                return frames
+            chunk = self._sock.recv(want)
             if not chunk:
                 raise ConnectionLost("peer closed")
-            self._buf += chunk
+            self.recvs += 1
+            buf += chunk
 
 
 class RpcServer:
@@ -203,22 +241,33 @@ class RpcServer:
     (the client re-raises it); any other exception becomes a generic
     remote failure string.
 
+    ``inline_methods`` names the endpoints whose handler cannot wait
+    on another thread, declared by the code that registers them and
+    knows what they touch: a request to one is handled and answered on
+    the thread that owns its connection, with no hand-off to the pool.
+    A connection multiplexes its client's threads, so nothing that can
+    park may be declared; a handler that finds it has to wait after all
+    returns a :class:`Park` and its request moves to the pool. An
+    endpoint not declared runs on the pool.
+
     Every fourth request of a class is stamped on the injected clock at
     its layer boundaries — frame read, decoded, handler start (on the
-    pool thread), handler end, reply sent — and the differences
-    accumulate per RPC class (:meth:`stats`): what a request cost in
-    decode, in the pool's queue, in its handler and in the reply. Every
-    request is counted, and annotated for the profiler. One locked add
-    a request.
+    pool thread, or this one), handler end, reply sent — and the
+    differences accumulate per RPC class (:meth:`stats`): what a request
+    cost in decode, in the pool's queue, in its handler and in the
+    reply. Every request is counted, and annotated for the profiler.
+    One locked add a request on a pool thread, one a burst on a
+    connection's own.
     """
 
     def __init__(self, host, port, handlers, max_workers=16,
-                 long_methods=(), secret=None):
+                 long_methods=(), inline_methods=(), secret=None):
         self.secret = secret
         self.handlers = dict(handlers)
         # endpoints that legitimately block (watch waits) run on their
         # own pool so parked waiters cannot starve short RPCs
         self.long_methods = set(long_methods)
+        self.inline_methods = set(inline_methods) - self.long_methods
         self._listener = socket.create_server(
             (host, port), reuse_port=False, backlog=64
         )
@@ -230,7 +279,7 @@ class RpcServer:
         )
         self._stats_lock = lockdep.lock("RpcServer._stats_lock")
         # per class, in RPC_COUNTERS' order; seconds until stats()
-        self._acc = {c: [0, 0, 0.0, 0.0, 0.0, 0.0] for c in RPC_CLASSES}
+        self._acc = {c: [0, 0, 0.0, 0.0, 0.0, 0.0, 0] for c in RPC_CLASSES}
         # requests decoded per class, for the sampling alone: bumped by
         # every connection thread without a lock (a lost count moves a
         # sample by one request)
@@ -246,7 +295,8 @@ class RpcServer:
             if self.long_methods
             else None
         )
-        self._conns = set()
+        self._conns = {}  # socket -> its frame reader, once authenticated
+        self._recv_retired = 0  # socket reads of connections since closed
         self._lock = lockdep.lock("RpcServer._lock")
         self._closed = threading.Event()
         self._accept_thread = threading.Thread(
@@ -258,14 +308,15 @@ class RpcServer:
     def address(self):
         return f"{self.host}:{self.port}"
 
-    def add_handlers(self, handlers, long_methods=()):
+    def add_handlers(self, handlers, long_methods=(), inline_methods=()):
         """Register more endpoints on a live server (an fdbserver process
         brings its coordinator endpoints up first so peers can reach the
         quorum, then attaches the cluster service after recovery).
 
         Long-method routing is installed BEFORE the handlers become
         callable: a blocking endpoint must never be reachable while it
-        would still dispatch onto the short-RPC pool."""
+        would still dispatch onto the short-RPC pool. Inline routing is
+        installed AFTER them: until then the endpoint rides the pool."""
         new_long = set(long_methods) - self.long_methods
         if new_long:
             if self._long_pool is None:
@@ -274,6 +325,7 @@ class RpcServer:
                 )
             self.long_methods |= new_long
         self.handlers.update(handlers)
+        self.inline_methods |= set(inline_methods) - self.long_methods
 
     def _accept_loop(self):
         while not self._closed.is_set():
@@ -285,7 +337,7 @@ class RpcServer:
                 return
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             with self._lock:
-                self._conns.add(sock)
+                self._conns[sock] = None
             threading.Thread(
                 target=self._serve_conn, args=(sock, peer),
                 name=f"rpc-conn-{peer}", daemon=True,
@@ -324,47 +376,83 @@ class RpcServer:
         try:
             if self.secret is not None:
                 self._authenticate(sock, send_lock, peer)
+            reader = _FrameReader(sock)
+            with self._lock:
+                self._conns[sock] = reader
             while not self._closed.is_set():
-                frame = _recv_frame(sock)
+                frames = reader.recv_burst()
+                # every frame of a burst was read by this recv: a
+                # frame's wait behind the earlier ones is queue wait
                 t_recv = span_mod.now()
-                msg = wire.loads(frame)
-                # protocol v5: an optional TRACING frame rides as a 5th
-                # element (the caller's SpanContext); shorter tuples are
-                # the untraced form — peers ignore what isn't there
-                kind, seq, method, args = msg[0], msg[1], msg[2], msg[3]
-                trace_ctx = msg[4] if len(msg) > 4 else None
-                if kind != "q":
-                    raise ConnectionLost(f"unexpected message kind {kind!r}")
-                pool = (
-                    self._long_pool
-                    if self._long_pool is not None
-                    and method in self.long_methods
-                    else self._pool
-                )
-                cls = rpc_class(method)
-                # stamps for the first request of a class and every
-                # fourth after it
-                seen = self._seen[cls]
-                self._seen[cls] = seen + 1
-                timed = seen % TIME_EVERY == 0
-                pool.submit(
-                    self._dispatch, sock, send_lock, seq, method, args,
-                    trace_ctx, cls, t_recv,
-                    span_mod.now() if timed else None,
-                )
-                if timed and pool is self._pool:
-                    queued = self._queue_depth()
-                    if queued > self._queued_high_water:
-                        self._queued_high_water = queued
+                burst = []
+                try:
+                    for frame in frames:
+                        burst.append(self._decode(frame, t_recv))
+                finally:
+                    # a frame that cannot be decoded fails the
+                    # connection once those in front of it are served
+                    self._answer(sock, send_lock, burst)
         except (ConnectionLost, ConnectionError, OSError, ValueError):
             pass
         finally:
             with self._lock:
-                self._conns.discard(sock)
+                reader = self._conns.pop(sock, None)
+                if reader is not None:
+                    self._recv_retired += reader.recvs
             try:
                 sock.close()
             except OSError:
                 pass
+
+    def _decode(self, frame, t_recv):
+        """A request frame → (seq, method, args, trace_ctx, cls, t_recv,
+        t_decoded); ``t_decoded`` only where the request is a timed one:
+        the first of its class and every fourth after it."""
+        msg = wire.loads(frame)
+        # protocol v5: an optional TRACING frame rides as a 5th element
+        # (the caller's SpanContext); shorter tuples are the untraced
+        # form — peers ignore what isn't there
+        kind, seq, method, args = msg[0], msg[1], msg[2], msg[3]
+        if kind != "q":
+            raise ConnectionLost(f"unexpected message kind {kind!r}")
+        cls = rpc_class(method)
+        seen = self._seen[cls]
+        self._seen[cls] = seen + 1
+        return (seq, method, args, msg[4] if len(msg) > 4 else None, cls,
+                t_recv, span_mod.now() if seen % TIME_EVERY == 0 else None)
+
+    def _answer(self, sock, send_lock, burst):
+        """A burst's requests in their order: each handed to a pool or
+        answered here, and what was answered here sent in one send (each
+        reply a whole frame) and counted in one locked add."""
+        answered = []  # by this thread: (frame, cls, stamps)
+        for request in burst:
+            self._route(sock, send_lock, request, answered)
+        if answered:
+            self._send(sock, send_lock, b"".join([a[0] for a in answered]))
+            self._count(answered, span_mod.now(), inline=1)
+
+    def _route(self, sock, send_lock, request, answered):
+        """One decoded request to the thread that answers it: this one
+        for a declared endpoint, unless its handler parks; else a pool."""
+        seq, method, _args, trace_ctx, cls, t_recv, t_decoded = request
+        fn = self.handlers.get(method)
+        if method in self.inline_methods:
+            parked = self._dispatch(sock, send_lock, fn, request, answered)
+            if parked is None:
+                return
+            fn = parked.resume
+            request = (seq, method, (), trace_ctx, cls, t_recv, t_decoded)
+        pool = (
+            self._long_pool
+            if self._long_pool is not None and method in self.long_methods
+            else self._pool
+        )
+        pool.submit(self._dispatch, sock, send_lock, fn, request)
+        if t_decoded is not None and pool is self._pool:
+            queued = self._queue_depth()
+            if queued > self._queued_high_water:
+                self._queued_high_water = queued
 
     def _queue_depth(self):
         """Requests decoded and not yet running on the short pool: the
@@ -372,8 +460,13 @@ class RpcServer:
         locked adds a request)."""
         return self._pool._work_queue.qsize()
 
-    def _dispatch(self, sock, send_lock, seq, method, args, trace_ctx,
-                  cls, t_recv, t_decoded):
+    def _dispatch(self, sock, send_lock, fn, request, answered=None):
+        """Handle one request on this thread. A pool thread sends the
+        reply and counts the request. A connection's own thread hands in
+        ``answered``, where the burst's replies gather for one send and
+        one count; a handler that parks there gets its :class:`Park`
+        back unanswered, for the caller to hand on."""
+        seq, method, args, trace_ctx, cls, t_recv, t_decoded = request
         timed = t_decoded is not None
         prior_ctx = None
         if trace_ctx is not None:
@@ -382,29 +475,47 @@ class RpcServer:
             # opens child spans off span.current() without every
             # handler signature growing a tracing parameter
             prior_ctx = span_mod.set_current(tuple(trace_ctx))
-        # rpc.<class>, handler start → reply sent, on this thread: a
-        # stage where its stamps or its span are wanted, else the
-        # profiler annotation alone
+        # rpc.<class>, handler start → reply sent (or, of a burst's,
+        # encoded), on this thread: a stage where its stamps or its
+        # span are wanted, else the profiler annotation alone
         scope = span_mod.stage if timed or trace_ctx is not None \
             else span_mod.annotation
+        stamps = None
         try:
             with scope(_RPC_STAGE[cls]) as st:
-                ok, payload = self._handle(method, args)
+                ok, payload = self._handle(fn, method, args)
+                if type(payload) is Park:
+                    if answered is not None:
+                        return payload
+                    ok, payload = self._handle(payload.resume, method, ())
                 if timed:
-                    t_handled = span_mod.now()
-                self._reply(sock, send_lock, seq, method, ok, payload)
+                    stamps = (t_recv, t_decoded, st.t0, span_mod.now())
+                frame = self._encode_reply(seq, method, ok, payload)
+                if answered is None:
+                    self._send(sock, send_lock, frame)
         finally:
             if trace_ctx is not None:
                 span_mod.set_current(prior_ctx)
-        acc = self._acc[cls]
+        if answered is None:
+            self._count(((frame, cls, stamps),), st.t1 if timed else 0.0)
+        else:
+            answered.append((frame, cls, stamps))
+
+    def _count(self, answered, t_sent, inline=0):
+        """The one locked add, after the send: a pool thread's request,
+        or the burst a connection's own thread answered."""
         with self._stats_lock:
-            acc[0] += 1
-            if timed:
-                acc[1] += 1
-                acc[2] += t_decoded - t_recv
-                acc[3] += st.t0 - t_decoded
-                acc[4] += t_handled - st.t0
-                acc[5] += st.t1 - t_handled
+            for _frame, cls, stamps in answered:
+                acc = self._acc[cls]
+                acc[0] += 1
+                acc[6] += inline
+                if stamps is not None:
+                    t_recv, t_decoded, t0, t_handled = stamps
+                    acc[1] += 1
+                    acc[2] += t_decoded - t_recv
+                    acc[3] += t0 - t_decoded
+                    acc[4] += t_handled - t0
+                    acc[5] += t_sent - t_handled
 
     @staticmethod
     def _remote_failure(method, e):
@@ -415,10 +526,9 @@ class RpcServer:
             error=str(e)[:200]).log()
         return False, f"{type(e).__name__}: {e}"
 
-    def _handle(self, method, args):
+    def _handle(self, fn, method, args):
         """Run the handler → (ok, payload); never raises."""
         try:
-            fn = self.handlers.get(method)
             if fn is None:
                 raise KeyError(f"no such endpoint: {method}")
             return True, fn(*args)
@@ -429,18 +539,25 @@ class RpcServer:
 
     def stats(self):
         """``cluster.rpc`` of the status document: the per-class totals
-        (``<counter>.<class>``), the short pool's size and the deepest
+        (``<counter>.<class>``), the socket reads that returned bytes
+        (``recv_calls``: requests / recv_calls is how many requests a
+        read brings), the short pool's size and the deepest
         its queue has been seen (looked at with every timed request),
         and this process's CPU and wall time, read
         now (no hot-path cost): Δcpu/Δwall ≈ 1.0 over a busy interval
         means the interpreter lock is the machine."""
         with self._stats_lock:
             acc = {c: list(v) for c, v in self._acc.items()}
+        with self._lock:
+            # a reader's count is its connection thread's alone to write
+            recv_calls = self._recv_retired + sum(
+                r.recvs for r in self._conns.values() if r is not None)
         # a clock that stepped backwards counts 0, never negative
         doc = {counter: {c: v[i] if isinstance(v[i], int)
                          else round(max(0.0, v[i]) * 1e6)
                          for c, v in acc.items()}
                for i, counter in enumerate(RPC_COUNTERS)}
+        doc["recv_calls"] = recv_calls
         doc["pool"] = {"workers": self.max_workers,
                        "queued": self._queue_depth(),
                        "queued_high_water": self._queued_high_water}
@@ -450,27 +567,27 @@ class RpcServer:
         }
         return doc
 
-    def _reply(self, sock, send_lock, seq, method, ok, payload):
-        """Encode and send; a result the wire cannot carry becomes a
-        generic remote failure, like a handler that raised."""
+    def _encode_reply(self, seq, method, ok, payload):
+        """One whole reply frame. A result the wire cannot carry, or one
+        over ``MAX_FRAME``, becomes a generic remote failure, like a
+        handler that raised: the client must still get an answer or its
+        future hangs forever."""
         try:
-            reply = wire.dumps(("r", seq, ok, payload))
+            return _frame(wire.dumps(("r", seq, ok, payload)))
         except Exception as e:
-            reply = wire.dumps(("r", seq, *self._remote_failure(method, e)))
+            return _frame(wire.dumps(
+                ("r", seq, *self._remote_failure(method, e))))
+
+    @staticmethod
+    def _send(sock, send_lock, frames):
+        """Whole frames under the connection's send lock, which EXISTS
+        to keep them whole (see ``_send_frame``); a client that vanished
+        has nothing to be told."""
         try:
-            _send_frame(sock, send_lock, reply)
+            with send_lock:
+                sock.sendall(frames)  # flowlint: disable=FL003
         except (ConnectionError, OSError):
-            pass  # client vanished; nothing to tell it
-        except ValueError:
-            # reply exceeds MAX_FRAME: the client must still get an answer
-            # or its future hangs forever — send the error instead
-            try:
-                _send_frame(sock, send_lock, wire.dumps((
-                    "r", seq, False,
-                    f"ValueError: reply to {method} exceeds frame limit",
-                )))
-            except (ConnectionError, OSError, ValueError):
-                pass
+            pass
 
     def close(self):
         self._closed.set()
@@ -565,28 +682,32 @@ class RpcClient:
             self._sock.settimeout(_DEADLINE_TICK_S)
             while True:
                 try:
-                    frame = reader.recv_frame()
+                    frames = reader.recv_burst()
                 except socket.timeout:
                     self._sweep_deadlines()
                     continue
                 self.last_activity = time.monotonic()
                 self.deadline_strikes = 0  # the link demonstrably moves data
-                kind, seq, ok, payload = wire.loads(frame)
-                with self._state_lock:
-                    entry = self._pending.pop(seq, None)
-                if entry is None:
-                    continue  # cancelled/timed-out request
-                fut = entry[0]
-                if fut.done():
-                    continue  # already deadline-settled
-                if ok:
-                    fut.set_result(payload)
-                elif isinstance(payload, FDBError):
-                    fut.set_exception(payload)
-                else:
-                    fut.set_exception(RemoteError(str(payload)))
+                for frame in frames:
+                    _kind, seq, ok, payload = wire.loads(frame)
+                    self._settle(seq, ok, payload)
         except (ConnectionLost, ConnectionError, OSError, ValueError) as e:
             self._fail_all(e)
+
+    def _settle(self, seq, ok, payload):
+        with self._state_lock:
+            entry = self._pending.pop(seq, None)
+        if entry is None:
+            return  # cancelled/timed-out request
+        fut = entry[0]
+        if fut.done():
+            return  # already deadline-settled
+        if ok:
+            fut.set_result(payload)
+        elif isinstance(payload, FDBError):
+            fut.set_exception(payload)
+        else:
+            fut.set_exception(RemoteError(str(payload)))
 
     def _sweep_deadlines(self):
         """Settle every request past its deadline with DeadlineExceeded.

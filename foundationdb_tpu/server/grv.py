@@ -129,6 +129,15 @@ class BatchingGrvProxy:
         return getattr(self.inner, name)
 
     def get_read_version(self, priority="default", tags=()):
+        v = self.grant_now(priority, tags)
+        return self.wait_for_grant(priority) if v is None else v
+
+    def grant_now(self, priority="default", tags=()):
+        """All of a GRV short of waiting: the checks, the tags'
+        attribution and the uncontended grant. → the version, or None
+        where the request has to queue: the caller then owes one
+        ``wait_for_grant``, which may park (the RPC server runs this
+        half on a connection's own thread and that one on its pool)."""
         if not getattr(self.inner.sequencer, "alive", True):
             # dead version authority: stall retryably (1037) — the fast
             # path and grant loop read committed_version directly, so
@@ -150,38 +159,41 @@ class BatchingGrvProxy:
             # committed-version read for the whole round): attribute the
             # start HERE, where the tags are still in hand
             self.inner._note_tag_started(tags)
-        qkey = "batch" if priority == "batch" else "default"
-        # one stage for both outcomes (profiler annotation; a hop span
-        # for a traced request, finished OUTSIDE the grant lock — file
-        # sinks write): ~0 on the fast path, the grant-queue wait the
-        # latency bands measure when queued
+        with self._lock:
+            if (
+                self._closed
+                or self._pending != 0  # drained-but-unresolved too
+                or not (rk is None or rk.admit(priority))
+            ):
+                return None
+            # uncontended fast path: no request is ahead of us in ANY
+            # state (queued or mid-round) and the budget has room —
+            # grant inline, no thread handoff. Checking _pending rather
+            # than the raw queues means a fresh arrival can never steal
+            # a refilled token from an older request the grant loop is
+            # currently holding.
+            self.inner.grv_count += 1
+            self.inner._m_grants.inc()
+            self._m_fast.inc()
+            fast_v = self.inner.sequencer.committed_version
+        # the grant as a stage (profiler annotation; a hop span for a
+        # traced request, finished OUTSIDE the grant lock — file sinks
+        # write): ~0 here, the grant-queue wait the latency bands
+        # measure when queued
         with span_mod.stage("grv.grant", priority=priority) as gsp:
-            fast_v = None
-            with self._lock:
-                if (
-                    not self._closed
-                    and self._pending == 0  # drained-but-unresolved too
-                    and (rk is None or rk.admit(priority))
-                ):
-                    # uncontended fast path: no request is ahead of us
-                    # in ANY state (queued or mid-round) and the budget
-                    # has room — grant inline, no thread handoff.
-                    # Checking _pending rather than the raw queues means
-                    # a fresh arrival can never steal a refilled token
-                    # from an older request the grant loop is currently
-                    # holding.
-                    self.inner.grv_count += 1
-                    self.inner._m_grants.inc()
-                    self._m_fast.inc()
-                    fast_v = self.inner.sequencer.committed_version
-            if fast_v is not None:
-                gsp.attr(version=fast_v)
-                return fast_v
+            gsp.attr(version=fast_v)
+        return fast_v
+
+    def wait_for_grant(self, priority="default"):
+        """Queue behind the grant loop and wait for its round: the half
+        of a GRV that ``grant_now`` could not give."""
+        with span_mod.stage("grv.grant", priority=priority) as gsp:
             fut = self._make_future(priority)
             with self._lock:
                 if self._closed:
                     raise err("process_behind")
-                self._queues[qkey].append(fut)
+                self._queues["batch" if priority == "batch"
+                             else "default"].append(fut)
                 self._pending += 1
                 self._wake.notify()
             fut["event"].wait()

@@ -205,7 +205,9 @@ def main(argv=None):
         cluster = build_cluster(args, coordination)
         service = ClusterService(cluster)
         service.rpc_server = server
-        server.add_handlers(service.handlers(), long_methods={"watch_wait"})
+        server.add_handlers(service.handlers(),
+                            long_methods=service.LONG_METHODS,
+                            inline_methods=service.inline_methods())
         # log-feed endpoints so --join storage-worker processes can pull
         from foundationdb_tpu.rpc.storageworker import LogFeed
 

@@ -399,3 +399,484 @@ def test_grv_coalescing_leader_failure_releases_waiters():
         assert not t.is_alive(), "waiter stranded after leader failure"
     assert len(errors) == 1  # the leader saw the failure
     assert results == [42, 42, 42]  # waiters fell back to direct calls
+
+
+# ─────────── a short request stays on its connection's thread ───────────
+DECLARED = pytest.mark.parametrize("declared", [True, False],
+                                   ids=["declared", "undeclared"])
+
+
+def _short_server(declared, extra=None, **kw):
+    """``storage_get`` echoes; declared inline or left to the pool."""
+    handlers = {"storage_get": lambda k, rv=0: k}
+    handlers.update(extra or {})
+    return RpcServer(
+        "127.0.0.1", 0, handlers,
+        inline_methods={"storage_get"} if declared else (), **kw)
+
+
+def _wait_for(pred, what, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < deadline, f"waited for {what}"
+        time.sleep(0.001)
+
+
+def _counted(server, n):
+    """The counters are added after the reply is sent: wait for the
+    n-th add, then read them."""
+    _wait_for(lambda: sum(server.stats()["requests"].values()) == n,
+              f"{n} requests counted")
+    return server.stats()
+
+
+@DECLARED
+def test_short_request_is_answered_while_held_commits_fill_the_pool(
+        declared):
+    """Sixteen commit-class handlers hold all sixteen workers: a read
+    declared inline is answered at once on its connection's thread; one
+    left to the pool waits for a worker (the parent's behaviour)."""
+    release = threading.Event()
+    running = threading.Semaphore(0)
+
+    def held():
+        running.release()
+        release.wait(30)
+        return "held"
+
+    server = _short_server(declared, {"commit": held}, max_workers=16)
+    client = RpcClient(server.host, server.port)
+    try:
+        commits = [client.call_async("commit") for _ in range(16)]
+        for _ in commits:
+            assert running.acquire(timeout=20)
+        read = client.call_async("storage_get", b"k")
+        if declared:
+            assert read.result(20) == b"k"
+            assert not any(c.done() for c in commits)
+        else:
+            _wait_for(lambda: server.stats()["pool"]["queued"] == 1,
+                      "the read to queue behind the commits")
+            assert not read.done()
+        release.set()
+        assert read.result(20) == b"k"
+        assert [c.result(20) for c in commits] == ["held"] * 16
+        doc = _counted(server, 17)
+    finally:
+        release.set()
+        client.close()
+        server.close()
+    assert doc["requests"]["read"] == 1 and doc["requests"]["commit"] == 16
+    assert doc["inline_requests"] == {
+        "read": int(declared), "grv": 0, "commit": 0, "admin": 0}
+
+
+def test_parked_undeclared_endpoint_never_delays_a_declared_one():
+    """Head of line: a connection multiplexes its client's threads, so
+    the endpoint that parks (undeclared: the pool's) must not stand in
+    front of the declared read sent after it on the same connection."""
+    parked, release = threading.Event(), threading.Event()
+
+    def park():
+        parked.set()
+        release.wait(30)
+        return "woke"
+
+    server = _short_server(True, {"park": park})
+    client = RpcClient(server.host, server.port)
+    try:
+        first = client.call_async("park")
+        assert parked.wait(20)
+        for i in range(8):
+            assert client.call("storage_get", b"k%d" % i, timeout=20) \
+                == b"k%d" % i
+        assert not first.done()
+        release.set()
+        assert first.result(20) == "woke"
+        doc = _counted(server, 9)
+    finally:
+        release.set()
+        client.close()
+        server.close()
+    assert doc["inline_requests"]["read"] == 8
+    assert doc["inline_requests"]["admin"] == 0
+
+
+@DECLARED
+def test_handler_that_parks_hands_its_request_to_the_pool(declared):
+    """A handler may return ``Park(resume)`` where it finds it has to
+    wait after all: declared inline, the request moves to the pool and
+    the connection goes on reading; on the pool, resume runs at once.
+    Either way the caller gets resume's result, counted not inline."""
+    from foundationdb_tpu.rpc.transport import Park
+
+    release = threading.Event()
+    resumed_on = []
+
+    def resume():
+        resumed_on.append(threading.current_thread().name)
+        release.wait(30)
+        return "granted"
+
+    server = RpcServer(
+        "127.0.0.1", 0,
+        {"get_read_version": lambda: Park(resume),
+         "storage_get": lambda k: k},
+        inline_methods={"get_read_version", "storage_get"}
+        if declared else ())
+    client = RpcClient(server.host, server.port)
+    try:
+        grv = client.call_async("get_read_version")
+        _wait_for(lambda: resumed_on, "the parked half to start")
+        assert client.call("storage_get", b"k", timeout=20) == b"k"
+        assert not grv.done()
+        release.set()
+        assert grv.result(20) == "granted"
+        doc = _counted(server, 2)
+    finally:
+        release.set()
+        client.close()
+        server.close()
+    assert resumed_on[0].startswith("rpc-handler")
+    assert doc["requests"]["grv"] == 1
+    assert doc["inline_requests"]["grv"] == 0
+    assert doc["inline_requests"]["read"] == int(declared)
+
+
+def test_grv_that_has_to_queue_is_answered_from_the_pool(remote_db):
+    """The served cluster's GRV runs inline only on its grant-now path.
+    With the ratekeeper denying, the request queues behind the grant
+    loop from a pool thread, and a read sent after it on the same
+    connection returns first."""
+    _, cluster, server = remote_db
+    rk = cluster.grv_proxy.inner.ratekeeper
+    assert rk is not None
+    admit, deny = rk.admit, threading.Event()
+    rk.admit = lambda *a, **kw: not deny.is_set() and admit(*a, **kw)
+    client = RpcClient(server.host, server.port)
+    try:
+        rv = client.call("get_read_version", "default", (), timeout=20)
+        _wait_for(lambda: server.stats()["requests"]["grv"] >= 1,
+                  "the granted GRV counted")
+        before = server.stats()
+        assert before["inline_requests"]["grv"] == before["requests"]["grv"]
+        deny.set()
+        queued = client.call_async("get_read_version", "default", ())
+        _wait_for(lambda: cluster.grv_proxy._pending == 1,
+                  "the GRV to queue")
+        assert client.call("storage_get", b"nokey", rv, timeout=20) is None
+        assert not queued.done()
+        deny.clear()
+        assert queued.result(20) >= rv
+        _wait_for(lambda: server.stats()["requests"]["grv"]
+                  == before["requests"]["grv"] + 1, "the GRV counted")
+        after = server.stats()
+    finally:
+        deny.clear()
+        rk.admit = admit
+        client.close()
+    assert after["inline_requests"]["grv"] == before["inline_requests"]["grv"]
+    assert after["inline_requests"]["read"] \
+        == before["inline_requests"]["read"] + 1
+    assert after["inline_requests"]["commit"] == 0
+    assert after["inline_requests"]["admin"] == 0
+
+
+def test_served_cluster_declares_reads_and_grv_not_commits(remote_db):
+    db, _, server = remote_db
+    assert server.inline_methods == {
+        "ping", "get_read_version", "storage_get", "get_range",
+        "resolve_selector", "read_batch"}
+    assert not server.inline_methods & server.long_methods
+    db[b"a"] = b"1"
+    assert db[b"a"] == b"1"
+    assert [k for k, _ in db.get_range(b"", b"\xff")] == [b"a"]
+    _wait_for(lambda: server.stats()["requests"]["commit"] >= 1
+              and server.stats()["inline_requests"]["read"] >= 2,
+              "the commit and the reads counted")
+    doc = server.stats()
+    assert doc["inline_requests"]["read"] == doc["requests"]["read"]
+    assert doc["inline_requests"]["commit"] == 0
+    assert doc["inline_requests"]["admin"] == 0
+    assert doc["requests"]["admin"] >= 1  # hello, at least
+
+
+# ───────────────────────── frames, read in bursts ──────────────────────
+def _raw_connect(server):
+    import socket
+
+    sock = socket.create_connection((server.host, server.port), 5)
+    sock.settimeout(20)
+    return sock
+
+
+def _drain_until_closed(sock):
+    """The server closed the connection: end of stream, or a reset
+    where it closed with bytes of ours unread."""
+    try:
+        while sock.recv(65536):
+            pass
+    except ConnectionResetError:
+        pass
+
+
+def _frame(msg):
+    import struct
+
+    payload = wire.dumps(msg)
+    return struct.pack(">I", len(payload)) + payload
+
+
+def _read_frames(sock, n):
+    """n whole frames' payloads off a raw socket, by the frame's own
+    rule (4-byte length, then exactly that many bytes)."""
+    import struct
+
+    def exact(k):
+        got = b""
+        while len(got) < k:
+            chunk = sock.recv(k - len(got))
+            assert chunk, "server closed early"
+            got += chunk
+        return got
+
+    return [exact(struct.unpack(">I", exact(4))[0]) for _ in range(n)]
+
+
+def _read_replies(sock, n):
+    return [wire.loads(frame) for frame in _read_frames(sock, n)]
+
+
+@DECLARED
+def test_frame_split_across_recv_boundaries(declared):
+    """A frame that arrives a few bytes at a time (header split too) is
+    one request; what follows it in the same segment is the next."""
+    server = _short_server(declared)
+    sock = _raw_connect(server)
+    try:
+        data = _frame(("q", 1, "storage_get", (b"x" * 300,))) \
+            + _frame(("q", 2, "storage_get", (b"y",)))
+        for cut in (1, 3, 4, 7, 150):
+            sock.sendall(data[:cut])
+            data = data[cut:]
+            time.sleep(0.01)
+        sock.sendall(data)
+        replies = sorted(_read_replies(sock, 2), key=lambda r: r[1])
+        doc = _counted(server, 2)
+    finally:
+        sock.close()
+        server.close()
+    assert replies == [("r", 1, True, b"x" * 300), ("r", 2, True, b"y")]
+    assert doc["recv_calls"] >= 6  # every read that returned bytes
+    assert doc["inline_requests"]["read"] == 2 * int(declared)
+
+
+@DECLARED
+def test_replies_of_one_burst_arrive_whole_and_match_their_seq(declared):
+    """Forty frames in one segment: forty whole replies, each the
+    answer to its own seq, in fewer reads than requests."""
+    server = _short_server(declared)
+    sock = _raw_connect(server)
+    n = 40
+    try:
+        sock.sendall(b"".join(
+            _frame(("q", 100 + i, "storage_get", (b"%d" % i * (i + 1),)))
+            for i in range(n)))
+        replies = _read_replies(sock, n)
+        doc = _counted(server, n)
+    finally:
+        sock.close()
+        server.close()
+    assert sorted(r[1] for r in replies) == [100 + i for i in range(n)]
+    for kind, seq, ok, payload in replies:
+        i = seq - 100
+        assert (kind, ok, payload) == ("r", True, b"%d" % i * (i + 1))
+    if declared:  # one thread, the burst's order
+        assert [r[1] for r in replies] == [100 + i for i in range(n)]
+    assert doc["requests"]["read"] == n
+    assert 1 <= doc["recv_calls"] < n
+
+
+@DECLARED
+def test_oversized_frame_closes_the_connection_at_its_header(declared):
+    import struct
+
+    from foundationdb_tpu.rpc.transport import MAX_FRAME
+
+    server = _short_server(declared)
+    sock = _raw_connect(server)
+    try:
+        # a good request, then a header of MAX_FRAME + 1 and no payload:
+        # the request in front of it is served (inline, before the
+        # header is looked at again; on the pool its reply races the
+        # close, as it always did), then the connection is closed with
+        # nothing buffered for the frame
+        sock.sendall(_frame(("q", 1, "storage_get", (b"k",)))
+                     + struct.pack(">I", MAX_FRAME + 1))
+        if declared:
+            assert _read_replies(sock, 1) == [("r", 1, True, b"k")]
+        _drain_until_closed(sock)
+        # a frame of exactly MAX_FRAME is still only a header to wait on
+        sock2 = _raw_connect(server)
+        sock2.sendall(struct.pack(">I", MAX_FRAME) + b"\x00" * 8)
+        sock2.settimeout(0.3)
+        with pytest.raises(TimeoutError):
+            sock2.recv(1)
+        sock2.close()
+    finally:
+        sock.close()
+        server.close()
+
+
+def test_pre_auth_frame_over_64_bytes_is_refused_unbuffered():
+    """Before the HMAC check a peer may send 64 bytes and no more: the
+    burst reader starts only behind the handshake."""
+    import struct
+
+    server = RpcServer("127.0.0.1", 0, {"storage_get": lambda k: k},
+                       inline_methods={"storage_get"}, secret="hunter2")
+    try:
+        sock = _raw_connect(server)
+        assert len(_read_frames(sock, 1)[0]) == 16  # the nonce
+        sock.sendall(struct.pack(">I", 65) + b"p" * 65)
+        _drain_until_closed(sock)
+        sock.close()
+        # a wrong proof of a legal size is refused too, and a request
+        # sent in the same segment as the proof is never dispatched
+        sock = _raw_connect(server)
+        _read_frames(sock, 1)
+        sock.sendall(struct.pack(">I", 32) + b"p" * 32
+                     + _frame(("q", 1, "storage_get", (b"k",))))
+        _drain_until_closed(sock)
+        sock.close()
+        assert sum(server.stats()["requests"].values()) == 0
+        good = RpcClient(server.host, server.port, secret="hunter2")
+        assert good.call("storage_get", b"k", timeout=20) == b"k"
+        good.close()
+    finally:
+        server.close()
+
+
+class _ChunkSocket:
+    """recv() hands out scripted chunks; a ``None`` is a timeout."""
+
+    def __init__(self, chunks):
+        self.chunks = list(chunks)
+
+    def recv(self, n):
+        if not self.chunks:
+            return b""
+        chunk = self.chunks.pop(0)
+        if chunk is None:
+            raise TimeoutError("tick")
+        assert len(chunk) <= n
+        return chunk
+
+
+@pytest.mark.parametrize("cuts", [
+    (),                       # everything in one read
+    (2,), (4,), (5,),         # inside the header, at it, one byte past
+    (1, 2, 3, 4, 5, 6),       # a byte at a time
+    (30, 31, 60),             # inside the second and third frames
+], ids=lambda c: "cuts=" + "-".join(map(str, c)) if c else "one-read")
+def test_frame_reader_parses_bursts_across_any_boundary(cuts):
+    """Whatever the segment boundaries, and with a timeout between any
+    two segments, the reader returns the same frames in order, each
+    burst holding every frame complete so far."""
+    from foundationdb_tpu.rpc.transport import ConnectionLost, _FrameReader
+
+    payloads = [b"a" * 20, b"", b"c" * 33, b"d"]
+    data = b"".join(len(p).to_bytes(4, "big") + p for p in payloads)
+    chunks, last = [], 0
+    for cut in cuts:
+        chunks += [data[last:cut], None]
+        last = cut
+    chunks.append(data[last:])
+    reader = _FrameReader(_ChunkSocket(chunks))
+    got, bursts = [], 0
+    while len(got) < len(payloads):
+        try:
+            frames = reader.recv_burst()
+        except TimeoutError:
+            continue
+        assert frames
+        got += frames
+        bursts += 1
+    assert got == payloads
+    assert reader.recvs == len(cuts) + 1
+    assert bursts <= reader.recvs
+    if not cuts:
+        assert bursts == 1
+    with pytest.raises(ConnectionLost):
+        reader.recv_burst()  # peer closed
+
+
+@DECLARED
+def test_undecodable_frame_fails_the_connection_behind_the_good_ones(
+        declared):
+    """A burst of two requests and a frame that is no message: the
+    requests in front of it are served (a declared one surely: it is
+    answered before the connection is failed), then the server closes."""
+    import struct
+
+    server = _short_server(declared)
+    sock = _raw_connect(server)
+    try:
+        sock.sendall(_frame(("q", 1, "storage_get", (b"a",)))
+                     + _frame(("q", 2, "storage_get", (b"b",)))
+                     + struct.pack(">I", 3) + b"\xfe\xfe\xfe")
+        if declared:
+            assert sorted(_read_replies(sock, 2)) == [
+                ("r", 1, True, b"a"), ("r", 2, True, b"b")]
+        _drain_until_closed(sock)
+        _wait_for(lambda: sum(server.stats()["requests"].values()) == 2,
+                  "both requests in front of it counted")
+    finally:
+        sock.close()
+        server.close()
+
+
+def test_inline_and_pooled_requests_interleaved_under_thread_switching():
+    """Four connections × eight threads, more than the cores, a switch
+    interval of 10 µs: reads answered on the connections' threads and
+    commits on the pool share sockets, send locks and the counters.
+    Every caller gets its own answer and every request is counted once,
+    where it was answered."""
+    import sys
+
+    server = _short_server(True, {"commit": lambda x: ("done", x)})
+    clients = [RpcClient(server.host, server.port) for _ in range(4)]
+    wrong, per_thread = [], 60
+    interval = sys.getswitchinterval()
+
+    def work(client, tid):
+        for i in range(per_thread):
+            key = b"%d:%d" % (tid, i)
+            if client.call("storage_get", key, timeout=30) != key:
+                wrong.append(("read", tid, i))
+            if i % 3 == 0 and client.call("commit", key, timeout=30) \
+                    != ("done", key):
+                wrong.append(("commit", tid, i))
+
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(c, 8 * n + t))
+                   for n, c in enumerate(clients) for t in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        reads, commits = 32 * per_thread, 32 * (per_thread // 3)
+        doc = _counted(server, reads + commits)
+    finally:
+        sys.setswitchinterval(interval)
+        for c in clients:
+            c.close()
+        server.close()
+    assert wrong == []
+    assert doc["requests"]["read"] == reads
+    assert doc["requests"]["commit"] == commits
+    assert doc["inline_requests"] == {"read": reads, "grv": 0, "commit": 0,
+                                      "admin": 0}
+    assert 1 <= doc["recv_calls"] <= reads + commits

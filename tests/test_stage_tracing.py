@@ -264,7 +264,10 @@ def _wait_until(pred, what, timeout=20.0):
 
 def test_rpc_server_counts_queue_wait_and_handler_by_class():
     """One worker, held by an Event: the request behind it waits in
-    the pool's queue for at least as long as the hold."""
+    the pool's queue for at least as long as the hold. Reads and GRVs
+    are declared inline, as the served cluster declares them, so the
+    request that queues is an admin call, and the read sent while the
+    worker is held is answered on its connection's thread."""
     started, release = threading.Event(), threading.Event()
 
     def held():
@@ -277,24 +280,26 @@ def test_rpc_server_counts_queue_wait_and_handler_by_class():
         "storage_get": lambda k: k,        # read class
         "get_read_version": lambda: 7,     # grv class
         "hello": lambda: "hi",             # unlisted -> admin
-    }, max_workers=1)
+    }, max_workers=1, inline_methods={"storage_get", "get_read_version"})
     client = RpcClient(server.host, server.port)
     try:
         first = client.call_async("commit")
         assert started.wait(20)
-        behind = client.call_async("storage_get", b"k")
+        behind = client.call_async("hello")
         _wait_until(lambda: server.stats()["pool"]["queued"] == 1,
                     "the second request to be decoded and queued")
         t_queued = span_mod.now()
+        # past the held worker and the queued call, with no worker
+        assert client.call("storage_get", b"k", timeout=20) == b"k"
+        assert not first.done() and not behind.done()
         while span_mod.now() - t_queued < 0.02:
             pass  # the hold: measured, not slept on
         hold_us = (span_mod.now() - t_queued) * 1e6
         release.set()
         assert first.result(20) == "held"
-        assert behind.result(20) == b"k"
+        assert behind.result(20) == "hi"
         assert client.call("get_read_version", timeout=20) == 7
         assert client.call("get_read_version", timeout=20) == 7
-        assert client.call("hello", timeout=20) == "hi"
         with pytest.raises(Exception):
             client.call("no_such_endpoint", timeout=20)
         # the reply is sent inside the handler's stage, the counters
@@ -309,7 +314,14 @@ def test_rpc_server_counts_queue_wait_and_handler_by_class():
     assert set(RPC_COUNTERS) <= set(doc)
     assert doc["requests"] == {"read": 1, "grv": 2, "commit": 1,
                                "admin": 2}
-    assert doc["queue_wait_us"]["read"] >= hold_us
+    # answered where they were decoded: the declared endpoints' requests
+    assert doc["inline_requests"] == {"read": 1, "grv": 2, "commit": 0,
+                                      "admin": 0}
+    # six requests, sent one at a time but for the two in flight
+    # together: a socket read brought one whole frame, seldom two
+    assert 4 <= doc["recv_calls"] <= 6
+    assert doc["queue_wait_us"]["admin"] >= hold_us
+    assert doc["queue_wait_us"]["read"] < hold_us  # it never queued
     # the held handler waited on an Event: wall far above its CPU
     assert doc["handler_wall_us"]["commit"] >= hold_us
     # stamps for the first request of a class and every fourth after it
