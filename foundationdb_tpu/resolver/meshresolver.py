@@ -39,7 +39,11 @@ import jax
 import numpy as np
 
 from foundationdb_tpu.core.options import DEFAULT_KNOBS
-from foundationdb_tpu.resolver.packing import BatchPacker, ShardRouter
+from foundationdb_tpu.resolver.packing import (
+    BatchPacker,
+    LaneBounds,
+    ShardRouter,
+)
 from foundationdb_tpu.resolver.resolver import (
     BACKLOG_B,
     Resolver,
@@ -62,7 +66,7 @@ class MeshResolver(Resolver):
     """
 
     def __init__(self, knobs=DEFAULT_KNOBS, base_version=0, n_lanes=None,
-                 mesh=None):
+                 mesh=None, heir_of=None):
         from foundationdb_tpu.parallel.mesh import (
             PreshardedResolverKernel,
             ShardedResolverKernel,
@@ -105,6 +109,11 @@ class MeshResolver(Resolver):
             ring_partition_bits=0
         )
         self._init_buckets()
+        if heir_of is not None:
+            # a replacement is fenced anyway: the sample its predecessor
+            # gathered, and the buckets cut from it, cost it nothing
+            self.buckets = heir_of.buckets
+            self._ranges_seen = heir_of._ranges_seen
         self.packer = BatchPacker(self.params, buckets=self.buckets)
         # "range" (the default) is the single-dispatch compacted path:
         # the host routes each entry to the lane(s) owning its keys
@@ -117,10 +126,22 @@ class MeshResolver(Resolver):
         self._fast_params = None
         self._fast_kernel = None
         self._range_history = False
+        self._rebound_fence = None  # the base_version a re-bound set
         if self.sharding == "range":
             self._kernel = PreshardedResolverKernel(self.params,
                                                     mesh=self.mesh)
-            self._router = ShardRouter(self.params, self.n_lanes)
+            # the lane bounds start as the first limb's uniform split
+            # and are cut from the keys this resolver packs (LaneBounds,
+            # _maybe_rebound); a replacement takes its predecessor's, or
+            # where the lanes' number changed cuts its own at once
+            self._lanes = LaneBounds(self.n_lanes)
+            bounds = None
+            if getattr(heir_of, "_router", None) is not None:
+                bounds = (heir_of._router.bounds
+                          if heir_of.n_lanes == self.n_lanes
+                          else self._lanes.fresh(self.buckets.sample()))
+            self._router = ShardRouter(self.params, self.n_lanes,
+                                       bounds=bounds)
             self._resolve = self._route_step
             # no point-specialized twin: the compacted layout already
             # skips dead sides per-entry, and a second compiled variant
@@ -128,7 +149,7 @@ class MeshResolver(Resolver):
         else:
             self._kernel = ShardedResolverKernel(self.params,
                                                  mesh=self.mesh)
-            self._router = None
+            self._router = self._lanes = None
             self._resolve = self._kernel._step
             # point-specialized fast variant (see Resolver.__init__):
             # same state, range lanes statically off. make_state=False —
@@ -162,11 +183,68 @@ class MeshResolver(Resolver):
             sb, k, lane_counts = self._router.split(stacked)
         if deviceprofile.enabled():
             self.profile.record_lane_counts(lane_counts.tolist())
+            # a range has a slot in every lane its span touches: what
+            # the lanes were given beyond the entries that came in
+            ranges = (np.count_nonzero(stacked.rr_mask)
+                      + np.count_nonzero(stacked.rw_mask))
+            points = (np.count_nonzero(stacked.pr_mask)
+                      + np.count_nonzero(stacked.pw_mask))
+            routed = lane_counts.sum()
             self.profile.count(
                 route_dispatches=1, route_slices=k,
-                lane_entries_routed=lane_counts.sum(),
-                lane_entries_fullest=lane_counts.max())
+                lane_entries_routed=routed,
+                lane_entries_fullest=lane_counts.max(),
+                range_entries_routed=ranges,
+                range_lane_dups=routed - points - ranges)
         return sb, k
+
+    def _maybe_rebound(self, commit_version):
+        """Cut the lane bounds again where the sample says so
+        (resolver/packing.py ``LaneBounds``). A moved bound moves exact
+        history, a lane's hash table and ring, which no fold makes safe,
+        so a re-bound is a fence, as a respawn is: a new router, fresh
+        lane state and empty summaries, ``base_version`` the batch in
+        hand's commit version. That batch and every read version from
+        before it are answered TOO_OLD by the host's rule and retry with
+        a fresh read version; a read at or above the fence needs only
+        writes from after it, and all of those were routed under the new
+        bounds. (Upstream sends a read to every resolver that owned its
+        range inside the MVCC window instead: ROADMAP B-I.10.) Between
+        steps, on the dispatching thread."""
+        if self._lanes is None or not self._lanes.due(self.buckets):
+            return
+        with span_mod.stage("resolver.rebound", self.profile):
+            found = self._lanes.look(self.buckets, self._router)
+            if found is None:
+                return
+            self._router, before, after = found
+            # (the old lanes' history is let go before its successor
+            # is made: the devices never hold both)
+            self.state = None
+            self.state = self._kernel.init_state()
+            self.base_version = self._rebound_fence = commit_version
+        self.profile.count(rebounds=1)
+        from foundationdb_tpu.utils.trace import TraceEvent
+
+        TraceEvent("ResolverLanesRebound").detail(
+            fenced_at=commit_version, lane_bounds=self.lane_bounds(),
+            shares_before=[round(float(x), 3) for x in before],
+            shares_after=[round(float(x), 3) for x in after]).log()
+
+    def _note_too_old(self, n):
+        if n and self.base_version == self._rebound_fence:
+            self.profile.count(rebound_fenced_txns=n)
+
+    def lane_bounds(self):
+        """The n − 1 lane bounds as printable keys (a bound cut from
+        the sample is a key row; the first limb's split is not, and has
+        no length); none where the lanes own by hash."""
+        out = []
+        for row in () if self._router is None else self._router.bounds:
+            raw = row[:-1].astype(">u4").tobytes()
+            key = raw[:int(row[-1])] if row[-1] else raw.rstrip(b"\x00")
+            out.append(repr(key)[2:-1])
+        return out
 
     def _route_step(self, state, batch):
         """Single-batch presharded step behind the ``self._resolve``
@@ -187,13 +265,12 @@ class MeshResolver(Resolver):
     def _make_scan_fn(self, use_fast):
         if self.sharding == "range":
             kern = self._kernel
-            router = self._router
 
             def routed_scan(state, stacked):
                 sb, k = self._split_counted(stacked)
                 state, st = kern._scan_step(state, sb)
                 if k > 1:
-                    st = router.reassemble(st, k)
+                    st = self._router.reassemble(st, k)
                 return state, st
 
             return routed_scan
@@ -234,13 +311,15 @@ class MeshResolver(Resolver):
     def status(self):
         doc = super().status()
         doc["sharding"] = self.sharding
+        doc["lane_bounds"] = self.lane_bounds()
         return doc
 
     def respawn(self, base_version):
         """Recruitment: a fresh fleet on the same mesh, fenced (the
-        sharded history died with this instance)."""
+        sharded history died with this instance); the lane bounds, the
+        coarse buckets and the sample they were cut from live on."""
         new = MeshResolver(self.knobs, base_version=base_version,
-                           mesh=self.mesh)
+                           mesh=self.mesh, heir_of=self)
         new._init_metrics(self.metrics)
         new.adopt_profile(self.profile)
         new._m_respawns.inc()

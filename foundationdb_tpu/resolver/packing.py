@@ -102,6 +102,7 @@ class CoarseBuckets:
         # within half of itself, and 2 · capacity rows of W limbs are a
         # few megabytes
         self.capacity = int(capacity or 4 * self.C)
+        self._width = params.key_width
         self._bounds = None  # sortable[C − 1], or None: the first limb's bits
         self._sample = None  # uint32[2 · capacity, W], made at the first row
         self._held = 0  # rows of it in use
@@ -140,6 +141,14 @@ class CoarseBuckets:
         buf[self._held:self._held + len(rows)] = rows
         self._held += len(rows)
 
+    def sample(self):
+        """The sampled rows uint32[held, W], oldest first (a view of the
+        buffer: the older a row, the more thinned out its neighbours).
+        What the lane bounds are cut from too (:class:`LaneBounds`)."""
+        if self._sample is None:
+            return np.empty((0, self._width), np.uint32)
+        return self._sample[:self._held]
+
     def recut_due(self):
         """Whether :meth:`recut` has something to do (cheap)."""
         if self._cut_seen < self.capacity:
@@ -150,7 +159,7 @@ class CoarseBuckets:
         """Cut the boundaries again from the sample if the class text's
         rule says so → whether they changed (the caller then folds the
         device's summaries before its next step)."""
-        rows = self._sample[:self._held]
+        rows = self.sample()
         self._checked = self.seen
         if self._cut_seen >= self.capacity and not self._stale(rows):
             return False
@@ -416,6 +425,94 @@ class ShardRouter:
             cv=cv_out, new_window_start=nws_out,
         )
         return sb, lane_counts
+
+
+class LaneBounds:
+    """When a mesh's lane bounds are cut again from the keys its
+    resolver packed (upstream: masterserver.actor.cpp
+    ``resolutionBalancing``, fed by the resolvers' ``iopsSample``).
+
+    The rows are :meth:`CoarseBuckets.sample`'s: point writes and range
+    begins in arrival order, duplicates kept. A look takes the newest
+    ``CHECK`` rows as what the lanes are asked to carry now and cuts
+    fresh bounds at the n-quantiles of the rows before them, so the rows
+    a cut is judged on are never rows it was made from. The bounds in
+    force are stale when the fresh ones would take a lane's fair share,
+    1/n of those newest rows, off the fullest lane. The test is a
+    difference and not a ratio because a bound is a whole key row and no
+    bound splits a key: under YCSB's Zipfian the hottest key is a third
+    to a half of the commit attempts, its lane holds 55-60% of them
+    under the best bounds there are, and "twice the share a fresh cut
+    would give" is then something one lane holding everything never
+    reaches. The first cut, from the first limb's uniform split, is
+    held to the same test: a table loaded in key order sends every new
+    row past every row before it, one lane has them all under any bounds
+    cut from the past, and nothing is cut until rows arrive that a cut
+    would spread. A moved bound moves exact history, so every cut costs
+    the resolver a fence (``MeshResolver._maybe_rebound``): the rule is
+    there to cut seldom.
+
+    The constants, swept on the chip's host (PERF.md §6, PR 37):
+    """
+
+    # rows the sample holds before the first look: a quartile of 4,096
+    # rows is known to 0.7% of the rows (sqrt(3/16 / 4096))
+    FIRST = 4096
+    # rows noted between two looks, and the newest rows a look judges
+    # by: a lane's share of 512 rows is known to 2%, against a test of
+    # 25%; at 600 rows a second (YCSB-A behind four lanes) the cut that
+    # follows a load falls a second into the traffic
+    CHECK = 512
+    # rows of the sample a look sorts, evenly strided: three quantiles
+    # of 4,096 rows are as good as of 131,072, and the sort then takes
+    # 1.4 ms where the whole sample's takes 53
+    FIT = 4096
+
+    def __init__(self, n):
+        self.n = int(n)
+        self._looked = 0  # CoarseBuckets.seen at the last look
+
+    def due(self, buckets):
+        """Whether :meth:`look` has something to do (cheap)."""
+        return (self.n > 1 and buckets.seen >= self.FIRST
+                and buckets.seen - self._looked >= self.CHECK)
+
+    def fresh(self, rows):
+        """Bounds uint32[n − 1, W] at the n-quantiles of limb rows
+        ``rows`` (a stride of them), or None under ``FIRST`` rows."""
+        if len(rows) < self.FIRST:
+            return None
+        fit = rows[::max(1, len(rows) // self.FIT)]
+        order = np.argsort(_rows_sortable(fit), kind="stable")
+        return fit[order[(np.arange(1, self.n) * len(fit)) // self.n]]
+
+    def look(self, buckets, router):
+        """→ (a router with new bounds, the newest rows' share a lane
+        under ``router``, … under the new one) where ``router``'s bounds
+        are stale, else None."""
+        self._looked = buckets.seen
+        rows = buckets.sample()
+        newest = rows[-self.CHECK:]
+
+        def shares(r):
+            return np.bincount(r.lane_of_points(newest),
+                               minlength=self.n) / len(newest)
+
+        before = shares(router)
+        if before.max() < 2.0 / self.n:
+            # no cut takes 1/n off a lane that holds under 2/n: the
+            # look of a balanced mesh ends here, before the sort (under
+            # load a sort costs the dispatching thread a turn at the
+            # interpreter lock: 5 ms a look, PERF.md §6, PR 37)
+            return None
+        bounds = self.fresh(rows[:-self.CHECK])
+        if bounds is None:
+            return None
+        cut = ShardRouter(router.params, self.n, bounds=bounds)
+        after = shares(cut)
+        if before.max() - after.max() < 1.0 / self.n:
+            return None
+        return cut, before, after
 
 
 class BatchPacker:

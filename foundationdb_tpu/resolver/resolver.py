@@ -344,17 +344,8 @@ class Resolver:
                     txn_slots=len(txns), wall_s=dsp.seconds)
             return out
         self._maybe_rebase(commit_version)
-        # base_version only ever advances to a past window start, so a read
-        # version below it is too old by construction — reject on host
-        # rather than letting the uint32 offset clamp to 0. Dropping these
-        # txns from the batch is safe: they commit nothing.
-        statuses = [None] * len(txns)
-        live = []
-        for i, t in enumerate(txns):
-            if t.read_version < self.base_version:
-                statuses[i] = TOO_OLD
-            else:
-                live.append((i, t))
+        self._maybe_rebound(commit_version)
+        statuses, live = self._split_too_old(txns)
         use_fast = self._pick_fast(t for _, t in live)
         self._maybe_rebucket()
         packer, resolve_fn = self._fast if use_fast else (
@@ -397,6 +388,30 @@ class Resolver:
             for (i, _), s in zip(chunk, out):
                 statuses[i] = s
         return statuses
+
+    def _split_too_old(self, txns):
+        """→ (statuses with TOO_OLD where the host can say so, [(index,
+        txn)] of the rest). base_version only ever advances to a past
+        window start or to a fence, so a read version below it is too
+        old by construction: reject on host rather than letting the
+        uint32 offset clamp to 0. Dropping these txns from the batch is
+        safe: they commit nothing."""
+        statuses = [None] * len(txns)
+        live = []
+        for i, t in enumerate(txns):
+            if t.read_version < self.base_version:
+                statuses[i] = TOO_OLD
+            else:
+                live.append((i, t))
+        self._note_too_old(len(txns) - len(live))
+        return statuses, live
+
+    def _maybe_rebound(self, commit_version):
+        """Lane bounds are the mesh's (MeshResolver): one lane has none."""
+
+    def _note_too_old(self, n):
+        """``n`` transactions refused by the host's rule: the mesh counts
+        those a re-bound's fence cost."""
 
     def _step_kernel(self, resolve_fn, batch, n, commit_version):
         """One threaded kernel step → (statuses[:n], its wall seconds:
@@ -479,6 +494,7 @@ class Resolver:
                     txn_slots=len(flat), wall_s=dsp.seconds)
             return out
         self._maybe_rebase(commit_version)
+        self._maybe_rebound(commit_version)
         cause = self._flat_fallback_cause(flat)
         if cause is not None:
             self._m_flat_fallbacks.inc()
@@ -651,6 +667,9 @@ class Resolver:
         if not self.alive:
             raise ResolverDown()
         self._maybe_rebase(batches[-1][1])
+        # (a fence at the backlog's first commit version: every batch of
+        # it still packs at or above base_version)
+        self._maybe_rebound(batches[0][1])
         # the scanned paths below bypass resolve(): count their volume
         # here (the eager/host route above counts via resolve itself)
         self._m_batches.inc(len(batches))
@@ -685,13 +704,7 @@ class Resolver:
         per_batch = []
         all_live = []
         for txns, cv, ws in batches:
-            statuses = [None] * len(txns)
-            live = []
-            for i, t in enumerate(txns):
-                if t.read_version < self.base_version:
-                    statuses[i] = TOO_OLD
-                else:
-                    live.append((i, t))
+            statuses, live = self._split_too_old(txns)
             per_batch.append((statuses, live, cv, ws))
             all_live.extend(t for _, t in live)
         use_fast = self._pick_fast(all_live)

@@ -629,8 +629,14 @@ class Cluster:
                     MeshResolver,
                 )
 
-                new = [MeshResolver(self.knobs, base_version=recovered,
-                                    n_lanes=new_resolver_lanes)]
+                # the old fleet's sample, buckets and (at the same
+                # lane count) bounds go to the new one: it is fenced
+                # anyway, and starts where the old one had got to
+                old = self.resolvers[0]
+                new = [MeshResolver(
+                    self.knobs, base_version=recovered,
+                    n_lanes=new_resolver_lanes,
+                    heir_of=old if isinstance(old, MeshResolver) else None)]
             else:
                 new = [Resolver(self.knobs, base_version=recovered)
                        for _ in range(new_resolver_lanes)]
@@ -1673,6 +1679,9 @@ class Cluster:
                          # "hash" = replicated-batch mesh, "local" =
                          # single-lane / host resolvers
                          "sharding": getattr(r, "sharding", "local"),
+                         # a range-sharded mesh's n − 1 lane bounds
+                         "lane_bounds": r.lane_bounds()
+                         if hasattr(r, "lane_bounds") else [],
                          "metrics": r.metrics.snapshot()}
                         for i, r in enumerate(self.resolvers)
                     ],

@@ -33,9 +33,9 @@ commit, the traced pair in PERF.md), not a CPU gate's.
 The resolver's host stages reach the profile through
 ``utils/span.stage(name, stats=profile)``: :meth:`DeviceProfile.add`
 maps ``resolver.pack`` / ``resolver.enqueue`` / ``resolver.readback``
-/ ``resolver.route`` / ``resolver.rebucket`` to ``pack_wall_ms`` /
-``enqueue_wall_ms`` / ``verdict_reduce_wall_ms`` / ``route_wall_ms`` /
-``rebucket_wall_ms``.
+/ ``resolver.route`` / ``resolver.rebucket`` / ``resolver.rebound`` to
+``pack_wall_ms`` / ``enqueue_wall_ms`` / ``verdict_reduce_wall_ms`` /
+``route_wall_ms`` / ``rebucket_wall_ms`` / ``rebound_wall_ms``.
 """
 
 import os
@@ -66,6 +66,7 @@ STAGE_WALLS = {
     "resolver.readback": "verdict_reduce_wall_s",
     "resolver.route": "route_wall_s",
     "resolver.rebucket": "rebucket_wall_s",
+    "resolver.rebound": "rebound_wall_s",
 }
 
 # the plain counters (:meth:`DeviceProfile.count`), each summed over
@@ -83,13 +84,21 @@ STAGE_WALLS = {
 # status code: an upper bound on the false conflicts the summaries
 # cost); ``bucket_entries_routed`` / ``bucket_entries_fullest``, a
 # pack's live point writes and those of them in its fullest bucket,
-# counted once boundaries are cut
+# counted once boundaries are cut. The lane bounds' (resolver/packing.py
+# ``LaneBounds``): ``rebounds``, the times ``MeshResolver._maybe_rebound``
+# cut new lane bounds and fenced; ``rebound_fenced_txns``, transactions
+# the host then answered TOO_OLD because they read before that fence;
+# ``range_entries_routed`` / ``range_lane_dups``, the range entries a
+# dispatch brought the router and the lane slots it gave them beyond
+# one each (a range has a slot in every lane its span touches)
 PLAIN_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
                   "lane_entries_fullest", "h2d_args", "rebuckets",
                   "conflicts_coarse_only", "bucket_entries_routed",
-                  "bucket_entries_fullest")
+                  "bucket_entries_fullest", "rebounds",
+                  "rebound_fenced_txns", "range_entries_routed",
+                  "range_lane_dups")
 # the stage walls that ride beside them through absorb and snapshot
-PLAIN_WALLS = ("route_wall_s", "rebucket_wall_s")
+PLAIN_WALLS = ("route_wall_s", "rebucket_wall_s", "rebound_wall_s")
 
 
 def set_enabled(on):
@@ -161,6 +170,8 @@ class DeviceProfile:
         self.route_wall_s = 0.0  # stage resolver.route: the router's split
         # stage resolver.rebucket: sample → boundaries → fold
         self.rebucket_wall_s = 0.0
+        # stage resolver.rebound: sample → lane bounds → fresh lane state
+        self.rebound_wall_s = 0.0
         for c in PLAIN_COUNTERS:
             setattr(self, c, 0)
         # fallback-cause taxonomy
@@ -422,6 +433,7 @@ class DeviceProfile:
                 "lane_skew_pct": lane_skew,
                 "route_wall_ms": round(self.route_wall_s * 1e3, 3),
                 "rebucket_wall_ms": round(self.rebucket_wall_s * 1e3, 3),
+                "rebound_wall_ms": round(self.rebound_wall_s * 1e3, 3),
                 **{c: getattr(self, c) for c in PLAIN_COUNTERS},
                 "fallback_causes": dict(sorted(
                     self.fallback_causes.items())),

@@ -71,12 +71,19 @@ def test_the_four_lane_cell_rehearses_correct_with_the_routers_metrics(
     metrics = line["metrics"]
     assert metrics["mesh.route_ms"]["value"] > 0
     assert metrics["mesh.slices_per_dispatch"]["value"] >= 1.0
-    # every user... key has one lane of the uniform first-limb split.
-    # The entries beside them are the server's own keys, which come by
-    # the clock: on a busy CPU the traced seconds hold ten routed
-    # entries and one or two of those (80.0-90.0 under fourteen spinning
-    # processes, which is what failed the driver's run of PR 30)
+    # this table's 2,000 rows stay under the lane rule's first look
+    # (4,096 sampled rows: resolver/packing.py LaneBounds), so three
+    # seconds on four CPU devices show the bounds a mesh starts with:
+    # every user... key has one lane of the uniform first-limb split
+    # (tests/test_bench_rehearsal_range4.py rehearses a table that is
+    # cut). The entries beside them are the server's own keys, which
+    # come by the clock: on a busy CPU the traced seconds hold ten
+    # routed entries and one or two of those (80.0-90.0 under fourteen
+    # spinning processes, which is what failed the driver's run of PR 30)
     assert 50.0 < metrics["mesh.fullest_lane_pct"]["value"] <= 100.0
+    # no bound moved, so no fence refused anyone; and YCSB sends no range
+    assert metrics["mesh.rebound_fenced_pct"]["value"] == 0
+    assert "mesh.range_dup_pct" not in metrics
     # a CPU has no device plane: the trace's metrics stay out of the line
     assert "mesh_step.device_ms" not in metrics
 

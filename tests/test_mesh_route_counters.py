@@ -3,8 +3,9 @@
 and utils/deviceprofile.py ``PLAIN_COUNTERS``), on the 8 host devices
 conftest forces.
 
-``ShardRouter`` splits the first limb uniformly, so every ``user%08d``
-key has the second of four lanes: the counters say so (the fullest lane
+``ShardRouter`` starts from the first limb's uniform split, and a
+sample under 4,096 rows cuts nothing (tests/test_lane_bounds.py), so
+every ``user%08d`` key has the second of four lanes: the counters say so (the fullest lane
 holds every entry), a batch beyond that lane's capacity is cut into
 slices, and whatever the four lanes commit the plain reference
 (resolver/skiplist.py's exact interval list) commits.
@@ -237,3 +238,98 @@ def test_the_kill_switch_stops_the_route_counters():
     assert snap["route_dispatches"] == 0 and snap["route_wall_ms"] == 0
     assert mesh.resolve(updates([1], 1000), 1020, 0) == [CONFLICT]
     assert mesh.profile.snapshot()["route_dispatches"] == 1
+
+
+# ── the lane bounds' counters (PR 37) ───────────────────────────────
+def rebound_at(mesh, ids):
+    """Make the next dispatch re-bound at ``ids``' keys: the rule's
+    answer stubbed (tests/test_lane_bounds.py holds the rule), the
+    fence, the stage and the counters the program's."""
+    from foundationdb_tpu.resolver.packing import ShardRouter
+
+    rows = mesh.packer.codec.encode_lower_batch([key(i) for i in ids])
+    router = ShardRouter(mesh.params, mesh.n_lanes, bounds=rows)
+    share = np.full(mesh.n_lanes, 1.0 / mesh.n_lanes)
+    mesh._lanes.due = lambda buckets: True
+
+    def look(buckets, old):
+        del mesh._lanes.due, mesh._lanes.look
+        return router, share, share
+
+    mesh._lanes.look = look
+
+
+def scan(a, n, rv):
+    """A scan of ``n`` rows that clears its first."""
+    return TxnRequest(rv, range_reads=[(key(a), key(a + n))],
+                      range_writes=[(key(a), key(a + 1))])
+
+
+def test_rebound_counters_reach_the_aggregate_and_outlive_a_respawn():
+    mesh = MeshResolver(KNOBS, n_lanes=4)
+    assert mesh.resolve(updates([10, 60_000], 1000), 1010, 0) == [
+        COMMITTED, COMMITTED]
+    snap = mesh.profile.snapshot()
+    assert snap["rebounds"] == snap["rebound_fenced_txns"] == 0
+    assert snap["range_entries_routed"] == snap["range_lane_dups"] == 0
+    assert snap["rebound_wall_ms"] == 0
+    assert mesh.status()["lane_bounds"] == ["@", "\\x80", "\\xc0"]
+    # the bounds move: the batch in hand and an older read are refused,
+    # each counted once; a read from after the fence is not
+    rebound_at(mesh, [25_000, 50_000, 75_000])
+    assert mesh.resolve(updates([1, 2, 3], 1010), 1020, 0) == [TOO_OLD] * 3
+    assert mesh.base_version == 1020
+    assert mesh.resolve(updates([4], 1015) + updates([5], 1020),
+                        1030, 0) == [TOO_OLD, COMMITTED]
+    assert mesh.status()["lane_bounds"] == [
+        "user00025000", "user00050000", "user00075000"]
+    # a scan across a bound has a slot in both lanes; its clear and a
+    # scan inside a lane have one: four range entries, one slot more
+    got = mesh.resolve([scan(24_990, 20, 1030), scan(60_000, 20, 1030)],
+                       1040, 0)
+    assert got == [COMMITTED, COMMITTED]
+    snap = mesh.profile.snapshot()
+    assert snap["rebounds"] == 1 and snap["rebound_fenced_txns"] == 4
+    assert snap["range_entries_routed"] == 4
+    assert snap["range_lane_dups"] == 1  # the read crosses, the clear not
+    assert snap["rebound_wall_ms"] > 0
+    assert snap["lane_entries"][0] > 0 and snap["lane_entries"][2] > 0
+    # a replacement keeps counting where this one stopped, with its
+    # bounds; its own fence is no re-bound's
+    new = mesh.respawn(1040)
+    assert new.status()["lane_bounds"] == mesh.status()["lane_bounds"]
+    assert new.resolve(updates([6], 1030), 1050, 0) == [TOO_OLD]
+    other = MeshResolver(KNOBS, n_lanes=2)
+    other.resolve([scan(100, 5, 1000)], 1010, 0)
+    agg = deviceprofile.merged_snapshot([new.profile, other.profile])
+    assert agg["rebounds"] == 1 and agg["rebound_fenced_txns"] == 4
+    assert agg["range_entries_routed"] == 6 and agg["range_lane_dups"] == 1
+    assert agg["rebound_wall_ms"] == pytest.approx(
+        snap["rebound_wall_ms"], abs=0.002)
+    # one lane has no bounds: its counters stay at rest
+    one = Resolver(KNOBS)
+    one.resolve([scan(100, 5, 1000)], 1010, 0)
+    snap = one.profile.snapshot()
+    assert snap["range_entries_routed"] == snap["rebounds"] == 0
+    assert "lane_bounds" not in one.status()
+    import dataclasses
+
+    by_hash = dataclasses.replace(KNOBS, resolver_sharding="hash")
+    assert MeshResolver(by_hash, n_lanes=2).status()["lane_bounds"] == []
+
+
+def test_the_kill_switch_stops_the_rebound_counters_not_the_fence():
+    mesh = MeshResolver(KNOBS, n_lanes=4)
+    rebound_at(mesh, [25_000, 50_000, 75_000])
+    deviceprofile.set_enabled(False)
+    try:
+        assert mesh.resolve(updates([1], 1000), 1010, 0) == [TOO_OLD]
+        assert mesh.resolve([scan(24_990, 20, 1010)], 1020, 0) == [COMMITTED]
+    finally:
+        deviceprofile.set_enabled(True)
+    assert mesh.base_version == 1010
+    snap = mesh.profile.snapshot()
+    assert snap["rebounds"] == snap["rebound_fenced_txns"] == 0
+    assert snap["range_entries_routed"] == snap["rebound_wall_ms"] == 0
+    assert mesh.resolve(updates([2], 1000), 1030, 0) == [TOO_OLD]
+    assert mesh.profile.snapshot()["rebound_fenced_txns"] == 1
