@@ -38,6 +38,7 @@ from foundationdb_tpu.rpc.transport import (
     WEDGED_STRIKE_LIMIT,
     ConnectionLost,
     DeadlineExceeded,
+    Deferred,
     Park,
     RpcServer,
     connect_any,
@@ -195,12 +196,18 @@ class ClusterService:
         where the read surface is this process's own (a surface over
         the wire would park the connection in its round trip: then
         reads stay on the pool); a GRV is granted now or hands the
-        server a ``Park`` (``get_read_version``). Commits wait for
-        their batch, watches for their key, admin calls for whatever
-        they manage: not declared, so they ride the pools."""
+        server a ``Park`` (``get_read_version``); a commit, where the
+        cluster batches commits on a thread of its own, is submitted
+        (a locked append) and answered when its batch settles
+        (``commit``: a ``Deferred``). Under the ``"sync"`` pipeline a
+        commit runs the whole pipeline under ``_commit_lock``; watches
+        wait for their key, admin calls and ``commit_batch`` for
+        whatever they manage: not declared, so they ride the pools."""
         from foundationdb_tpu.server.storage import RangeReadInterface
 
         inline = {"ping", "get_read_version"}
+        if self._commit_lock is None:
+            inline.add("commit")
         if isinstance(self.cluster.read_storage(), RangeReadInterface):
             inline |= {"storage_get", "get_range", "resolve_selector",
                        "read_batch"}
@@ -321,10 +328,19 @@ class ClusterService:
         # the proxy returns (never raises) FDBError verdicts; the wire
         # carries them as values so the client transaction sees the exact
         # in-process contract
+        proxy = self.cluster.commit_proxy
         if self._commit_lock is not None:
             with self._commit_lock:
-                return self.cluster.commit_proxy.commit(request)
-        return self.cluster.commit_proxy.commit(request)
+                return proxy.commit(request)
+        submit = getattr(proxy, "submit", None)
+        if submit is None:
+            # a proxy that cannot take a completion: its wait must not
+            # hold the connection this request came in on
+            return Park(lambda: proxy.commit(request))
+        # no thread waits for the batch: the batcher's settle completes
+        # the request, and the reply leaves with its batch's others
+        fut = submit(request)
+        return Deferred(fut.add_done_callback, fut.poll)
 
     def _configure(self, commit_proxies=None, resolvers=None):
         """Live reconfiguration over the wire (fdbcli `configure`);

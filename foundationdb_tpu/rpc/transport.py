@@ -10,7 +10,11 @@ complete frame in it) and answers a request on the thread that read it
 where its endpoint was declared unable to wait on another thread
 (``inline_methods``: a storage read, a GRV that can be granted now);
 every other handler runs on a shared pool, so a blocking endpoint (a
-commit, a watch wait, a queued GRV) never stalls the socket.
+watch wait, a queued GRV) never stalls the socket. A handler whose
+result another thread will have later (a commit: its batch settles on
+the batcher's thread) returns a :class:`Deferred` and holds no thread
+at all: the replies of one settle go out together, one send a
+connection, from the server's reply thread.
 
 Frame: 4-byte big-endian length + wire payload.
 Request: ("q", seq, method, args-tuple)  Reply: ("r", seq, ok, payload).
@@ -27,6 +31,7 @@ exposes full read/write/management access and is unsafe.
 import hashlib
 import hmac
 import os
+from collections import deque
 import socket
 import struct
 import threading
@@ -48,6 +53,24 @@ _AUTH_HANDSHAKE_TIMEOUT_S = 5.0
 # long before checking outstanding requests against their deadlines, so
 # a wedged peer costs one deadline + one tick, never a hung thread
 _DEADLINE_TICK_S = 0.05
+# the server's reply thread looks this often at the requests whose
+# reply is deferred and unanswered (``Deferred.poll``): a registrant's
+# own deadline (the batcher's stranded-batch watchdog) is kept to a tick
+_DEFERRED_POLL_S = 0.25
+# A reply provokes its client's next request. The replies of one settle
+# sent back to back come back as one burst of requests, and every
+# request in flight beside them queues behind it for the interpreter;
+# so the reply thread pauses between one connection's send and the
+# next, off everybody's path (it sleeps), this long for each reply it
+# has just sent: about what the interpreter needs to serve the request
+# a reply provokes (≈ 6,500 short requests a second, PERF.md §5).
+# Measured on a TPU v5 lite's host, ycsb_a's 64 clients over eight
+# connections, two replies a send (PERF.md §6, PR 38): read p95 8.84 ms
+# with no pause, 6.77 / 6.69 / 6.40 / 6.73 / 6.79 with 0.10 / 0.15 /
+# 0.20 / 0.25 / 0.60 ms a connection (6.66 while sixteen parked workers
+# sent one reply each as they got their turns); the update median pays
+# 2.6 ms of its 18 gained for any pause at all and 5 for the longest.
+_REPLY_SPACING_S = 0.00015
 # consecutive deadline sweeps (with zero frames received in between)
 # after which a connection is presumed black-holed rather than slow:
 # callers close it and reconnect on a fresh socket instead of paying
@@ -87,7 +110,8 @@ def rpc_class(method):
 # system call there, 6 µs against a read handler of 36 µs, and two on
 # every request cost a tenth of the ycsb cell's ops_per_s.
 RPC_COUNTERS = ("requests", "timed_requests", "decode_us", "queue_wait_us",
-                "handler_wall_us", "reply_us", "inline_requests")
+                "handler_wall_us", "reply_us", "inline_requests",
+                "deferred_requests")
 TIME_EVERY = 4
 _RPC_STAGE = {c: "rpc." + c for c in RPC_CLASSES}
 
@@ -183,6 +207,58 @@ class Park:
         self.resume = resume
 
 
+class Deferred:
+    """What a handler returns in place of a result that another thread
+    will have later: ``register(complete)`` arranges that
+    ``complete(payload)`` is called exactly once, on any thread (at
+    once, if the result is in hand). The request then holds no thread,
+    on the pool or off it. ``complete`` queues the reply and hands back
+    the server's flush; a caller that completes several requests in a
+    row calls the flush once, behind the last, and their replies share
+    a send per connection. ``register`` returns what ``complete``
+    returned if it ran at once, else None. ``poll``, where given, is
+    called every ``_DEFERRED_POLL_S`` or so while the request is
+    unanswered, for a registrant with a deadline of its own to keep."""
+
+    __slots__ = ("register", "poll")
+
+    def __init__(self, register, poll=None):
+        self.register = register
+        self.poll = poll
+
+
+class _DeferredReply:
+    """One request whose handler returned a :class:`Deferred`: where
+    its reply goes, and its stamps so far."""
+
+    __slots__ = ("server", "sock", "send_lock", "seq", "method", "cls",
+                 "stamps", "poll")
+
+    def __init__(self, server, sock, send_lock, seq, method, cls, stamps,
+                 poll):
+        self.server = server
+        self.sock = sock
+        self.send_lock = send_lock
+        self.seq = seq
+        self.method = method
+        self.cls = cls
+        self.stamps = stamps  # (t_recv, t_decoded, t0) of a timed one
+        self.poll = poll
+
+    def complete(self, payload, ok=True):
+        """The result is in hand: encode the reply and queue it for
+        the next flush. Never raises (``_encode_reply``)."""
+        server = self.server
+        stamps = self.stamps
+        if stamps is not None:
+            stamps += (span_mod.now(),)
+        frame = server._encode_reply(self.seq, self.method, ok, payload)
+        server._deferred.discard(self)
+        server._outbox.append(
+            (self.sock, self.send_lock, frame, self.cls, stamps))
+        return server._outbox_ready.set
+
+
 class _FrameReader:
     """Buffered frame reader: one ``recv`` of up to 64 KB, every
     complete frame parsed out of the buffer.
@@ -248,7 +324,10 @@ class RpcServer:
     A connection multiplexes its client's threads, so nothing that can
     park may be declared; a handler that finds it has to wait after all
     returns a :class:`Park` and its request moves to the pool. An
-    endpoint not declared runs on the pool.
+    endpoint not declared runs on the pool. On either kind of thread a
+    handler may return a :class:`Deferred`: the request then waits on
+    no thread, and the reply thread sends its reply with the others
+    that were completed beside it.
 
     Every fourth request of a class is stamped on the injected clock at
     its layer boundaries — frame read, decoded, handler start (on the
@@ -257,7 +336,10 @@ class RpcServer:
     cost in decode, in the pool's queue, in its handler and in the
     reply. Every request is counted, and annotated for the profiler.
     One locked add a request on a pool thread, one a burst on a
-    connection's own.
+    connection's own, one a flush of deferred replies. A deferred
+    request's handler time runs from its handler's start to its
+    ``complete``, its reply time from there to the return of the send
+    that carried it.
     """
 
     def __init__(self, host, port, handlers, max_workers=16,
@@ -279,7 +361,9 @@ class RpcServer:
         )
         self._stats_lock = lockdep.lock("RpcServer._stats_lock")
         # per class, in RPC_COUNTERS' order; seconds until stats()
-        self._acc = {c: [0, 0, 0.0, 0.0, 0.0, 0.0, 0] for c in RPC_CLASSES}
+        self._acc = {c: [0, 0, 0.0, 0.0, 0.0, 0.0, 0, 0]
+                     for c in RPC_CLASSES}
+        self._deferred_sends = 0  # sends that carried deferred replies
         # requests decoded per class, for the sampling alone: bumped by
         # every connection thread without a lock (a lost count moves a
         # sample by one request)
@@ -295,6 +379,14 @@ class RpcServer:
             if self.long_methods
             else None
         )
+        # deferred replies: the requests waiting for their ``complete``
+        # (set operations are atomic; the reply thread copies it to
+        # poll), the replies completed and not yet sent, and the event
+        # a completer sets once behind the last of its row
+        self._deferred = set()
+        self._outbox = deque()
+        self._outbox_ready = threading.Event()
+        self._reply_thread = None  # started by the first deferred request
         self._conns = {}  # socket -> its frame reader, once authenticated
         self._recv_retired = 0  # socket reads of connections since closed
         self._lock = lockdep.lock("RpcServer._lock")
@@ -465,7 +557,9 @@ class RpcServer:
         reply and counts the request. A connection's own thread hands in
         ``answered``, where the burst's replies gather for one send and
         one count; a handler that parks there gets its :class:`Park`
-        back unanswered, for the caller to hand on."""
+        back unanswered, for the caller to hand on. A request whose
+        handler defers is answered and counted by the flush that sends
+        its reply (``_defer``), on neither kind of thread."""
         seq, method, args, trace_ctx, cls, t_recv, t_decoded = request
         timed = t_decoded is not None
         prior_ctx = None
@@ -488,6 +582,12 @@ class RpcServer:
                     if answered is not None:
                         return payload
                     ok, payload = self._handle(payload.resume, method, ())
+                if type(payload) is Deferred:
+                    self._defer(_DeferredReply(
+                        self, sock, send_lock, seq, method, cls,
+                        (t_recv, t_decoded, st.t0) if timed else None,
+                        payload.poll), payload.register)
+                    return None
                 if timed:
                     stamps = (t_recv, t_decoded, st.t0, span_mod.now())
                 frame = self._encode_reply(seq, method, ok, payload)
@@ -501,21 +601,99 @@ class RpcServer:
         else:
             answered.append((frame, cls, stamps))
 
+    def _defer(self, reply, register):
+        """A handler's result will come later: note the request as
+        unanswered and hand the registrant its ``complete``."""
+        if self._reply_thread is None:
+            with self._lock:
+                if self._reply_thread is None and not self._closed.is_set():
+                    self._reply_thread = threading.Thread(
+                        target=self._reply_loop, name="rpc-reply",
+                        daemon=True)
+                    self._reply_thread.start()
+        self._deferred.add(reply)
+        try:
+            flush = register(reply.complete)
+        except Exception as e:
+            flush = reply.complete(
+                self._remote_failure(reply.method, e)[1], ok=False)
+        if flush is not None:
+            flush()
+
+    def _reply_loop(self):
+        """The reply thread: woken once by whoever completed a row of
+        deferred requests, it sends their replies; between wakes it
+        polls the unanswered ones' registrants."""
+        ready = self._outbox_ready
+        next_poll = time.monotonic() + _DEFERRED_POLL_S
+        while not self._closed.is_set():
+            ready.wait(_DEFERRED_POLL_S)
+            # cleared BEFORE the drain: a reply queued after the drain
+            # sets it again, one queued before the clear is drained
+            ready.clear()
+            self._flush_deferred()
+            now = time.monotonic()
+            if now < next_poll:
+                continue
+            next_poll = now + _DEFERRED_POLL_S
+            for poll in {r.poll for r in list(self._deferred)} - {None}:
+                try:
+                    poll()
+                except Exception as e:
+                    TraceEvent("RpcDeferredPollError",
+                               severity=SEV_ERROR).detail(
+                        etype=type(e).__name__, error=str(e)[:200]).log()
+
+    def _flush_deferred(self):
+        """Every reply completed so far, grouped by connection: one
+        send a connection (each reply a whole frame), a pause of
+        ``_REPLY_SPACING_S`` a reply between connections, one locked
+        add."""
+        outbox = self._outbox
+        by_conn = {}
+        while outbox:  # this thread is its one consumer
+            sock, send_lock, frame, cls, stamps = outbox.popleft()
+            conn = by_conn.get(sock)
+            if conn is None:
+                by_conn[sock] = conn = (send_lock, [])
+            conn[1].append((frame, cls, stamps))
+        if not by_conn:
+            return
+        sent = []
+        for sock, (send_lock, replies) in by_conn.items():
+            if sent:
+                time.sleep(_REPLY_SPACING_S * len(sent[-1][0]))
+            # fdb.rpc.<class> of the first: a flush's replies are one
+            # class's (the commits of a batch) but for a coincidence
+            with span_mod.annotation(_RPC_STAGE[replies[0][1]]):
+                self._send(sock, send_lock,
+                           b"".join([r[0] for r in replies]))
+            sent.append((replies, span_mod.now()))
+        with self._stats_lock:
+            self._deferred_sends += len(sent)
+            for replies, t_sent in sent:
+                self._add(replies, t_sent, 0, 1)
+
     def _count(self, answered, t_sent, inline=0):
         """The one locked add, after the send: a pool thread's request,
         or the burst a connection's own thread answered."""
         with self._stats_lock:
-            for _frame, cls, stamps in answered:
-                acc = self._acc[cls]
-                acc[0] += 1
-                acc[6] += inline
-                if stamps is not None:
-                    t_recv, t_decoded, t0, t_handled = stamps
-                    acc[1] += 1
-                    acc[2] += t_decoded - t_recv
-                    acc[3] += t0 - t_decoded
-                    acc[4] += t_handled - t0
-                    acc[5] += t_sent - t_handled
+            self._add(answered, t_sent, inline, 0)
+
+    def _add(self, answered, t_sent, inline, deferred):
+        """Under ``_stats_lock``: requests answered by one send."""
+        for _frame, cls, stamps in answered:
+            acc = self._acc[cls]
+            acc[0] += 1
+            acc[6] += inline
+            acc[7] += deferred
+            if stamps is not None:
+                t_recv, t_decoded, t0, t_handled = stamps
+                acc[1] += 1
+                acc[2] += t_decoded - t_recv
+                acc[3] += t0 - t_decoded
+                acc[4] += t_handled - t0
+                acc[5] += t_sent - t_handled
 
     @staticmethod
     def _remote_failure(method, e):
@@ -541,13 +719,17 @@ class RpcServer:
         """``cluster.rpc`` of the status document: the per-class totals
         (``<counter>.<class>``), the socket reads that returned bytes
         (``recv_calls``: requests / recv_calls is how many requests a
-        read brings), the short pool's size and the deepest
+        read brings), the sends that carried deferred replies
+        (``deferred_sends``: deferred_requests / deferred_sends is how
+        many replies a send takes) and the deferred requests unanswered
+        now (``deferred_pending``), the short pool's size and the deepest
         its queue has been seen (looked at with every timed request),
         and this process's CPU and wall time, read
         now (no hot-path cost): Δcpu/Δwall ≈ 1.0 over a busy interval
         means the interpreter lock is the machine."""
         with self._stats_lock:
             acc = {c: list(v) for c, v in self._acc.items()}
+            deferred_sends = self._deferred_sends
         with self._lock:
             # a reader's count is its connection thread's alone to write
             recv_calls = self._recv_retired + sum(
@@ -558,6 +740,8 @@ class RpcServer:
                          for c, v in acc.items()}
                for i, counter in enumerate(RPC_COUNTERS)}
         doc["recv_calls"] = recv_calls
+        doc["deferred_sends"] = deferred_sends
+        doc["deferred_pending"] = len(self._deferred)
         doc["pool"] = {"workers": self.max_workers,
                        "queued": self._queue_depth(),
                        "queued_high_water": self._queued_high_water}
@@ -610,6 +794,11 @@ class RpcServer:
         if self._long_pool is not None:
             self._long_pool.shutdown(wait=False)
         self._accept_thread.join(timeout=2)
+        with self._lock:
+            reply_thread = self._reply_thread
+        if reply_thread is not None:
+            self._outbox_ready.set()
+            reply_thread.join(timeout=2)
 
 
 class RemoteError(RuntimeError):

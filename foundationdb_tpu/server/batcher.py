@@ -11,7 +11,9 @@ Two drive modes:
 
 - **thread** (live deployments): a daemon batcher thread
   collects submissions for up to ``interval_s`` (or until ``max_batch``),
-  then drives the inner proxy. Clients block on a CommitFuture. With
+  then drives the inner proxy. In-process clients block on a
+  CommitFuture; a served ``commit`` (rpc/service.py) blocks no thread:
+  it hands the future a completion and the settle answers it. With
   ``knobs.commit_pipeline_depth > 1`` the drain loop is a bounded
   TWO-STAGE pipeline: the batcher thread runs stage A+B of each backlog
   group (version grant + host packing + gate-ordered lazy resolve
@@ -45,6 +47,11 @@ from foundationdb_tpu.utils.trace import SEV_ERROR, StageStats, TraceEvent
 
 _UNSET = object()
 
+# set() racing set() (the watchdog's 1021 against a wedged drive's late
+# result) has one winner, and the winner alone runs the completion: one
+# lock for every future, held for a compare and two stores
+_settle_mu = lockdep.lock("batcher._settle_mu")
+
 
 class CommitFuture:
     """Resolves to a commit version (int) or an FDBError.
@@ -56,25 +63,58 @@ class CommitFuture:
     was measurable e2e overhead at tens of thousands of commits/sec.
     A standalone future (no proxy) must be ``set`` before ``result`` is
     awaited — the pattern of every standalone construction site
-    (read-only fast paths, fault wrappers resolve immediately)."""
+    (read-only fast paths, fault wrappers resolve immediately).
 
-    __slots__ = ("_result", "_proxy", "born")
+    A holder that must not block (the served ``commit``: its thread owns
+    a connection) takes the result through ``add_done_callback`` and
+    calls ``poll`` now and then in place of ``result``."""
+
+    __slots__ = ("_result", "_proxy", "born", "_on_done")
 
     def __init__(self, proxy=None):
         self._result = _UNSET
         self._proxy = proxy
         self.born = None  # injected-clock stamp set at submit (spans)
+        self._on_done = None
 
     def done(self):
         return self._result is not _UNSET
 
     def set(self, result):
+        """Settle; returns what the completion returned (below), None
+        where there is none or the future was settled already."""
         # first settlement wins: once a waiter may have observed a
         # verdict (e.g. the stranded-batch watchdog's 1021, already
         # acted on by a retry), a late real result must not replace it
         # — an acked-then-changed verdict is how double-applies happen
-        if self._result is _UNSET:
+        with _settle_mu:
+            if self._result is not _UNSET:
+                return None
             self._result = result
+            fn, self._on_done = self._on_done, None
+        return None if fn is None else fn(result)
+
+    def add_done_callback(self, fn):
+        """The future's one completion: ``fn(result)`` runs exactly
+        once, on the thread whose ``set`` wins — here and now if that
+        has happened — outside every lock of this module, and must not
+        raise. It may hand back a callable (a flush of what it queued):
+        whoever settled the future calls it once it has settled all it
+        had to, so completions of one batch can share a send."""
+        with _settle_mu:
+            if self._result is _UNSET:
+                if self._on_done is not None:
+                    raise RuntimeError("commit future has a completion")
+                self._on_done = fn
+                return None
+            result = self._result
+        return fn(result)
+
+    def poll(self):
+        """The proxy's stranded-batch watchdog, for a holder that does
+        not wait in ``result`` (which runs it between wait chunks)."""
+        if self._proxy is not None:
+            self._proxy._check_stranded()
 
     def result(self, timeout=None):
         """Block until resolved (thread mode); returns version or FDBError.
@@ -275,7 +315,9 @@ class BatchingCommitProxy:
 
     def _check_stranded(self):
         """Stranded-batch watchdog (invoked by waiting clients between
-        wait chunks): a batch that has been driving the inner proxy
+        wait chunks, and for the commits nobody waits on by whoever
+        holds their futures: ``CommitFuture.poll``): a batch
+        that has been driving the inner proxy
         past ``watchdog_s`` settles every future in it with 1021 — the
         commits MAY have happened; the retry loop's idempotency ids own
         the disambiguation. The wedged drive keeps running; its eventual
@@ -291,10 +333,7 @@ class BatchingCommitProxy:
         TraceEvent("CommitBatchStranded", severity=30).detail(
             txns=len(run), bound_s=self.watchdog_s).log()
         unknown = FDBError.from_name("commit_unknown_result")
-        for _, fut in run:
-            fut.set(unknown)
-        with self._done_cond:
-            self._done_cond.notify_all()
+        self._set_all((fut, unknown) for _, fut in run)
 
     def _run_batch(self, pending):
         with self._lock:
@@ -523,18 +562,32 @@ class BatchingCommitProxy:
             )
         self._adapt_backlog(txns, conflicts)
 
+    def _set_all(self, settled):
+        """Settle every (future, result) pair, wake the threads that
+        wait in ``result`` ONCE for the whole batch, then run each
+        flush the completions handed back once: a batch's served
+        commits are answered together, behind the last ``set``."""
+        flushes = set()
+        with span_mod.annotation("batcher.settle"):
+            for fut, res in settled:
+                flush = fut.set(res)
+                if flush is not None:
+                    flushes.add(flush)
+            with self._done_cond:
+                self._done_cond.notify_all()
+            for flush in flushes:
+                flush()
+
     def _settle(self, chunk, results):
         self._record_span(chunk)
-        for (_, fut), res in zip(chunk, results):
-            fut.set(res)
-        with self._done_cond:  # ONE wakeup for the whole batch
+        with self._done_cond:
             # stat counters live under _done_cond: _settle runs on the
             # batcher thread, the apply worker, AND caller threads
             # (manual/sim pipelines), so the bare += was a lost-update
             self.batches_committed += 1
             self.txns_batched += len(chunk)
             self.max_batch_seen = max(self.max_batch_seen, len(chunk))
-            self._done_cond.notify_all()
+        self._set_all(zip((fut for _, fut in chunk), results))
 
     def _record_span(self, chunk):
         """One commit_e2e band record per settled batch window: the
@@ -564,13 +617,11 @@ class BatchingCommitProxy:
 
     def _fail_chunks(self, chunks, e):
         self.last_batch_error = e
+        error = e if isinstance(e, FDBError) else \
+            FDBError.from_name("commit_unknown_result")
         for chunk in chunks:
             self._record_span(chunk)  # a failure reply is still a reply
-            for _, fut in chunk:
-                fut.set(e if isinstance(e, FDBError) else
-                        FDBError.from_name("commit_unknown_result"))
-        with self._done_cond:
-            self._done_cond.notify_all()
+        self._set_all((fut, error) for chunk in chunks for _, fut in chunk)
 
     def _batcher_loop(self):
         while True:
@@ -610,10 +661,7 @@ class BatchingCommitProxy:
         with self._lock:
             pending, self._pending = self._pending, []
             self._first_pending_step = None
-        for _, fut in pending:
-            fut.set(error)
-        with self._done_cond:
-            self._done_cond.notify_all()
+        self._set_all((fut, error) for _, fut in pending)
 
     def close(self):
         with self._lock:

@@ -370,3 +370,197 @@ def test_backlog_depth_adapts_to_conflict_rate():
     for _ in range(10):
         bp._run_batch(list(pending))
     assert bp._backlog_target == bp.MAX_BACKLOG  # clean traffic regrows
+
+
+# ───────────── completions: a commit nobody waits for (PR 38) ─────────────
+class _WedgedInner:
+    """An inner proxy whose ``commit_batch`` stands still until
+    released, then commits everything at version 7."""
+
+    def __init__(self, **knobs):
+        from foundationdb_tpu.core.options import Knobs
+
+        self.knobs = Knobs(**knobs)
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        self.batches = []
+
+    def commit_batch(self, reqs):
+        self.batches.append(len(reqs))
+        self.entered.set()
+        assert self.release.wait(30)
+        return [7] * len(reqs)
+
+
+def _wait_for(pred, what, timeout=20.0):
+    import time
+
+    deadline = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < deadline, f"waited for {what}"
+        time.sleep(0.001)
+
+
+@pytest.mark.parametrize("registered", ["before", "after"])
+def test_completion_runs_once_with_the_first_settlement(registered):
+    """``set`` keeps 'first settlement wins'; the completion sees the
+    winner, whether it was registered before it or after."""
+    from foundationdb_tpu.server.batcher import CommitFuture
+
+    got = []
+    fut = CommitFuture()
+    if registered == "before":
+        assert fut.add_done_callback(got.append) is None
+        assert got == []
+    fut.set(11)
+    fut.set(FDBError.from_name("commit_unknown_result"))  # the loser
+    if registered == "after":
+        fut.add_done_callback(got.append)  # runs here and now
+    fut.set(12)
+    assert got == [11] and fut.result(timeout=0) == 11
+
+
+def test_a_future_takes_one_completion():
+    from foundationdb_tpu.server.batcher import CommitFuture
+
+    fut = CommitFuture()
+    fut.add_done_callback(lambda r: None)
+    with pytest.raises(RuntimeError):
+        fut.add_done_callback(lambda r: None)
+
+
+def test_completion_fires_exactly_once_under_set_racing_set():
+    """The watchdog's 1021 against the real result, from two threads at
+    a 10 µs switch interval, with the completion registered by a third:
+    every future's completion runs once, with the value ``result``
+    reads ever after."""
+    import sys
+
+    from foundationdb_tpu.server.batcher import CommitFuture
+
+    n = 3000
+    unknown = FDBError.from_name("commit_unknown_result")
+    futs = [CommitFuture() for _ in range(n)]
+    got = [[] for _ in range(n)]
+    start = threading.Barrier(3)
+
+    def settle(value):
+        start.wait(10)
+        for f in futs:
+            f.set(value)
+
+    def register():
+        start.wait(10)
+        for f, g in zip(futs, got):
+            f.add_done_callback(g.append)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=settle, args=(7,)),
+                   threading.Thread(target=settle, args=(unknown,)),
+                   threading.Thread(target=register)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert all(len(g) == 1 for g in got)
+    assert all(g[0] is f.result(timeout=0) for f, g in zip(futs, got))
+    assert {type(g[0]) for g in got} <= {int, FDBError}
+
+
+def test_a_settle_runs_each_flush_once_behind_its_last_completion():
+    """What a completion hands back is called once a settle, after
+    every future of the batch is set: the hook a served batch's replies
+    share a send through."""
+    from foundationdb_tpu.server.batcher import BatchingCommitProxy
+
+    inner = _WedgedInner()
+    inner.release.set()
+    bp = BatchingCommitProxy(inner, mode="manual")
+    events = []
+
+    def flush_a():
+        events.append("flush_a")
+
+    def flush_b():
+        events.append("flush_b")
+
+    futs = [bp.submit(object()) for _ in range(6)]
+    for i, fut in enumerate(futs):
+        fut.add_done_callback(
+            lambda r, i=i: events.append(i) or (flush_a, flush_b)[i % 2])
+    plain = bp.submit(object())  # no completion: result() as ever
+    bp.flush()
+    assert events[:6] == list(range(6))
+    assert sorted(events[6:]) == ["flush_a", "flush_b"]
+    assert plain.result(timeout=0) == 7
+    assert inner.batches == [7] and bp.batches_committed == 1
+
+
+@pytest.mark.parametrize("how", ["fail_pending", "fail_chunks"])
+def test_failed_batches_answer_their_completions(how):
+    """A crash before the batch formed (``fail_pending``) and a batch
+    the inner proxy threw out of (``_fail_chunks``) both complete the
+    commits nobody waits for, with 1021."""
+    from foundationdb_tpu.core.options import Knobs
+    from foundationdb_tpu.server.batcher import BatchingCommitProxy
+
+    class Inner:
+        knobs = Knobs()
+
+        def commit_batch(self, reqs):
+            raise IOError("disk full (injected)")
+
+    bp = BatchingCommitProxy(Inner(), mode="manual")
+    got, flushes = [], []
+
+    def flush():
+        flushes.append(len(got))
+
+    for _ in range(5):
+        bp.submit(object()).add_done_callback(
+            lambda r: got.append(r) or flush)
+    if how == "fail_pending":
+        bp.fail_pending(FDBError.from_name("commit_unknown_result"))
+    else:
+        bp.flush()
+        assert isinstance(bp.last_batch_error, IOError)
+    assert [r.code for r in got] == [1021] * 5
+    assert flushes == [5]  # once, behind the last of them
+
+
+def test_watchdog_without_a_waiter_settles_a_wedged_batch():
+    """No thread blocks in ``result``: whoever holds the futures calls
+    ``poll`` now and then. A batch wedged past ``watchdog_s``
+    completes every commit of it with 1021, the wedged drive's late
+    results change nothing, ``stranded_settled`` counts them."""
+    from foundationdb_tpu.server.batcher import BatchingCommitProxy
+
+    inner = _WedgedInner()
+    bp = BatchingCommitProxy(inner, interval_s=0.0, mode="thread")
+    bp.watchdog_s = 0.2
+    got = []
+    try:
+        futs = [bp.submit(object()) for _ in range(1)]
+        assert inner.entered.wait(20)
+        for fut in futs:
+            fut.add_done_callback(got.append)
+        futs[0].poll()  # too early: the batch is young
+        assert got == [] and bp.stranded_settled == 0
+        _wait_for(lambda: futs[0].poll() or got,
+                  "the watchdog to fire")
+        assert [r.code for r in got] == [1021]
+        assert bp.stranded_settled == 1
+        inner.release.set()  # the wedged drive's late set loses
+        _wait_for(lambda: bp.batches_committed == 1, "the late settle")
+        assert [r.code for r in got] == [1021]
+        assert futs[0].result(timeout=0).code == 1021
+        late = bp.submit(object())  # the batcher thread lives on
+        assert late.result(timeout=20) == 7
+    finally:
+        inner.release.set()
+        bp.close()

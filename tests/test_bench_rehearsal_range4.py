@@ -58,7 +58,14 @@ def test_the_four_lane_range_cell_rehearses_correct_with_its_metrics():
     traced = {"range4.mesh_step.device_ms", "range4.mesh_step_roofline",
               "range4.device.idle_share"}
     assert traced < mine and not traced & set(metrics)
-    assert set(metrics) == mine - traced, sorted(mine ^ set(metrics))
+    # PR 38's two list the cell and not its twin: a PR that claims a
+    # gain adds metric files and edits no file the benchmark has
+    # (tests/test_rpc.py holds the counters they read)
+    untwinned = {"rpc.commit.deferred_share_pct",
+                 "rpc.deferred_replies_per_send"}
+    assert untwinned < mine
+    assert set(metrics) == mine - traced - untwinned, \
+        sorted((mine - untwinned) ^ set(metrics))
     # both of this PR's counters are read: a fence costs its round in
     # the warm-up, and a range of 20 rows among 12,000 slots crosses one
     # of three bounds now and then

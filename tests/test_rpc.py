@@ -582,11 +582,15 @@ def test_grv_that_has_to_queue_is_answered_from_the_pool(remote_db):
     assert after["inline_requests"]["admin"] == 0
 
 
-def test_served_cluster_declares_reads_and_grv_not_commits(remote_db):
+def test_served_cluster_declares_reads_grv_and_the_deferred_commit(
+        remote_db):
+    """``commit`` is declared since PR 38 (submitted where it was
+    decoded, answered by a completion) and still never counts inline:
+    it was not answered on the thread that read it."""
     db, _, server = remote_db
     assert server.inline_methods == {
         "ping", "get_read_version", "storage_get", "get_range",
-        "resolve_selector", "read_batch"}
+        "resolve_selector", "read_batch", "commit"}
     assert not server.inline_methods & server.long_methods
     db[b"a"] = b"1"
     assert db[b"a"] == b"1"
@@ -597,6 +601,7 @@ def test_served_cluster_declares_reads_and_grv_not_commits(remote_db):
     doc = server.stats()
     assert doc["inline_requests"]["read"] == doc["requests"]["read"]
     assert doc["inline_requests"]["commit"] == 0
+    assert doc["deferred_requests"]["commit"] == doc["requests"]["commit"]
     assert doc["inline_requests"]["admin"] == 0
     assert doc["requests"]["admin"] >= 1  # hello, at least
 
@@ -880,3 +885,512 @@ def test_inline_and_pooled_requests_interleaved_under_thread_switching():
     assert doc["inline_requests"] == {"read": reads, "grv": 0, "commit": 0,
                                       "admin": 0}
     assert 1 <= doc["recv_calls"] <= reads + commits
+
+
+# ─────────────── a deferred reply: a request that holds no thread ───────────────
+from foundationdb_tpu.rpc.transport import Deferred  # noqa: E402
+
+
+class _Registrar:
+    """Stands where the batcher stands: takes each deferred request's
+    ``complete`` and calls it later, from the test's own thread."""
+
+    def __init__(self):
+        from collections import deque
+
+        self.completes = deque()  # appended by the server's threads
+        self.polls = 0
+
+    def handler(self, x):
+        return Deferred(lambda complete: self.completes.append(
+            (x, complete)), self.poll)
+
+    def poll(self):
+        self.polls += 1
+
+    def settle(self, n=None):
+        """Complete what has registered (the first ``n``) in one row:
+        one flush behind the last."""
+        n = len(self.completes) if n is None else n
+        row = [self.completes.popleft() for _ in range(n)]
+        flushes = {complete(("done", x)) for x, complete in row}
+        for flush in flushes:
+            flush()
+
+
+def test_deferred_reply_reaches_its_caller_with_every_pool_worker_held():
+    """Sixteen admin calls hold all sixteen workers; a declared
+    endpoint that defers is answered all the same: its request waits on
+    no thread, the pool's or the connection's."""
+    release = threading.Event()
+    running = threading.Semaphore(0)
+
+    def held():
+        running.release()
+        release.wait(30)
+        return "held"
+
+    reg = _Registrar()
+    server = RpcServer("127.0.0.1", 0,
+                       {"commit": reg.handler, "status": held,
+                        "ping": lambda: "pong"},
+                       inline_methods={"commit", "ping"}, max_workers=16)
+    client = RpcClient(server.host, server.port)
+    try:
+        holders = [client.call_async("status") for _ in range(16)]
+        for _ in holders:
+            assert running.acquire(timeout=20)
+        commits = [client.call_async("commit", i) for i in range(5)]
+        _wait_for(lambda: len(reg.completes) == 5, "five to register")
+        # the connection's thread is free too: it answers a ping
+        assert client.call("ping", timeout=20) == "pong"
+        doc = server.stats()
+        assert doc["deferred_pending"] == 5
+        assert doc["pool"]["queued"] == 0
+        assert not any(c.done() for c in commits)
+        reg.settle()
+        assert [c.result(20) for c in commits] == [
+            ("done", i) for i in range(5)]
+        assert not any(h.done() for h in holders)
+        release.set()
+        assert [h.result(20) for h in holders] == ["held"] * 16
+        doc = _counted(server, 22)
+    finally:
+        release.set()
+        client.close()
+        server.close()
+    assert doc["deferred_requests"] == {
+        "read": 0, "grv": 0, "commit": 5, "admin": 0}
+    assert doc["inline_requests"] == {
+        "read": 1, "grv": 0, "commit": 0, "admin": 0}
+    assert doc["deferred_sends"] == 1 and doc["deferred_pending"] == 0
+
+
+@DECLARED
+def test_deferred_replies_of_one_row_share_a_send_per_connection(declared):
+    """Twelve requests from three connections completed in one row:
+    three sends. From a pool thread (undeclared) as from a connection's
+    own, the deferred request is counted where its reply was sent: not
+    inline."""
+    reg = _Registrar()
+    server = RpcServer("127.0.0.1", 0, {"commit": reg.handler},
+                       inline_methods={"commit"} if declared else ())
+    clients = [RpcClient(server.host, server.port) for _ in range(3)]
+    try:
+        futs = [c.call_async("commit", (n, i))
+                for n, c in enumerate(clients) for i in range(4)]
+        _wait_for(lambda: len(reg.completes) == 12, "twelve to register")
+        reg.settle(6)
+        reg.settle()
+        assert sorted(f.result(20) for f in futs) == [
+            ("done", (n, i)) for n in range(3) for i in range(4)]
+        doc = _counted(server, 12)
+    finally:
+        for c in clients:
+            c.close()
+        server.close()
+    assert doc["deferred_requests"]["commit"] == 12
+    assert doc["inline_requests"]["commit"] == 0
+    # two rows, each over at most three connections
+    assert 2 <= doc["deferred_sends"] <= 6
+    assert doc["timed_requests"]["commit"] == 3  # every fourth
+
+
+def test_deferred_result_in_hand_at_registration_is_answered_at_once():
+    server = RpcServer(
+        "127.0.0.1", 0,
+        {"commit": lambda x: Deferred(lambda complete: complete(x + 1))},
+        inline_methods={"commit"})
+    client = RpcClient(server.host, server.port)
+    try:
+        assert client.call("commit", 41, timeout=20) == 42
+        doc = _counted(server, 1)
+    finally:
+        client.close()
+        server.close()
+    assert doc["deferred_requests"]["commit"] == 1
+    assert doc["deferred_sends"] == 1
+
+
+def test_deferred_registration_that_raises_answers_a_remote_failure():
+    from foundationdb_tpu.rpc.transport import RemoteError
+
+    def register(complete):
+        raise KeyError("no batcher (injected)")
+
+    server = RpcServer("127.0.0.1", 0,
+                       {"commit": lambda: Deferred(register)},
+                       inline_methods={"commit"})
+    client = RpcClient(server.host, server.port)
+    try:
+        with pytest.raises(RemoteError, match="no batcher"):
+            client.call("commit", timeout=20)
+        assert server.stats()["deferred_pending"] == 0
+    finally:
+        client.close()
+        server.close()
+
+
+def test_deferred_poll_runs_while_unanswered_and_not_after(monkeypatch):
+    from foundationdb_tpu.rpc import transport
+
+    monkeypatch.setattr(transport, "_DEFERRED_POLL_S", 0.01)
+    reg = _Registrar()
+    server = RpcServer("127.0.0.1", 0, {"commit": reg.handler},
+                       inline_methods={"commit"})
+    client = RpcClient(server.host, server.port)
+    try:
+        futs = [client.call_async("commit", i) for i in range(3)]
+        # one poll a tick for the three of them: they share a registrant
+        _wait_for(lambda: reg.polls >= 3, "the reply thread to poll")
+        reg.settle()
+        assert [f.result(20) for f in futs] == [("done", i) for i in range(3)]
+        _wait_for(lambda: server.stats()["deferred_pending"] == 0, "answers")
+        time.sleep(0.05)
+        polls = reg.polls
+        time.sleep(0.1)
+        assert reg.polls == polls
+    finally:
+        client.close()
+        server.close()
+
+
+def test_vanished_client_costs_its_row_nothing():
+    """A client that left before its request was completed: its reply
+    has nowhere to go, the others of the row arrive."""
+    reg = _Registrar()
+    server = RpcServer("127.0.0.1", 0, {"commit": reg.handler},
+                       inline_methods={"commit"})
+    gone = RpcClient(server.host, server.port)
+    stays = RpcClient(server.host, server.port)
+    try:
+        gone.call_async("commit", "gone")
+        fut = stays.call_async("commit", "stays")
+        _wait_for(lambda: len(reg.completes) == 2, "two to register")
+        gone.close()
+        _wait_for(lambda: len(server._conns) == 1, "the server to notice")
+        reg.settle()
+        assert fut.result(20) == ("done", "stays")
+        doc = _counted(server, 2)
+    finally:
+        stays.close()
+        server.close()
+    assert doc["deferred_requests"]["commit"] == 2
+    assert doc["deferred_pending"] == 0
+
+
+def test_close_with_deferred_requests_pending_stops_the_reply_thread():
+    reg = _Registrar()
+    server = RpcServer("127.0.0.1", 0, {"commit": reg.handler},
+                       inline_methods={"commit"})
+    client = RpcClient(server.host, server.port)
+    fut = client.call_async("commit", 1)
+    _wait_for(lambda: len(reg.completes) == 1, "one to register")
+    reply_thread = server._reply_thread
+    server.close()
+    assert reply_thread is not None and not reply_thread.is_alive()
+    reg.settle()  # a completion behind the close raises nothing
+    with pytest.raises(Exception):
+        fut.result(20)
+    client.close()
+
+
+def test_deferred_and_inline_requests_interleaved_under_thread_switching():
+    """Four connections × eight threads at a 10 µs switch interval:
+    reads answered on the connections' threads, commits deferred and
+    completed in rows by a settler thread. Every caller gets its own
+    answer; every request is counted once, where it was answered."""
+    import sys
+
+    reg = _Registrar()
+    server = _short_server(True, {"commit": reg.handler})
+    server.inline_methods.add("commit")
+    clients = [RpcClient(server.host, server.port) for _ in range(4)]
+    wrong, per_thread = [], 40
+    stop = threading.Event()
+    interval = sys.getswitchinterval()
+
+    def settler():
+        while not stop.is_set():
+            reg_row = len(reg.completes)
+            if reg_row:
+                reg.settle(reg_row)
+            else:
+                time.sleep(0.0005)
+
+    def work(client, tid):
+        for i in range(per_thread):
+            key = b"%d:%d" % (tid, i)
+            if client.call("storage_get", key, timeout=30) != key:
+                wrong.append(("read", tid, i))
+            if client.call("commit", key, timeout=30) != ("done", key):
+                wrong.append(("commit", tid, i))
+
+    sys.setswitchinterval(1e-5)
+    try:
+        st = threading.Thread(target=settler)
+        st.start()
+        threads = [threading.Thread(target=work, args=(c, 8 * n + t))
+                   for n, c in enumerate(clients) for t in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        total = 32 * per_thread
+        doc = _counted(server, 2 * total)
+    finally:
+        stop.set()
+        st.join(timeout=10)
+        sys.setswitchinterval(interval)
+        for c in clients:
+            c.close()
+        server.close()
+    assert wrong == []
+    assert doc["requests"]["read"] == total
+    assert doc["requests"]["commit"] == total
+    assert doc["inline_requests"]["read"] == total
+    assert doc["inline_requests"]["commit"] == 0
+    assert doc["deferred_requests"]["commit"] == total
+    assert 1 <= doc["deferred_sends"] <= total
+    assert doc["deferred_pending"] == 0
+
+
+# ───────────── the served commit: submitted, deferred, answered in a batch ─────────────
+class _Gate:
+    """Wraps the served cluster's inner ``commit_batch``: a batch stands
+    at the gate until released, then runs as it would have."""
+
+    def __init__(self, cluster):
+        self.inner = cluster.commit_proxy.inner
+        self.orig = self.inner.commit_batch
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        self.sizes = []
+        self.inner.commit_batch = self
+
+    def __call__(self, reqs):
+        self.sizes.append(len(reqs))
+        self.entered.set()
+        assert self.release.wait(60)
+        return self.orig(reqs)
+
+    def open(self):
+        self.release.set()
+        self.inner.commit_batch = self.orig
+
+
+def _blind_set(rv, key, value=b"v"):
+    end = key + b"\\x00"
+    return CommitRequest(rv, [Mutation(Op.SET, key, value)], [],
+                         [(key, end)])
+
+
+def test_forty_commits_from_four_connections_hold_no_pool_worker(remote_db):
+    """Forty commits wait for a held batch: no pool worker is theirs
+    (none queued, a ping and an admin call answered meanwhile), and
+    when the batches settle the replies leave in at most one send a
+    connection a batch."""
+    db, cluster, server = remote_db
+    db[b"seed"] = b"0"  # the first batch, before the gate
+    rv = db.create_transaction().get_read_version()
+    before = server.stats()
+    batches_before = cluster.commit_proxy.batches_committed
+    gate = _Gate(cluster)
+    clients = [RpcClient(server.host, server.port) for _ in range(4)]
+    try:
+        futs = [clients[0].call_async("commit", _blind_set(rv, b"k0.0"))]
+        assert gate.entered.wait(20)  # the batcher stands at the gate
+        futs += [c.call_async("commit", _blind_set(rv, b"k%d.%d" % (n, i)))
+                 for n, c in enumerate(clients) for i in range(10)
+                 if (n, i) != (0, 0)]  # forty in all
+        _wait_for(lambda: server.stats()["deferred_pending"] == 40,
+                  "forty commits submitted")
+        doc = server.stats()
+        assert doc["pool"]["queued"] == 0
+        assert clients[1].call("ping", timeout=20) == "pong"   # inline
+        assert "batch_txn_capacity" in clients[2].call(
+            "knobs", timeout=20)                               # the pool
+        assert not any(f.done() for f in futs)
+        gate.open()
+        versions = [f.result(30) for f in futs]
+        assert all(isinstance(v, int) for v in versions)
+        _wait_for(lambda: server.stats()["deferred_requests"]["commit"]
+                  - before["deferred_requests"]["commit"] == 40,
+                  "forty replies counted")
+        after = server.stats()
+    finally:
+        gate.open()
+        for c in clients:
+            c.close()
+    batches = cluster.commit_proxy.batches_committed - batches_before
+    sends = after["deferred_sends"] - before["deferred_sends"]
+    # the one held, then the thirty-nine that gathered behind it
+    assert gate.sizes[0] == 1 and 2 <= batches <= 40
+    assert 1 <= sends <= 4 * batches
+    assert batches > 2 or sends <= 5  # 1 + 39 over four connections
+    assert after["inline_requests"]["commit"] == 0
+    assert after["deferred_requests"]["commit"] == after["requests"]["commit"]
+    assert after["deferred_pending"] == 0
+    assert db[b"k3.9"] == b"v" and db[b"k0.0"] == b"v"
+
+
+def test_conflict_verdict_rides_the_deferred_reply_as_a_value(remote_db):
+    """1020 is a verdict, not a failure: the wire carries the FDBError
+    as a value, as before the reply was deferred."""
+    db, _, server = remote_db
+    db[b"hot"] = b"0"
+    tr = db.create_transaction()
+    rv = tr.get_read_version()
+    db[b"hot"] = b"1"  # a write behind rv
+    client = RpcClient(server.host, server.port)
+    try:
+        stale = CommitRequest(rv, [Mutation(Op.SET, b"hot", b"2")],
+                              [(b"hot", b"hot\\x00")],
+                              [(b"hot", b"hot\\x00")])
+        verdict = client.call("commit", stale, timeout=20)
+    finally:
+        client.close()
+    assert isinstance(verdict, FDBError) and verdict.code == 1020
+    assert db[b"hot"] == b"1"
+    # and through the client stack: the transaction retries on it
+    assert tr.get(b"hot") == b"0"
+    tr[b"hot"] = b"3"
+    with pytest.raises(FDBError) as ei:
+        tr.commit()
+    assert ei.value.code == 1020
+
+
+def test_client_gone_before_its_batch_settles_fails_no_one_else(remote_db):
+    db, cluster, server = remote_db
+    rv = db.create_transaction().get_read_version()
+    gate = _Gate(cluster)
+    gone = RpcClient(server.host, server.port)
+    stays = RpcClient(server.host, server.port)
+    try:
+        gone.call_async("commit", _blind_set(rv, b"gone"))
+        assert gate.entered.wait(20)
+        fut = stays.call_async("commit", _blind_set(rv, b"stays"))
+        _wait_for(lambda: server.stats()["deferred_pending"] == 2,
+                  "both submitted")
+        gone.close()
+        _wait_for(lambda: len(server._conns) == 2, "the server to notice")
+        gate.open()
+        assert isinstance(fut.result(30), int)
+    finally:
+        gate.open()
+        stays.close()
+    # the commit whose client left is committed all the same
+    assert db[b"gone"] == b"v" and db[b"stays"] == b"v"
+    assert server.stats()["deferred_pending"] == 0
+
+
+def test_deferred_commit_stamps_cover_the_batch_and_no_queue(remote_db):
+    """``handler_wall_us`` of a commit runs from its submit to its
+    settlement (the wait for the batch and the batch); nothing waits in
+    front of the handler."""
+    db, cluster, server = remote_db
+    rv = db.create_transaction().get_read_version()
+    before = server.stats()
+    gate = _Gate(cluster)
+    client = RpcClient(server.host, server.port)
+    held = 0.2
+    try:
+        # four, so that one of them is a timed one whatever came before
+        futs = [client.call_async("commit", _blind_set(rv, b"t%d" % i))
+                for i in range(4)]
+        assert gate.entered.wait(20)
+        _wait_for(lambda: server.stats()["deferred_pending"] == 4,
+                  "four submitted")
+        time.sleep(held)
+        gate.open()
+        assert all(isinstance(f.result(30), int) for f in futs)
+        _wait_for(lambda: server.stats()["requests"]["commit"]
+                  - before["requests"]["commit"] == 4, "four counted")
+        after = server.stats()
+    finally:
+        gate.open()
+        client.close()
+
+    def delta(counter):
+        return after[counter]["commit"] - before[counter]["commit"]
+
+    timed = delta("timed_requests")
+    assert timed >= 1
+    assert delta("handler_wall_us") >= timed * held * 1e6
+    assert delta("queue_wait_us") < timed * held * 1e6 / 4
+    assert delta("reply_us") < timed * held * 1e6
+
+
+def test_sync_pipeline_commit_still_runs_on_the_pool_under_its_lock():
+    """The ``"sync"`` pipeline has no batcher to submit to: ``commit``
+    is not declared, blocks a pool worker under ``_commit_lock`` and is
+    answered by it."""
+    cluster = Cluster(resolver_backend="cpu", **TEST_KNOBS)
+    server = serve_cluster(cluster)
+    rc = RemoteCluster([server.address])
+    try:
+        assert "commit" not in server.inline_methods
+        db = rc.database()
+        db[b"a"] = b"1"
+        assert db[b"a"] == b"1"
+        doc = _counted(server, sum(server.stats()["requests"].values()))
+        assert doc["requests"]["commit"] == 1
+        assert doc["deferred_requests"]["commit"] == 0
+        assert doc["inline_requests"]["commit"] == 0
+        assert doc["deferred_sends"] == 0
+        assert server._reply_thread is None
+    finally:
+        rc.close()
+        server.close()
+        cluster.close()
+
+
+def test_watchdog_settles_a_wedged_served_batch_with_no_thread_waiting(
+        remote_db, monkeypatch):
+    """The inner proxy wedges past a shortened ``watchdog_s``: every
+    deferred commit of the batch is answered 1021 by the reply thread's
+    poll, with no thread blocked in ``result`` (the pool is idle), and
+    the wedged drive's late results change nothing."""
+    from foundationdb_tpu.rpc import transport
+
+    monkeypatch.setattr(transport, "_DEFERRED_POLL_S", 0.02)
+    db, cluster, server = remote_db
+    db[b"seed"] = b"0"  # starts the reply thread at the shortened tick
+    rv = db.create_transaction().get_read_version()
+    proxy = cluster.commit_proxy
+    proxy.watchdog_s = 0.3
+    gate = _Gate(cluster)
+    client = RpcClient(server.host, server.port)
+    try:
+        first = client.call_async("commit", _blind_set(rv, b"w0"))
+        assert gate.entered.wait(20)
+        verdict = first.result(30)  # well inside the 15 s deadline
+        assert isinstance(verdict, FDBError) and verdict.code == 1021
+        assert proxy.stranded_settled == 1
+        doc = server.stats()
+        assert doc["pool"]["queued"] == 0 and doc["deferred_pending"] == 0
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("rpc-handler")
+                    and _blocked_in_result(t)]
+        gate.open()  # the wedged drive finishes: its set loses
+        _wait_for(lambda: db.get(b"w0") == b"v", "the late apply")
+        assert proxy.stranded_settled == 1
+        counted = server.stats()["deferred_requests"]["commit"]
+        time.sleep(0.1)
+        assert server.stats()["deferred_requests"]["commit"] == counted
+    finally:
+        gate.open()
+        client.close()
+
+
+def _blocked_in_result(thread):
+    import sys
+
+    frame = sys._current_frames().get(thread.ident)
+    while frame is not None:
+        if frame.f_code.co_name == "result" \
+                and frame.f_code.co_filename.endswith("batcher.py"):
+            return True
+        frame = frame.f_back
+    return False
