@@ -43,8 +43,10 @@ _SHARED_RE = re.compile(r"#\s*flowlint:\s*shared\(([^)]*)\)")
 # runtime agree by construction)
 _THREADING_CTORS = {"Lock": "lock", "RLock": "rlock",
                     "Condition": "condition"}
+# ``lockdep.counted(<lock>, <stat name>)`` is the lock it wraps, as a
+# Condition is: ``with self._mu_read:`` acquires ``self._mu``
 _LOCKDEP_CTORS = {"lock": "lock", "rlock": "rlock",
-                  "condition": "condition"}
+                  "condition": "condition", "counted": "counted"}
 
 
 def parse_rule_list(text):
@@ -74,7 +76,8 @@ def _lock_ctor(node):
     ``(kind, name_literal_or_None, wrapped_expr_or_None)``; else None.
 
     Recognizes ``threading.Lock/RLock/Condition`` (bare imports too)
-    and the ``lockdep.lock/rlock/condition`` factories.
+    and the ``lockdep.lock/rlock/condition`` factories, and
+    ``lockdep.counted``, which wraps a lock as a Condition does.
     """
     if not isinstance(node, ast.Call):
         return None
@@ -96,6 +99,9 @@ def _lock_ctor(node):
     elif terminal in _LOCKDEP_CTORS and "lockdep" in fn.split("."):
         kind = _LOCKDEP_CTORS[terminal]
         args = list(node.args)
+        if kind == "counted":
+            # no id of its own: the name it is given names its stat
+            return kind, None, args[0] if args else None
         if args and isinstance(args[0], ast.Constant) and \
                 isinstance(args[0].value, str):
             name = args[0].value

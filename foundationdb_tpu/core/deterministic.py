@@ -48,6 +48,7 @@ class DeterminismRegistry:
         self._streams = {}
         self._seed = None  # None = production mode (OS entropy)
         self._clock = time.time
+        self._cpu_clock = time.thread_time
 
     # ── entropy ──
     def rng(self, name):
@@ -100,10 +101,20 @@ class DeterminismRegistry:
         return self._clock()
 
     def set_clock(self, fn):
+        """Inject the clock. The thread CPU clock follows it: under an
+        injected clock CPU time IS that clock (a stage's off-CPU time
+        reads 0) and nothing of the real one reaches a Span event."""
         self._clock = fn
+        self._cpu_clock = fn
+
+    def set_cpu_clock(self, fn):
+        """A CPU clock of its own beside an injected clock (tests that
+        hold a stage to a known wall and a known CPU time)."""
+        self._cpu_clock = fn
 
     def reset_clock(self):
         self._clock = time.time
+        self._cpu_clock = time.thread_time
 
 
 _registry = DeterminismRegistry()
@@ -137,5 +148,17 @@ def now():
     return _registry._clock()
 
 
+def thread_cpu():
+    """The calling thread's CPU seconds, through the same seam (sim: the
+    injected clock itself). ``time.thread_time`` is a real system call
+    on the chip's host, 6 µs: a few reads a resolver DISPATCH, never one
+    on a request's path (utils/span.stage ``cpu=True``)."""
+    return _registry._cpu_clock()
+
+
 def set_clock(fn):
     _registry.set_clock(fn)
+
+
+def set_cpu_clock(fn):
+    _registry.set_cpu_clock(fn)

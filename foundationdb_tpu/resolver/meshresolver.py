@@ -179,7 +179,8 @@ class MeshResolver(Resolver):
         rollup the hash path fills with per-lane walls — in range mode
         the split balance IS the utilization story, and it is known
         before the device ever runs."""
-        with span_mod.stage("resolver.route", self.profile):
+        with span_mod.stage("resolver.route", self.profile,
+                            cpu=self.profile.cpu_sampled):
             sb, k, lane_counts = self._router.split(stacked)
         if deviceprofile.enabled():
             self.profile.record_lane_counts(lane_counts.tolist())

@@ -353,13 +353,15 @@ class Resolver:
         )
         for c in range(0, max(len(live), 1), self.params.txns):
             chunk = live[c : c + self.params.txns]
-            with span_mod.stage("resolver.pack", self.profile):
+            packed = span_mod.stage("resolver.pack", self.profile,
+                                    cpu=self.profile.cpu_turn())
+            with packed:
                 batch = packer.pack(
                     [t for _, t in chunk], self.base_version,
                     commit_version, new_window_start
                 )
             out, step_s = self._step_kernel(resolve_fn, batch, len(chunk),
-                                            commit_version)
+                                            commit_version, packed)
             if deviceprofile.enabled():
                 # each chunk is one device step padded to a full
                 # params.txns batch — the single-batch route's pad waste
@@ -413,15 +415,22 @@ class Resolver:
         """``n`` transactions refused by the host's rule: the mesh counts
         those a re-bound's fence cost."""
 
-    def _step_kernel(self, resolve_fn, batch, n, commit_version):
+    def _step_kernel(self, resolve_fn, batch, n, commit_version, packed):
         """One threaded kernel step → (statuses[:n], its wall seconds:
         the dispatch wall, enqueue + readback), or (None, seconds) when
         the Pallas fallback engaged (the resolver restarted fenced and
-        the caller must answer TOO_OLD)."""
+        the caller must answer TOO_OLD). ``packed`` is the batch's
+        closed ``resolver.pack`` stage."""
         # enqueue: the jitted call returning (H2D + launch); readback:
-        # the device wait + D2H of the verdicts
-        enq = span_mod.stage("resolver.enqueue", self.profile)
-        rdb = span_mod.stage("resolver.readback", self.profile)
+        # the device wait + D2H of the verdicts. Each starts its CPU
+        # split from the closing reading of the stage before it: a read
+        # of the thread's CPU clock is a slow system call on the chip's
+        # host, and nothing but these stages runs between them (and
+        # none does where the pack took none: DeviceProfile.cpu_turn)
+        enq = span_mod.stage("resolver.enqueue", self.profile,
+                             cpu=packed.cpu and packed)
+        rdb = span_mod.stage("resolver.readback", self.profile,
+                             cpu=enq.cpu and enq)
         try:
             with enq:
                 status, _accepted, self.state = resolve_fn(self.state,
@@ -506,11 +515,13 @@ class Resolver:
         packer, resolve_fn = self._fast if use_fast else (
             self.packer, self._resolve
         )
-        with span_mod.stage("resolver.pack", self.profile):
+        packed = span_mod.stage("resolver.pack", self.profile,
+                                cpu=self.profile.cpu_turn())
+        with packed:
             batch = packer.pack_flat(flat, self.base_version,
                                      commit_version, new_window_start)
         out, step_s = self._step_kernel(resolve_fn, batch, len(flat),
-                                        commit_version)
+                                        commit_version, packed)
         if deviceprofile.enabled():
             pp = self._fast_params if use_fast else self.params
             self.profile.record_dispatch(
@@ -728,6 +739,7 @@ class Resolver:
             packed.extend([pad] * (B - len(packed)))
         scan_fn = self._get_scan_fn(use_fast, B)
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *packed)
+        cpu_turn = self.profile.cpu_turn()
         prof = deviceprofile.enabled()
         if prof:
             ent = {"pr": 0, "pw": 0, "rr": 0, "rw": 0}
@@ -761,11 +773,9 @@ class Resolver:
 
         def materialize():
             self._profile_lanes(st)
-            rt0 = deviceprofile.now() if deviceprofile.enabled() else 0.0
-            arr = np.asarray(st)  # the ONE host sync for the backlog
-            if deviceprofile.enabled():
-                self.profile.record_verdict_reduce(
-                    deviceprofile.now() - rt0)
+            with span_mod.stage("resolver.readback", self.profile,
+                                cpu=cpu_turn):
+                arr = np.asarray(st)  # the ONE host sync for the backlog
             out = []
             for b, (statuses, live, cv, ws) in enumerate(per_batch):
                 row = self._plain_statuses(arr[b][: len(live)].tolist())
@@ -815,6 +825,7 @@ class Resolver:
         scan_fn = self._get_scan_fn(use_fast, B)
         import time as _time
 
+        cpu_turn = self.profile.cpu_turn()
         prof = deviceprofile.enabled()
         if prof:
             pp = packer.params
@@ -849,11 +860,9 @@ class Resolver:
 
         def materialize():
             self._profile_lanes(st)
-            rt0 = deviceprofile.now() if deviceprofile.enabled() else 0.0
-            arr = np.asarray(st)  # the ONE host sync for the backlog
-            if deviceprofile.enabled():
-                self.profile.record_verdict_reduce(
-                    deviceprofile.now() - rt0)
+            with span_mod.stage("resolver.readback", self.profile,
+                                cpu=cpu_turn):
+                arr = np.asarray(st)  # the ONE host sync for the backlog
             return [
                 self._plain_statuses(arr[b][: len(f)].tolist())
                 for b, f in enumerate(flats)

@@ -1505,6 +1505,25 @@ class Cluster:
             "aggregate": deviceprofile.merged_snapshot(profs),
         }
 
+    def lock_wait_status(self):
+        """``cluster.locks``: the counted acquisitions
+        (``utils/lockdep.counted``) of the three mutexes a served request
+        or a batch waits at, each summed over its role's live instances
+        here, when status is built: ``acquisitions``, ``blocked`` (those
+        that had to wait) and ``wait_us`` (for how long, in all).
+        Integers, like ``cluster.rpc``. A recruited instance starts at 0."""
+        roles = {
+            "storage_mu_read": (self.storages, "_mu_read"),
+            "storage_mu_apply": (self.storages, "_mu_apply"),
+            "commit_mu": (self._inner_proxies(), "_commit_mu_counted"),
+            "grv_lock": ([self.grv_proxy], "_lock_counted"),
+        }
+        return {
+            name: lockdep.sum_counted(
+                st for st in (getattr(o, attr, None) for o in owners)
+                if st is not None)
+            for name, (owners, attr) in roles.items()}
+
     def health_status(self):
         """The ``cluster.health`` document (``health`` RPC /
         \\xff\\xff/status/health / fdbcli doctor / tools/doctor.py):
@@ -1640,6 +1659,9 @@ class Cluster:
                 # the resolver dispatch layer's pad/bucket/fallback
                 # accounting, cluster-owned like metrics/heatmaps above
                 "device": self.device_profile_status(),
+                # blocked acquisitions and wait of storage's mutex, the
+                # commit mutex and the GRV lock (utils/lockdep.counted)
+                "locks": self.lock_wait_status(),
                 # metrics history (utils/timeseries.py): the retention
                 # layer's full doc — bounded per-metric windows, the
                 # verdict timeline, and the flight-recorder summary —

@@ -103,6 +103,9 @@ class BatchingGrvProxy:
         self.max_wait_s = max_wait_s
         self._lock = lockdep.lock("BatchingGrvProxy._lock")
         self._wake = lockdep.condition("BatchingGrvProxy._lock", self._lock)
+        # the same mutex, counted (cluster.locks.grv_lock), entered by
+        # every request's grant_now and by the grant loop's drain
+        self._lock_counted = lockdep.counted(self._lock, "grv_lock")
         # two queues so a starved batch-priority request cannot head-of-
         # line-block default traffic (ref: per-priority GRV queues)
         self._queues = {"default": [], "batch": []}
@@ -144,7 +147,7 @@ class BatchingGrvProxy:
             # the liveness check must happen here too
             raise err("process_behind")
         if priority == "immediate":
-            with self._lock:  # counter consistency with the grant loop
+            with self._lock_counted:  # consistency with the grant loop
                 return self.inner.get_read_version(priority)  # bypass
         rk = self.inner.ratekeeper
         if rk is not None and tags and not rk.tag_gate(tags):
@@ -159,7 +162,7 @@ class BatchingGrvProxy:
             # committed-version read for the whole round): attribute the
             # start HERE, where the tags are still in hand
             self.inner._note_tag_started(tags)
-        with self._lock:
+        with self._lock_counted:
             if (
                 self._closed
                 or self._pending != 0  # drained-but-unresolved too
@@ -257,7 +260,7 @@ class BatchingGrvProxy:
         simulation (and tests) can drive rounds without the thread or
         wall clock (``now`` overrides the aging clock). Returns whether
         anything was granted."""
-        with self._lock:
+        with self._lock_counted:
             work = {p: list(self._queues[p]) for p in ("default", "batch")}
             self._queues = {"default": [], "batch": []}
         rk = self.inner.ratekeeper

@@ -179,6 +179,10 @@ class CommitProxy:
         # noise; deterministic sims are single-threaded so ordering is
         # unchanged. (Ref: the proxy's commit path is one actor.)
         self._commit_mu = lockdep.rlock("CommitProxy._commit_mu")
+        # the same mutex, counted (cluster.locks.commit_mu), entered
+        # where a batch, a backlog group or its second half takes it
+        self._commit_mu_counted = lockdep.counted(self._commit_mu,
+                                                  "commit_mu")
         self._batches_since_pump = 0
         self.pump_interval = 64  # batches between flush + ratekeeper rounds
         self.resolver_bounds = None  # n-1 split keys; None = static split
@@ -340,7 +344,7 @@ class CommitProxy:
         prior_ctx = span_mod.set_current(rctx) if rctx is not None \
             else None
         try:
-            with self._commit_mu:
+            with self._commit_mu_counted:
                 with span_mod.stage("commit.batch", self.stages,
                                     txns=len(requests)):
                     return self._commit_batch_locked(requests)
@@ -656,7 +660,7 @@ class CommitProxy:
 
     def _commit_batches_outer(self, request_batches):
         try:
-            with self._commit_mu:
+            with self._commit_mu_counted:
                 if getattr(self, "lock_uid", None) is not None:
                     # checked UNDER the mutex: a lock landing while this
                     # backlog queued must fence it exactly as commit_batch
@@ -955,7 +959,7 @@ class CommitProxy:
             ]
         group.resolve_s = _time.perf_counter() - t0
         t1 = _time.perf_counter()
-        with self._commit_mu:
+        with self._commit_mu_counted:
             if not self.alive or not self.sequencer.alive:
                 # killed mid-pipeline (txn-system recovery quiesce):
                 # nothing may reach the log after the frontier read —

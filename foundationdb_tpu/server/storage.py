@@ -139,6 +139,14 @@ class StorageServer(RangeReadInterface):
         # readers hold the same lock (RLock: flush iterates internally).
         # Single-threaded deployments pay one uncontended acquire per op.
         self._mu = lockdep.rlock("StorageServer._mu")
+        # the same mutex, counted (blocked acquisitions and their wait:
+        # cluster.locks.storage_mu_read / _apply), entered at its
+        # outermost hot acquisitions: a served read's first, and apply's
+        self._mu_read = lockdep.counted(self._mu, "storage_mu_read")
+        self._mu_apply = lockdep.counted(self._mu, "storage_mu_apply")
+        # the thread serving a read_batch, while it holds the mutex for
+        # its batch: its point reads take (and count) it no second time
+        self._batch_thread = None
         self.alive = True  # failure detection flips this (sim kill)
         # placement tag (ref: storage locality in DatabaseConfiguration
         # region blocks): the cluster stamps its primary-region id when
@@ -213,7 +221,7 @@ class StorageServer(RangeReadInterface):
         # batch-span context), a storage.apply hop span
         with span_mod.stage("storage.apply", version=version,
                             mutations=len(mutations)) as asp:
-            with self._mu:
+            with self._mu_apply:
                 overlay_get = self._overlay.get
                 overlay = self._overlay
                 dirty_append = self._dirty.append
@@ -376,7 +384,11 @@ class StorageServer(RangeReadInterface):
             self._read_cd -= 1
             if self._read_cd <= 0:
                 self._sample_read(key)
-        with self._mu:
+        if self._batch_thread == threading.get_ident():
+            # read_batch holds the mutex for its whole batch and counted
+            # its one acquisition: every point read still passes here
+            return self._lookup(key, version)
+        with self._mu_read:
             return self._lookup(key, version)
 
     def read_batch(self, ops):
@@ -391,30 +403,36 @@ class StorageServer(RangeReadInterface):
         Returns one slot per op, FDBError slots included (per-key
         errors are NOT batch-fatal — a too-old key fails alone).
         Delegates to the public per-op methods under the held RLock
-        (reentrant), so version checks, read counters, and countdown
+        (``get`` sees it held and takes it no second time; the range and
+        selector methods re-enter it), so version checks, read counters,
+        and countdown
         heat sampling charge EXACTLY as the unbatched path does: one
         decrement per key served, never one per RPC."""
         t0 = metrics_mod.now()
         out = []
-        with self._mu:
-            for op in ops:
-                try:
-                    kind = op[0]
-                    if kind == "g":
-                        out.append(self.get(op[1], op[2]))
-                    elif kind == "r":
-                        out.append([
-                            (k, v) for k, v in self.get_range(
-                                op[1], op[2], op[3],
-                                limit=op[4], reverse=op[5],
-                            )
-                        ])
-                    elif kind == "s":
-                        out.append(self.resolve_selector(op[1], op[2]))
-                    else:
-                        raise err("client_invalid_operation")
-                except FDBError as e:
-                    out.append(e)
+        with self._mu_read:
+            self._batch_thread = threading.get_ident()
+            try:
+                for op in ops:
+                    try:
+                        kind = op[0]
+                        if kind == "g":
+                            out.append(self.get(op[1], op[2]))
+                        elif kind == "r":
+                            out.append([
+                                (k, v) for k, v in self.get_range(
+                                    op[1], op[2], op[3],
+                                    limit=op[4], reverse=op[5],
+                                )
+                            ])
+                        elif kind == "s":
+                            out.append(self.resolve_selector(op[1], op[2]))
+                        else:
+                            raise err("client_invalid_operation")
+                    except FDBError as e:
+                        out.append(e)
+            finally:
+                self._batch_thread = None  # with the mutex still held
         self._m_read_batch.record(max(0.0, metrics_mod.now() - t0))
         # reads-per-RPC histogram: recorded /1e3 so bands_ms()'s ×1e3
         # yields the RAW batch size (p50_ms field == p50 batch size)
@@ -449,7 +467,9 @@ class StorageServer(RangeReadInterface):
             self._read_cd -= 1
             if self._read_cd <= 0:
                 self._sample_read(begin)
-        with self._mu:
+        # the router's range reads enter here first; a range or selector
+        # op of a direct ``read_batch`` re-enters (counted, never blocked)
+        with self._mu_read:
             yield from self._iter_live_locked(begin, end, version, reverse)
 
     def _iter_live_locked(self, begin, end, version, reverse=False):

@@ -35,7 +35,11 @@ The resolver's host stages reach the profile through
 maps ``resolver.pack`` / ``resolver.enqueue`` / ``resolver.readback``
 / ``resolver.route`` / ``resolver.rebucket`` / ``resolver.rebound`` to
 ``pack_wall_ms`` / ``enqueue_wall_ms`` / ``verdict_reduce_wall_ms`` /
-``route_wall_ms`` / ``rebucket_wall_ms`` / ``rebound_wall_ms``.
+``route_wall_ms`` / ``rebucket_wall_ms`` / ``rebound_wall_ms``. The
+dispatching thread's four (pack, enqueue, readback, route) are opened
+with ``cpu=True`` and bring their CPU seconds too: snapshot() splits
+each wall sum into ``<stage>_cpu_ms`` and ``<stage>_offcpu_ms``
+(:data:`STAGE_CPU`).
 """
 
 import os
@@ -69,6 +73,28 @@ STAGE_WALLS = {
     "resolver.rebound": "rebound_wall_s",
 }
 
+# the stages opened with ``cpu=True`` → the sum of the dispatching
+# thread's CPU seconds inside them, as its clock gave them. snapshot()
+# prints each beside the rest of the stage's wall, ``<stage>_offcpu_ms``
+# = max(0, wall − CPU) of the SUMS: the thread's wait for the
+# interpreter (these stages do not sleep) and, in the readback, for the
+# device. Not a stage at a time: the chip's host steps a thread's CPU
+# clock by 10 ms, so one stage reads 0 or 10 ms (utils/span.stage)
+STAGE_CPU = {
+    "resolver.pack": "pack_cpu_s",
+    "resolver.enqueue": "enqueue_cpu_s",
+    "resolver.readback": "readback_cpu_s",
+    "resolver.route": "route_cpu_s",
+}
+CPU_SUMS = tuple(STAGE_CPU.values())
+# one dispatch in CPU_EVERY takes those readings (:meth:`DeviceProfile.
+# cpu_turn`) and each counts CPU_EVERY times: a read of the thread's CPU
+# clock holds the interpreter for about 30 µs under a cell's load on the
+# chip's host, four to six of them a dispatch, and read on every
+# dispatch they cost a cell ≈ 0.2% of its rate for every 10 dispatches
+# a second it makes (PERF.md §6, PR 39: mako, 225 a second, −4.1%)
+CPU_EVERY = 2
+
 # the plain counters (:meth:`DeviceProfile.count`), each summed over
 # dispatches. The mesh router's, fed by ``MeshResolver._split_counted``:
 # the dispatches routed and their slices (k: 1 unless a lane
@@ -97,8 +123,10 @@ PLAIN_COUNTERS = ("route_dispatches", "route_slices", "lane_entries_routed",
                   "bucket_entries_fullest", "rebounds",
                   "rebound_fenced_txns", "range_entries_routed",
                   "range_lane_dups")
-# the stage walls that ride beside them through absorb and snapshot
-PLAIN_WALLS = ("route_wall_s", "rebucket_wall_s", "rebound_wall_s")
+# the stage walls that ride beside them through absorb and snapshot,
+# and with them the four stages' CPU sums
+PLAIN_WALLS = ("route_wall_s", "rebucket_wall_s",
+               "rebound_wall_s") + CPU_SUMS
 
 
 def set_enabled(on):
@@ -172,6 +200,11 @@ class DeviceProfile:
         self.rebucket_wall_s = 0.0
         # stage resolver.rebound: sample → lane bounds → fresh lane state
         self.rebound_wall_s = 0.0
+        for c in CPU_SUMS:
+            setattr(self, c, 0.0)
+        self._cpu_turns = 0
+        # whether the dispatch in hand takes CPU readings (cpu_turn)
+        self.cpu_sampled = False
         for c in PLAIN_COUNTERS:
             setattr(self, c, 0)
         # fallback-cause taxonomy
@@ -284,20 +317,45 @@ class DeviceProfile:
             for c, n in counters.items():
                 setattr(self, c, getattr(self, c) + int(n))
 
-    def record_verdict_reduce(self, wall_s):
-        if not _enabled:
-            return
-        with self._lock:
-            self.verdict_reduce_wall_s += float(wall_s)
+    def cpu_turn(self):
+        """Called by the dispatching thread once a dispatch, before its
+        pack → whether this one's stages read the thread's CPU clock
+        (``stage(..., cpu=True)``): one dispatch in :data:`CPU_EVERY`,
+        none with the profile switched off. Kept in ``cpu_sampled`` for
+        the stages opened deeper in the same dispatch (the mesh's
+        route)."""
+        self._cpu_turns += 1
+        self.cpu_sampled = _enabled and self._cpu_turns % CPU_EVERY == 0
+        return self.cpu_sampled
 
-    def add(self, stage, seconds):
+    def add(self, stage, seconds, cpu_s=None):
         """The ``stats`` sink of ``utils/span.stage``: a resolver host
-        stage's seconds into its wall (:data:`STAGE_WALLS`)."""
+        stage's seconds into its wall (:data:`STAGE_WALLS`) and, from a
+        stage opened with ``cpu=True``, its CPU seconds into the sum
+        beside it (:data:`STAGE_CPU`), :data:`CPU_EVERY` times: it
+        stands for the dispatches that took no reading."""
         if not _enabled:
             return
         wall = STAGE_WALLS[stage]
         with self._lock:
             setattr(self, wall, getattr(self, wall) + float(seconds))
+            if cpu_s is not None:
+                cpu = STAGE_CPU[stage]
+                setattr(self, cpu,
+                        getattr(self, cpu) + CPU_EVERY * float(cpu_s))
+
+    def _cpu_split_ms(self):
+        """``<stage>_cpu_ms`` and ``<stage>_offcpu_ms`` of the four
+        stages: each wall sum as its CPU sum and the rest (never below
+        0). Called with the lock held."""
+        out = {}
+        for stage, cpu in STAGE_CPU.items():
+            on = getattr(self, cpu)
+            off = max(0.0, getattr(self, STAGE_WALLS[stage]) - on)
+            name = cpu[:-len("_cpu_s")]  # pack_cpu_s -> pack
+            out[name + "_cpu_ms"] = round(on * 1e3, 3)
+            out[name + "_offcpu_ms"] = round(off * 1e3, 3)
+        return out
 
     # ── carryover + rollup ──
 
@@ -434,6 +492,7 @@ class DeviceProfile:
                 "route_wall_ms": round(self.route_wall_s * 1e3, 3),
                 "rebucket_wall_ms": round(self.rebucket_wall_s * 1e3, 3),
                 "rebound_wall_ms": round(self.rebound_wall_s * 1e3, 3),
+                **self._cpu_split_ms(),
                 **{c: getattr(self, c) for c in PLAIN_COUNTERS},
                 "fallback_causes": dict(sorted(
                     self.fallback_causes.items())),

@@ -40,7 +40,7 @@ import os
 import threading
 
 __all__ = [
-    "lock", "rlock", "condition", "enable", "disable", "enabled",
+    "lock", "rlock", "condition", "counted", "enable", "disable", "enabled",
     "reset", "edge_set", "cycle_count", "cycles", "witness_doc",
     "acquisition_count",
 ]
@@ -270,6 +270,83 @@ def condition(name, lock=None):
     if lock is None:
         lock = _DepLock(threading.Lock(), name)
     return threading.Condition(lock)
+
+
+class counted:
+    """``with counted_mu:`` is ``with mu:``, counted: how often the
+    acquisition had to wait, and for how long. One per (lock, stat); the
+    owner makes it beside the lock it wraps and enters it at that lock's
+    OUTERMOST hot acquisitions (an RLock's re-entries inside stay plain
+    ``with mu:``: they can never wait).
+
+    The lock is tried first (``acquire(False)``: through
+    :class:`_DepLock` when the witness is on, so it sees every
+    acquisition). Only where that fails is the injected clock read, the
+    lock waited for, and the wait added to ``blocked`` and ``wait_s`` —
+    and, in a process with a profiler annotator (utils/span.py), shown
+    as ``fdb.lock.<name>`` on the host plane. An uncontended
+    acquisition reads no clock. The three numbers are written while
+    HOLDING the lock they describe: no lock of their own, no new edge.
+    A torn read by :meth:`snapshot` is at worst one acquisition stale.
+
+    The static model (analysis/model.py) aliases the attribute to the
+    wrapped lock, as it does a Condition: FL006 sees ``with
+    self._mu_read:`` as an acquisition of ``StorageServer._mu``."""
+
+    __slots__ = ("_acquire", "_release", "_label", "acquisitions",
+                 "blocked", "wait_s")
+
+    def __init__(self, mu, name):
+        # bound once: this is entered on every served request
+        self._acquire = mu.acquire
+        self._release = mu.release
+        self._label = "lock." + name
+        self.acquisitions = 0
+        self.blocked = 0
+        self.wait_s = 0.0
+
+    def __enter__(self):
+        if not self._acquire(False):
+            sp = _span_mod or _bind_span()
+            with sp.annotation(self._label):
+                t0 = sp.now()
+                self._acquire()
+                self.wait_s += max(0.0, sp.now() - t0)
+            self.blocked += 1
+        self.acquisitions += 1
+        return self
+
+    def __exit__(self, t, v, tb):
+        self._release()
+        return False
+
+    def snapshot(self):
+        """Integers, as ``cluster.rpc.*``'s."""
+        return {"acquisitions": self.acquisitions,
+                "blocked": self.blocked,
+                "wait_us": round(self.wait_s * 1e6)}
+
+
+# utils/span.py, bound at the first blocked acquisition: it imports
+# core/deterministic.py, which makes its own lock through this module
+_span_mod = None
+
+
+def _bind_span():
+    global _span_mod
+    from foundationdb_tpu.utils import span
+
+    _span_mod = span
+    return span
+
+
+def sum_counted(stats):
+    """One ``snapshot`` over a role's instances (status, when built)."""
+    out = {"acquisitions": 0, "blocked": 0, "wait_us": 0}
+    for st in stats:
+        for k, v in st.snapshot().items():
+            out[k] += v
+    return out
 
 
 def acquisition_count():

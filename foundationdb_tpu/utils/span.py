@@ -59,6 +59,9 @@ _ID_STREAM = "span-id"
 _SAMPLE_STREAM = "span-sample"
 
 now = deterministic.now  # the injected clock every span stamp uses
+# the calling thread's CPU clock, through the same seam: a stage that
+# asks for it (``cpu=True``) reads it beside its two stamps
+thread_cpu = deterministic.thread_cpu
 
 # process-wide gauges (GIL-atomic ints, the metrics Counter idiom):
 # sampled = root transaction spans that will emit (drawn or promoted),
@@ -383,16 +386,38 @@ class stage:
     With no sampled context and no annotator the cost is two clock
     reads and one locked add. After exit ``t0``/``t1``/``seconds`` hold
     the stamps, for a caller that feeds its own band or counter from
-    the same interval (tlog_push, storage_apply, the rpc counters)."""
+    the same interval (tlog_push, storage_apply, the rpc counters).
 
-    __slots__ = ("name", "stats", "attrs", "t0", "t1", "_span", "_prior",
-                 "_ann")
+    ``cpu=True`` also takes the calling thread's CPU clock
+    (``deterministic.thread_cpu``) just inside the two stamps and hands
+    ``stats.add`` a third number, the CPU seconds the clock showed
+    between them; summed over many stages, what is left of their wall
+    is time the thread stood off the CPU: for a stage that does not
+    sleep, its wait for the interpreter (in ``resolver.readback`` also
+    for the device). The reading is handed on as the clock gave it and
+    never cut to this stage's wall: on the chip's host the thread clock
+    moves in steps of 10 ms (a tick counts for the thread if it finds
+    it running), so one stage reads 0 or 10 ms and only a sum over many
+    means anything; cut to the wall, the sum of 1 ms stages read a
+    tenth of the truth there. The span carries ``cpu_ms``. ``cpu`` may
+    also be the stage that closed just before this one opens: this one
+    then starts from that one's closing reading and takes no opening
+    one of its own (pack, enqueue, readback: four reads a dispatch, not
+    six). A read is a system call that holds the interpreter, 6 µs on
+    an idle host of the chip and about 30 under a cell's load (pack
+    walls rose 0.06 ms with two inside): for the dispatching thread's
+    four resolver stages only, on one dispatch in two
+    (``DeviceProfile.cpu_turn``), never a request's path."""
 
-    def __init__(self, name, stats=None, **attrs):
+    __slots__ = ("name", "stats", "attrs", "t0", "t1", "cpu", "cpu_seconds",
+                 "_c0", "_c1", "_span", "_prior", "_ann")
+
+    def __init__(self, name, stats=None, cpu=False, **attrs):
         self.name = name
         self.stats = stats
+        self.cpu = cpu
         self.attrs = attrs
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t1 = self.cpu_seconds = 0.0
 
     @property
     def seconds(self):
@@ -416,21 +441,36 @@ class stage:
             _tls.ctx = sp.context()
         else:
             self._span = None
+        cpu = self.cpu
+        if cpu:
+            self._c0 = thread_cpu() if cpu is True else cpu._c1
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        cpu = self.cpu
+        if cpu:
+            self._c1 = c1 = thread_cpu()
         self.t1 = t1 = now()
+        wall = max(0.0, t1 - self.t0)
+        if cpu:
+            self.cpu_seconds = max(0.0, c1 - self._c0)
         sp = self._span
         if sp is not None:
             _tls.ctx = self._prior
             if exc is not None:
                 sp.attr(error=str(exc)[:200])
+            if cpu:
+                sp.attr(cpu_ms=round(self.cpu_seconds * 1e3, 3))
             sp.finish(end=t1)
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         if self.stats is not None and metrics_mod.enabled():
-            # flowlint: calls(StageStats.add, DeviceProfile.add)
-            self.stats.add(self.name, max(0.0, t1 - self.t0))
+            if cpu:
+                # flowlint: calls(DeviceProfile.add)
+                self.stats.add(self.name, wall, self.cpu_seconds)
+            else:
+                # flowlint: calls(StageStats.add, DeviceProfile.add)
+                self.stats.add(self.name, wall)
         return False
 
     def attr(self, **kw):

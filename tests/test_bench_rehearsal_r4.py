@@ -13,6 +13,14 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 CELL = "rehearsal.ycsb.c4.r4"
+# PR 39's metrics of a four-lane cell beside ``mesh.route_{cpu,offcpu}_ms``:
+# a stage's wall split by the dispatching thread's CPU clock, and the
+# counted acquisitions of the three mutexes
+SPLIT = {"resolver.pack": "pack_wall_ms", "resolver.enqueue":
+         "enqueue_wall_ms", "resolver.readback": "verdict_reduce_wall_ms"}
+LOCKS = ("storage.mu", "proxy.commit_mu", "grv.lock")
+WAITS = ([f"{s}_{k}_ms" for s in SPLIT for k in ("cpu", "offcpu")]
+         + [f"{m}_{k}" for m in LOCKS for k in ("wait_ms", "blocked_pct")])
 
 
 def read_json(path):
@@ -42,7 +50,7 @@ def four_lane_bench(tmp_path):
         "name": CELL, "config": "rehearsal_ycsb_r4",
         "traffic": "rehearsal.ycsb.c4", "chips": 4, "why": "rehearsal"})
     for m in read_json(os.path.join(ROOT, "BENCHMARK.json"))["per_layer"]:
-        if m["name"].startswith("mesh"):
+        if m["name"].startswith("mesh") or m["name"] in WAITS:
             bench["per_layer"].append({**m, "workloads": [CELL]})
     path = tmp_path / "cells.json"
     path.write_text(json.dumps(bench))
@@ -86,6 +94,16 @@ def test_the_four_lane_cell_rehearses_correct_with_the_routers_metrics(
     assert "mesh.range_dup_pct" not in metrics
     # a CPU has no device plane: the trace's metrics stay out of the line
     assert "mesh_step.device_ms" not in metrics
+    # fourteen numbers a four-lane cell: off-CPU is what the CPU sum
+    # (one dispatch in two read, counted twice) leaves of the route's
+    # wall, so the two are its parts; and every lock reads
+    assert set(WAITS) <= set(metrics)
+    route, on, off = (metrics[m]["value"] for m in (
+        "mesh.route_ms", "mesh.route_cpu_ms", "mesh.route_offcpu_ms"))
+    assert on > 0 and abs(off - max(0.0, route - on)) <= 0.02 * route
+    for m in LOCKS:
+        assert metrics[m + "_wait_ms"]["value"] >= 0
+        assert 0 <= metrics[m + "_blocked_pct"]["value"] <= 100
 
 
 def test_a_cell_whose_server_sees_fewer_devices_than_chips_is_refused(

@@ -63,6 +63,15 @@ def test_the_four_lane_range_cell_rehearses_correct_with_its_metrics():
     # (tests/test_rpc.py holds the counters they read)
     untwinned = {"rpc.commit.deferred_share_pct",
                  "rpc.deferred_replies_per_send"}
+    # so do PR 39's fourteen, the stages' CPU split and the lock waits
+    # (tests/test_bench_rehearsal_r4.py rehearses them on four lanes)
+    untwinned |= {
+        f"{stage}_{part}_ms" for stage in (
+            "resolver.pack", "resolver.enqueue", "resolver.readback",
+            "mesh.route") for part in ("cpu", "offcpu")} | {
+        f"{lock}_{what}" for lock in (
+            "storage.mu", "proxy.commit_mu", "grv.lock")
+        for what in ("wait_ms", "blocked_pct")}
     assert untwinned < mine
     assert set(metrics) == mine - traced - untwinned, \
         sorted((mine - untwinned) ^ set(metrics))

@@ -82,7 +82,8 @@ def test_kill_switch_gates_recording_but_not_absorb():
         p.record_fallback("flat_to_legacy")
         p.record_staging(hit=True)
         p.record_lanes([0.1])
-        p.record_verdict_reduce(0.5)
+        p.add("resolver.readback", 0.5)
+        assert p.snapshot()["verdict_reduce_wall_ms"] == 0.0
         assert p.snapshot()["dispatches"] == 0
         assert p.snapshot()["recompiles"] == 0
         # absorb BYPASSES the switch: carried history is not overhead
