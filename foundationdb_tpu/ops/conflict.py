@@ -1174,12 +1174,15 @@ def unpack_args(buf, layout):
 
 
 class PackedProgram:
-    """A jitted ``(state, buf, layout)`` resolve program behind the call
-    its callers make, ``(state, batch)``: the batch goes to the device
-    as the one array ``pack_args`` builds, whatever its fields."""
+    """A resolve program ``fn(state, buf, layout)``, jitted with the
+    layout static and the state donated, behind the call its callers
+    make, ``(state, batch)``: the batch goes to the device as the one
+    array ``pack_args`` builds, whatever its fields. The XLA module
+    keeps ``fn``'s name: the benchmark finds a program by it."""
 
-    def __init__(self, jitted, lanes=0):
-        self.jitted = jitted
+    def __init__(self, fn, donate, lanes=0):
+        self.jitted = jax.jit(fn, static_argnums=2,
+                              donate_argnums=(0,) if donate else ())
         self.lanes = lanes
 
     def __call__(self, state, batch):
@@ -1246,24 +1249,29 @@ def _fold_fn(params, out_shardings):
     return jax.jit(fold_coarse_state, donate_argnums=(0,), **placed)
 
 
-def make_resolve_fn(params: ResolverParams, donate=True):
-    """jit-compiled resolver step with the history buffers donated.
+def _packed_step(params):
+    """``resolve_batch`` on one packed row, ``(state, row, layout)``."""
+    return lambda state, row, layout: resolve_batch(
+        state, unpack_args(row, layout), params)
 
-    It takes the batch's fields as they are. One device pays 0.04 ms an
-    argument; handed the batch as one packed array the served step gave
-    no more operations a second and the read tail grew by a third: the
-    dispatching thread waits at this call for the request threads' turn
-    at the interpreter, not for the arguments (PERF.md §6, PR 33). The
-    mesh programs, where an argument costs 0.55 ms, take it packed
-    (parallel/mesh.py, :class:`PackedProgram`)."""
+
+def make_resolve_fn(params: ResolverParams, donate=True):
+    """jit-compiled resolver step with the history buffers donated,
+    behind :class:`PackedProgram`: called ``(state, batch)``, it hands
+    the device the state and ONE host array, as every mesh program does
+    (parallel/mesh.py). The jitted call costs the dispatching thread
+    work for every host array it is handed, nobody contending: 22
+    fields 1.48 ms, one array 0.62 with its pack, on the chip's idle
+    host, and under ``CommitProxy._commit_mu`` that work is the batch
+    every commit waits behind (PERF.md §6, PR 40)."""
     validate_params(params)
-    fn = lambda state, batch: resolve_batch(state, batch, params)
+    fn = _packed_step(params)
     if params.range_reads or params.range_writes:
         # the step with range lanes has a name of its own in a trace
         # (``jit_resolve_full``); the point-only step keeps the lambda's
         # (``jit__lambda``), which the benchmark's older cells read
         fn.__name__ = "resolve_full"
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return PackedProgram(fn, donate)
 
 
 def scan_of(step_fn):
@@ -1279,6 +1287,16 @@ def scan_of(step_fn):
             return s2, status
 
         return jax.lax.scan(body, state, batches)
+
+    return scan_step
+
+
+def packed_scan_of(step):
+    """:func:`scan_of` for ``step(state, row, layout)`` over a stack of
+    packed rows: an iteration unpacks its own row."""
+
+    def scan_step(state, rows, layout):
+        return scan_of(functools.partial(step, layout=layout))(state, rows)
 
     return scan_step
 
@@ -1305,8 +1323,7 @@ def make_resolve_scan_fn(params: ResolverParams, donate=True):
     """
     validate_params(params)
     params = params._replace(use_pallas=False)
-    scan_step = scan_of(lambda s, b: resolve_batch(s, b, params))
-    return jax.jit(scan_step, donate_argnums=(0,) if donate else ())
+    return PackedProgram(packed_scan_of(_packed_step(params)), donate)
 
 
 def count_retraces(fn, on_retrace, gate=None):

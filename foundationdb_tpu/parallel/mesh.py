@@ -100,16 +100,14 @@ class ShardedResolverKernel:
         a lane's view of one packed batch (``ck.pack_args``): the step
         over ``P(*row_spec)`` and its scan over a stack of rows, each
         ``jit(shard_map(...))`` with the layout static, behind
-        ``ck.PackedProgram``. A mesh program takes ONE host array
-        because sharding a host array over n devices costs per
-        argument, contended or not (0.55 ms each over four chips:
-        PERF.md §6, PR 33). The XLA modules keep the names ``step`` and
-        ``scan_step`` give them: the benchmark finds them by name."""
+        ``ck.PackedProgram``. Every resolve program takes ONE host
+        array, the one device's too (``ck.make_resolve_fn``): the
+        jitted call costs the dispatching thread work for each argument,
+        contended or not, 0.04 ms on one device and, where the array is
+        sharded over four chips, 0.55 ms (PERF.md §6, PR 33, PR 40).
+        The XLA modules keep the names ``step`` and ``scan_step`` give
+        them: the benchmark finds them by name."""
         state_specs = _state_specs(self.spec_axes)
-
-        def scan_step(state, rows, layout):
-            return ck.scan_of(functools.partial(step, layout=layout))(
-                state, rows)
 
         def program(body, buf_spec, out_specs):
             @functools.wraps(body)
@@ -120,13 +118,11 @@ class ShardedResolverKernel:
                     check_vma=False,
                 )(state, buf)
 
-            return ck.PackedProgram(
-                jax.jit(sharded, static_argnums=2,
-                        donate_argnums=(0,) if donate else ()),
-                lanes=lanes)
+            return ck.PackedProgram(sharded, donate, lanes=lanes)
 
         return (program(step, P(*row_spec), (P(), P(), state_specs)),
-                program(scan_step, P(None, *row_spec), (state_specs, P())))
+                program(ck.packed_scan_of(step), P(None, *row_spec),
+                        (state_specs, P())))
 
     def init_state(self):
         p, n = self.params, self.n

@@ -278,10 +278,10 @@ class MeshResolver(Resolver):
         kernel = self._fast_kernel if use_fast else self._kernel
         return kernel._scan_step
 
-    def _h2d_args(self, batch):
-        """One: every mesh program takes its batch as the single array
-        ``ops/conflict.pack_args`` builds (parallel/mesh.py)."""
-        return 1
+    def _offer_interpreter(self, n):
+        """Nothing to add on a mesh: its programs took one array in
+        PR 33 and its cells' tails were measured as they are, and a
+        dispatch's router gives the lock up at every large numpy call."""
 
     def _profile_lanes(self, statuses):
         """Per-lane dispatch wall for one mesh dispatch (ROADMAP item

@@ -11,6 +11,8 @@ and T statuses out). ``"cpu"`` runs the exact host ConflictSet
 (resolver/skiplist.py; later a C++ twin via native/).
 """
 
+import time
+
 import jax
 import numpy as np
 
@@ -421,8 +423,9 @@ class Resolver:
         the Pallas fallback engaged (the resolver restarted fenced and
         the caller must answer TOO_OLD). ``packed`` is the batch's
         closed ``resolver.pack`` stage."""
-        # enqueue: the jitted call returning (H2D + launch); readback:
-        # the device wait + D2H of the verdicts. Each starts its CPU
+        # enqueue: the jitted call returning (H2D + launch) and the
+        # offers of the interpreter behind it; readback: the device
+        # wait + D2H of the verdicts. Each starts its CPU
         # split from the closing reading of the stage before it: a read
         # of the thread's CPU clock is a slow system call on the chip's
         # host, and nothing but these stages runs between them (and
@@ -435,6 +438,7 @@ class Resolver:
             with enq:
                 status, _accepted, self.state = resolve_fn(self.state,
                                                            batch)
+                self._offer_interpreter(n)
             self.profile.count(h2d_args=self._h2d_args(batch))
             # materialize INSIDE the try: dispatch is async, so a
             # kernel that compiles but faults at runtime only raises
@@ -561,11 +565,26 @@ class Resolver:
             return "over_capacity"
         return "flat_to_legacy"  # limb-width mismatch
 
+    def _offer_interpreter(self, n):
+        """Give the interpreter lock up and take it again, once for each
+        of the ``n`` transactions of the batch just launched, while the
+        device runs the step. Each of them was answered at the last
+        settle and has sent its next request since (a reply provokes a
+        request), and those requests are what stands in line. Until
+        PR 40 the jitted call gave the lock up once for each of the 22
+        host arrays it was handed, and the request threads were served
+        in those gaps; handed one array and offering nothing, the step
+        left ycsb_a's reads a p95 of 9.4–10.7 ms for 6.0 and its updates
+        a median of 30.8–32.1 ms for 26.5 (PERF.md §6, PR 40). Where
+        nobody stands in line an offer is one system call."""
+        for _ in range(n):
+            time.sleep(0)
+
     def _h2d_args(self, batch):
-        """Host arrays one dispatch hands its jitted program: the one
-        device's step takes the batch's fields as they are (MeshResolver
-        packs them into one: PERF.md §6, PR 33)."""
-        return len(batch)
+        """Host arrays one dispatch hands its jitted program: one, the
+        array ``ops/conflict.pack_args`` builds of the batch, on one
+        device as on a mesh (``ck.PackedProgram``; PERF.md §6, PR 40)."""
+        return 1
 
     def _profile_lanes(self, statuses):
         """Per-lane dispatch-wall capture hook, called host-side at

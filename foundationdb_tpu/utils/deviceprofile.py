@@ -101,8 +101,8 @@ CPU_EVERY = 2
 # overflowed), the entries routed and, of them, those of the dispatch's
 # fullest lane. ``h2d_args``, counted by the resolver beside each
 # jitted call: the host arrays the dispatch handed its program (the
-# state is on the device already) — the batch's 22 fields on one
-# device, one array on a mesh (``ops/conflict.pack_args``). The coarse
+# state is on the device already) — one array a dispatch, on one device
+# as on a mesh (``ops/conflict.pack_args``). The coarse
 # buckets' (resolver/packing.py ``CoarseBuckets``): ``rebuckets``, the
 # times ``Resolver._maybe_rebucket`` cut new boundaries and folded the
 # device's summaries; ``conflicts_coarse_only``, transactions refused

@@ -1,23 +1,28 @@
-"""One argument a mesh dispatch (ops/conflict.py ``pack_args`` /
-``unpack_args`` / ``PackedProgram``): a mesh resolve program takes its
-batch as ONE uint32 array. What goes in comes out, field for field
-(a ``ResolveBatch``, a ``ShardBatch``, with a batch axis ahead); the
-programs' verdicts and state are those of ``resolve_batch`` /
-``resolve_batch_presharded`` called on the fields; every lowered mesh
-program has one parameter beside the state's; and a served commit
-counts the host arrays it handed over (``h2d_args``).
+"""One argument a dispatch (ops/conflict.py ``pack_args`` /
+``unpack_args`` / ``PackedProgram``): every resolve program, one
+device's or a mesh's, takes its batch as ONE uint32 array. What goes in
+comes out, field for field (a ``ResolveBatch``, a ``ShardBatch``, with a
+batch axis ahead); the programs' verdicts and state are those of
+``resolve_batch`` / ``resolve_batch_presharded`` called on the fields;
+every lowered program has one parameter beside the state's and the name
+the benchmark's trace metrics find it by; and a served commit counts the
+host arrays it handed over (``h2d_args``).
 """
 
 import functools
 import re
+import types
 
 import jax
 import numpy as np
 import pytest
 
 import foundationdb_tpu as fdb
+from foundationdb_tpu.core.options import Knobs
 from foundationdb_tpu.ops import conflict as ck
 from foundationdb_tpu.parallel import mesh as pm
+from foundationdb_tpu.resolver import resolver as resolver_mod
+from foundationdb_tpu.resolver.meshresolver import MeshResolver
 from foundationdb_tpu.resolver.packing import BatchPacker, ShardRouter
 from foundationdb_tpu.resolver.resolver import fast_params_of
 from foundationdb_tpu.resolver.skiplist import TxnRequest
@@ -213,7 +218,36 @@ def test_the_packed_four_lane_step_is_the_presharded_step_on_the_fields():
     _assert_same_run(kern._step, fieldwise, kern.init_state, routed())
 
 
-# ── one parameter beside the state's ────────────────────────────────
+@pytest.mark.parametrize("variant", ["full", "fast"])
+def test_the_packed_one_device_step_is_resolve_batch_on_the_fields(variant):
+    p = PARAMS if variant == "full" else fast_params_of(PARAMS)
+    fieldwise = jax.jit(functools.partial(ck.resolve_batch, params=p))
+    _assert_same_run(ck.make_resolve_fn(p, donate=False), fieldwise,
+                     lambda: ck.init_state(p),
+                     _batches(p, 33, ranges=variant == "full"))
+
+
+@pytest.mark.parametrize("variant", ["full", "fast"])
+def test_the_packed_one_device_scan_is_resolve_batch_batch_by_batch(variant):
+    """Lead axis ``(B,)``: the scan over a stack of packed rows gives
+    every batch the verdicts, and leaves the state, that the step on
+    the fields gives them one after another."""
+    p = PARAMS if variant == "full" else fast_params_of(PARAMS)
+    batches = list(_batches(p, 34, ranges=variant == "full"))
+    stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
+    state, statuses = ck.make_resolve_scan_fn(p, donate=False)(
+        ck.init_state(p), stacked)
+    fieldwise = jax.jit(functools.partial(ck.resolve_batch, params=p))
+    want = ck.init_state(p)
+    for b, batch in enumerate(batches):
+        status, _, want = fieldwise(want, batch)
+        assert np.array_equal(np.asarray(statuses[b]), np.asarray(status)), b
+    assert len(set(np.asarray(statuses).ravel().tolist())) > 1
+    for name, x, y in zip(state._fields, state, want):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), name
+
+
+# ── one parameter beside the state's, under the name it had ─────────
 def _programs():
     stacked = lambda b: jax.tree.map(lambda a: np.stack([a, a]), b)
     empty = BatchPacker(PARAMS).pack_empty(0, 1, 0)
@@ -228,15 +262,27 @@ def _programs():
         jax.tree.map(lambda a: a[0], sb)
     yield "range_scan", ranged._scan_step, \
         jax.eval_shape(ranged.init_state), sb
+    # one device: the three modules benchmark/metrics/resolve_step*,
+    # range_step* and scan.resolve_step* read by name
+    fast = fast_params_of(PARAMS)
+    one = jax.eval_shape(lambda: ck.init_state(PARAMS))
+    yield "jit__lambda", ck.make_resolve_fn(fast), one, \
+        BatchPacker(fast).pack_empty(0, 1, 0)
+    yield "jit_resolve_full", ck.make_resolve_fn(PARAMS), one, empty
+    yield "jit_scan_step", ck.make_resolve_scan_fn(PARAMS), one, \
+        stacked(empty)
 
 
 @pytest.mark.parametrize("program", [
-    "hash_step", "hash_scan", "range_step", "range_scan"])
-def test_a_lowered_mesh_program_takes_the_state_and_one_array(program):
+    "hash_step", "hash_scan", "range_step", "range_scan",
+    "jit__lambda", "jit_resolve_full", "jit_scan_step"])
+def test_a_lowered_program_takes_the_state_and_one_array(program):
     fn, state, batch = next(
         (f, s, b) for name, f, s, b in _programs() if name == program)
     assert isinstance(fn, ck.PackedProgram)
     text = fn.lower(state, batch).as_text()
+    if program.startswith("jit_"):
+        assert text.startswith(f"module @{program} ")
     main = re.search(r"func\.func public @main\((.*?)\) ->", text, re.S)
     params = re.findall(r"%arg\d+: tensor<([^>]*)>", main.group(1))
     assert len(params) == len(jax.tree.leaves(state)) + 1
@@ -244,6 +290,25 @@ def test_a_lowered_mesh_program_takes_the_state_and_one_array(program):
     assert params[-1].startswith(
         "x".join(map(str, batch.rv.shape[:-1] + (
             (fn.lanes,) if fn.lanes else ()) + (words,))) + "xui32")
+
+
+# ── what the 22 arguments did beside carrying the batch ─────────────
+@pytest.mark.parametrize("lanes", [1, LANES])
+def test_a_one_lane_dispatch_offers_the_interpreter_once_a_transaction(
+        monkeypatch, lanes):
+    """The 22-array call gave the interpreter lock up 22 times; the
+    one-array call of one device offers it instead, once a transaction
+    of the batch, after the launch. A mesh dispatch never did."""
+    knobs = Knobs(resolver_backend="tpu", **TEST_KNOBS)
+    r = (resolver_mod.Resolver(knobs) if lanes == 1
+         else MeshResolver(knobs, n_lanes=lanes))
+    offers = []
+    monkeypatch.setattr(resolver_mod, "time",
+                        types.SimpleNamespace(sleep=offers.append))
+    txns = [TxnRequest(read_version=5, point_reads=[b"a%d" % i],
+                       point_writes=[b"b%d" % i]) for i in range(5)]
+    assert r.resolve(txns, 10, 0) == [ck.COMMITTED] * 5
+    assert offers == ([0] * 5 if lanes == 1 else [])
 
 
 # ── the counter that says so ────────────────────────────────────────
@@ -254,8 +319,7 @@ def _increment(tr):
 
 @pytest.mark.parametrize("resolvers", [1, LANES])
 def test_a_served_commit_counts_the_host_arrays_it_handed_over(resolvers):
-    """One array a mesh dispatch; the one-device step still takes the
-    batch's 22 fields (PERF.md §6, PR 33: why)."""
+    """One array a dispatch, on one device as on a mesh."""
     cluster = Cluster(resolver_backend="tpu", commit_pipeline="thread",
                       n_resolvers=resolvers, **TEST_KNOBS)
     server = serve_cluster(cluster)
@@ -269,6 +333,5 @@ def test_a_served_commit_counts_the_host_arrays_it_handed_over(resolvers):
         server.close()
         cluster.close()
     assert agg["dispatches"] >= 4
-    per_dispatch = 1 if resolvers > 1 else len(ck.ResolveBatch._fields)
-    assert agg["h2d_args"] == per_dispatch * agg["dispatches"]
+    assert agg["h2d_args"] == agg["dispatches"]
     assert (agg["route_dispatches"] > 0) == (resolvers > 1)
