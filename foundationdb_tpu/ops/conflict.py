@@ -88,6 +88,13 @@ The full step also says which refusals only a coarse lane raised
 matrix found nothing): an upper bound on the false conflicts the
 summaries cost. The host counts them and answers CONFLICT.
 
+Every step also says which refusals only the batch itself raised
+(``CONFLICT_BATCH``: admissible against the history and the window,
+``a0``, and not accepted at the fixpoint): a smaller batch might have
+committed them, a larger one refuses more. The two sets never meet (a
+coarse-only refusal is a history hit, so not in ``a0``); the host counts
+both and answers CONFLICT.
+
 Versions are uint32 offsets from a host-held base (core/versions.py);
 version 0 means "no write recorded".
 """
@@ -230,7 +237,7 @@ class ShardBatch(NamedTuple):
 
 
 from foundationdb_tpu.core.status import (  # noqa: E402
-    COMMITTED, CONFLICT, CONFLICT_COARSE, TOO_OLD)
+    COMMITTED, CONFLICT, CONFLICT_BATCH, CONFLICT_COARSE, TOO_OLD)
 
 
 def has_coarse_lanes(params: ResolverParams):
@@ -348,6 +355,15 @@ def _overlap_matrix(T, pw, pr, rw, rr):
         m = rw_ok()[:, :, None, None] & rr_mask[None, None]
         O |= jnp.any(ov & m, axis=(1, 3))
     return O
+
+
+def _verdicts(too_old, a0, accepted):
+    """A step's statuses before the coarse mark: TOO_OLD, COMMITTED, or
+    a refusal, CONFLICT_BATCH where only an earlier accepted transaction
+    of this batch stood in the way (``a0``: admissible against the
+    history and the window) and CONFLICT where the history did."""
+    return jnp.where(too_old, TOO_OLD, jnp.where(
+        accepted, COMMITTED, jnp.where(a0, CONFLICT_BATCH, CONFLICT)))
 
 
 def _mark_coarse_only(status, accepted, Of, coarse_hist, axis_name):
@@ -628,7 +644,7 @@ def resolve_batch(
 
     accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
 
-    status = jnp.where(too_old, TOO_OLD, jnp.where(accepted, COMMITTED, CONFLICT))
+    status = _verdicts(too_old, a0, accepted)
     if coarse_lanes:
         status = _mark_coarse_only(
             status, accepted, Of, hist & ~exact & ~too_old, axis_name)
@@ -968,7 +984,7 @@ def resolve_batch_presharded(
 
     accepted, _ = jax.lax.while_loop(cond, body, (a0, jnp.array(True)))
 
-    status = jnp.where(too_old, TOO_OLD, jnp.where(accepted, COMMITTED, CONFLICT))
+    status = _verdicts(too_old, a0, accepted)
     if has_coarse_lanes(params):
         status = _mark_coarse_only(
             status, accepted, Of, hist & ~exact & ~too_old, axis_name)

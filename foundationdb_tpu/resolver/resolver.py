@@ -26,7 +26,7 @@ from foundationdb_tpu.utils import metrics as metrics_mod
 from foundationdb_tpu.utils import span as span_mod
 
 COMMITTED, CONFLICT, TOO_OLD = ck.COMMITTED, ck.CONFLICT, ck.TOO_OLD
-CONFLICT_COARSE = ck.CONFLICT_COARSE
+CONFLICT_COARSE, CONFLICT_BATCH = ck.CONFLICT_COARSE, ck.CONFLICT_BATCH
 
 # resolve_many's fixed scan width: backlog dispatches pad to a multiple
 # of this (server/batcher.py MAX_BACKLOG matches) so every backlog size
@@ -233,13 +233,15 @@ class Resolver:
 
     def _plain_statuses(self, statuses):
         """A dispatch's verdicts as the proxy takes them: the device's
-        fourth code (a refusal only a coarse summary raised) is counted
-        and answered as the CONFLICT it is."""
-        n = statuses.count(CONFLICT_COARSE)
-        if n:
-            self.profile.count(conflicts_coarse_only=n)
-            statuses = [CONFLICT if s == CONFLICT_COARSE else s
-                        for s in statuses]
+        two refusal codes beside CONFLICT (one only a coarse summary
+        raised, one only an earlier transaction of the same batch) are
+        counted and answered as the CONFLICT they are."""
+        coarse = statuses.count(CONFLICT_COARSE)
+        in_batch = statuses.count(CONFLICT_BATCH)
+        if coarse or in_batch:
+            self.profile.count(conflicts_coarse_only=coarse,
+                               conflicts_in_batch=in_batch)
+            statuses = [CONFLICT if s > TOO_OLD else s for s in statuses]
         return statuses
 
     def adopt_profile(self, profile):

@@ -120,7 +120,11 @@ CPU_ALWAYS = frozenset({"resolver.route"})
 # status code: an upper bound on the false conflicts the summaries
 # cost); ``bucket_entries_routed`` / ``bucket_entries_fullest``, a
 # pack's live point writes and those of them in its fullest bucket,
-# counted once boundaries are cut. The lane bounds' (resolver/packing.py
+# counted once boundaries are cut. ``conflicts_in_batch``, on every
+# step: transactions refused where only an earlier accepted transaction
+# of the same batch stood in the way (the device's fifth status code:
+# refusals the batch caused itself, which the history alone would not
+# have made). The lane bounds' (resolver/packing.py
 # ``LaneBounds``): ``rebounds``, the times ``MeshResolver._maybe_rebound``
 # cut new lane bounds and fenced; ``rebound_fenced_txns``, transactions
 # the host then answered TOO_OLD because they read before that fence;
@@ -130,7 +134,8 @@ CPU_ALWAYS = frozenset({"resolver.route"})
 PLAIN_COUNTERS = ("route_dispatches", "route_slices",
                   "route_staging_reused", "lane_entries_routed",
                   "lane_entries_fullest", "h2d_args", "rebuckets",
-                  "conflicts_coarse_only", "bucket_entries_routed",
+                  "conflicts_coarse_only", "conflicts_in_batch",
+                  "bucket_entries_routed",
                   "bucket_entries_fullest", "rebounds",
                   "rebound_fenced_txns", "range_entries_routed",
                   "range_lane_dups")

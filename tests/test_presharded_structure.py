@@ -31,15 +31,20 @@ LANES = 4
 # built until PR 40 and what its program still runs behind
 # ``unpack_args``. "fast" (the point-only step, the program four of the
 # benchmark's cells run): on the parent of PR 31 (commit 11e6431),
-# untouched since. "full": re-taken in PR 35 (on the parent
+# and "full" re-taken when it was renamed (on the parent
 # c14e2d5 it was dba61d84…f1b24ab), which changed that program by
 # design: it is named ``resolve_full``, keeps the exact lanes' hits
 # apart from the coarse summaries' and returns CONFLICT_COARSE where
 # only a summary refused (ops/conflict.py ``_mark_coarse_only``).
+# Both re-taken once every step returned CONFLICT_BATCH where only an
+# earlier transaction of the batch refused (ops/conflict.py
+# ``_verdicts``: one select more over the verdicts, every other line
+# the same but for its numbering; on commit 5f92650 "full" was
+# 70658cc8…92247778 and "fast" c1d846a5…f4cc6335).
 ONE_LANE_TEXT = {
     "jax": "0.9.0",
-    "full": "70658cc81ea4d822febc0dca000ed08c01ff8d4e6af53119e992703192247778",
-    "fast": "c1d846a504d6e595bb55ae56e0b29a6701b90cdb4b164061630f64f9f4cc6335",
+    "full": "642f6f088f470b2417e5c4428a645e3089ef2a6264e58274edc1c5f511ab6a7d",
+    "fast": "f0907f901702e4e8cf6edb5f75cbb23f54d539758b632dbe8f24fd37ff306416",
 }
 
 

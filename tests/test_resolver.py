@@ -37,10 +37,11 @@ SMALL = ck.ResolverParams(
 
 def plain_status(status):
     """A step's statuses as numpy, the device-side CONFLICT_COARSE (a
-    refusal only a coarse summary raised; ``Resolver`` counts it on its
-    way out) answered as the CONFLICT it is."""
+    refusal only a coarse summary raised) and CONFLICT_BATCH (one only
+    an earlier transaction of the batch raised) answered as the CONFLICT
+    they are, as ``Resolver`` counts and answers them on their way out."""
     status = np.asarray(status)
-    return np.where(status == ck.CONFLICT_COARSE, CONFLICT, status)
+    return np.where(status > TOO_OLD, CONFLICT, status)
 
 
 def make_kernel(params=SMALL):

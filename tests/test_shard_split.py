@@ -37,6 +37,7 @@ from foundationdb_tpu.resolver.skiplist import TxnRequest
 from foundationdb_tpu.server.cluster import Cluster
 
 from conftest import TEST_KNOBS
+from tests.test_resolver import plain_status
 
 PARAMS = ck.ResolverParams(
     txns=16, point_reads=2, point_writes=2, range_reads=2,
@@ -165,7 +166,8 @@ def test_chunked_overflow_is_conservative_only():
     packed, k, _ = router.split(stacked)
     assert k > 1, "fixture must actually overflow into chunking"
     _, st = kern._scan_step.call_packed(kern.state, packed)
-    st = np.asarray(router.reassemble(st, k))
+    st = plain_status(router.reassemble(st, k))
+    st_ref = plain_status(st_ref)
     from foundationdb_tpu.core.status import COMMITTED, CONFLICT
 
     conservative = (st == st_ref) | (
@@ -316,7 +318,7 @@ def test_a_txn_with_more_than_k_slots_in_a_lane_loses_no_conflict(
     moved = single.pw_txn.copy()
     moved[(single.pw_txn == 1) & single.pw_mask] = 0
     st, _, _ = kern._step(kern.state, single._replace(pw_txn=moved))
-    st = np.asarray(st)
+    st = plain_status(st)
     assert st[0] == COMMITTED and st[2] == CONFLICT
 
 
