@@ -26,12 +26,29 @@ the ``\\x00`` — ``blob[off : off+len+1]`` is ``k+b"\\x00"`` verbatim
 (when ``len == C`` the first byte of the length word is 0, because
 C < 2^24).
 
+A range END one byte past capacity that ends in ``\\x00`` — ``k+\\x00``
+for a capacity-length ``k``, what a ``get_range`` cut short by its limit
+records after its last row — is an entry too, by the same byte:
+
+    entry(k+\\x00) = k  ||  >I(C+1)           (len(k) == C)
+
+For every key ``x`` of at most C bytes, ``x < k+\\x00`` holds if and
+only if ``entry(x) < entry(k+\\x00)`` (the limbs tie only where ``x`` is
+``k`` or ``k`` with zeros cut off its tail, and then the length word
+decides), and every byte-slicing consumer reads ``blob[off : off+C+1]``
+as ``k+\\x00`` verbatim. The legacy route encodes the same end as the
+prefix successor of ``k`` (``KeyCodec.encode_upper``); for keys of at
+most C bytes both bounds admit exactly the same keys, and for longer
+keys — which only the legacy route carries — both are conservative, so
+``encode_upper`` and the native packer stay as they are for the
+transactions that still need them.
+
 Per transaction the client ships four blobs (read/write × point/range)
 plus counts; the proxy concatenates blobs across the batch with
 ``b"".join`` and derives every offset from cumsums — no per-key touch
-server-side. Keys longer than C bytes don't flatten (the conservative
-prefix widening would be lossy on the wire); those transactions ride
-the legacy path unchanged.
+server-side. Other keys longer than C bytes don't flatten (the
+conservative prefix widening would be lossy on the wire); those
+transactions ride the legacy path unchanged.
 
 Kept in ``core`` so the wire codec can name :class:`FlatConflicts`
 without importing the resolver stack (and with it JAX).
@@ -44,7 +61,8 @@ import numpy as np
 
 _U32 = struct.Struct(">I")
 
-# per-num_limbs encode tables: (zero padding, length words 0..C)
+# per-num_limbs encode tables: (zero padding, length words 0..C+1 —
+# C+1 is a capacity-length key's successor)
 _ENC_TABS = {}
 
 
@@ -52,7 +70,7 @@ def _tabs(num_limbs):
     t = _ENC_TABS.get(num_limbs)
     if t is None:
         cap = 4 * num_limbs
-        t = (b"\x00" * cap, [_U32.pack(n) for n in range(cap + 1)])
+        t = (b"\x00" * cap, [_U32.pack(n) for n in range(cap + 2)])
         _ENC_TABS[num_limbs] = t
     return t
 
@@ -94,7 +112,9 @@ def encode_entry(key, num_limbs):
 def _encode_side(ranges, num_limbs, pad, lens):
     """One side's (points, point_blob, ranges, range_blob), or None on
     an over-capacity key. The point test mirrors proxy._split_ranges:
-    ``[k, k+\\x00)`` without building the successor bytes."""
+    ``[k, k+\\x00)`` without building the successor bytes. A range end
+    ``k+\\x00`` with ``len(k) == C`` is the entry ``k || >I(C+1)`` (the
+    module docstring)."""
     cap = 4 * num_limbs
     pts = []
     rgs = []
@@ -108,18 +128,24 @@ def _encode_side(ranges, num_limbs, pad, lens):
                 return None
             pts.append(b + pad[nb:] + lens[nb])
         else:
-            if nb > cap or len(e) > cap:
+            if nb > cap:
                 return None
             rgs.append(b + pad[nb:] + lens[nb])
             ne = len(e)
-            rgs.append(e + pad[ne:] + lens[ne])
+            if ne <= cap:
+                rgs.append(e + pad[ne:] + lens[ne])
+            elif ne == cap + 1 and e[-1] == 0:
+                rgs.append(e[:cap] + lens[ne])
+            else:
+                return None
     return len(pts), b"".join(pts), len(rgs) // 2, b"".join(rgs)
 
 
 def encode_conflicts(read_ranges, write_ranges, num_limbs):
     """Encode a transaction's conflict ranges → FlatConflicts, or None
-    when any key exceeds the 4*num_limbs-byte limb capacity (the legacy
-    path handles those with its conservative widening)."""
+    when any key exceeds the 4*num_limbs-byte limb capacity, save a
+    capacity-length key's successor as a range end (the legacy path
+    handles those with its conservative widening)."""
     pad, lens = _tabs(num_limbs)
     r = _encode_side(read_ranges, num_limbs, pad, lens)
     if r is None:
@@ -132,7 +158,8 @@ def encode_conflicts(read_ranges, write_ranges, num_limbs):
 
 def point_limbs(blob, num_limbs):
     """uint32[n_entries, W] native-order limb rows (one frombuffer
-    pass — this IS KeyCodec.encode_lower_batch's output)."""
+    pass — this IS KeyCodec.encode_lower_batch's output; a range blob
+    reads as its lower and upper rows in turn)."""
     W = num_limbs + 1
     if not blob:
         return np.zeros((0, W), dtype=np.uint32)
@@ -140,19 +167,9 @@ def point_limbs(blob, num_limbs):
         np.uint32)
 
 
-def range_limbs(blob, num_limbs):
-    """(lower uint32[n, W], upper uint32[n, W]) limb rows."""
-    W = num_limbs + 1
-    if not blob:
-        z = np.zeros((0, W), dtype=np.uint32)
-        return z, z
-    a = np.frombuffer(blob, dtype=">u4").reshape(-1, 2, W).astype(
-        np.uint32)
-    return a[:, 0], a[:, 1]
-
-
 def _decode_entries(blob, num_limbs):
-    """entry blob → list[bytes] raw keys (exact: in-capacity only)."""
+    """entry blob → list[bytes] raw keys (exact: an entry holds an
+    in-capacity key or a capacity-length key's successor)."""
     w = entry_width(num_limbs)
     if not blob:
         return []
@@ -201,12 +218,6 @@ class FlatTxnBatch:
 
     def __len__(self):
         return len(self.rv)
-
-    def point_limbs(self, blob):
-        return point_limbs(blob, self.num_limbs)
-
-    def range_limbs(self, blob):
-        return range_limbs(blob, self.num_limbs)
 
     # ── fallback decode (rare: lane overflow, too-old txns,
     #    report_conflicting_keys) ──
@@ -258,14 +269,25 @@ def build_flat_batch(requests, num_limbs, idmp_key_of=None):
 
     ``idmp_key_of(request)`` returns the idempotency system row an
     id-carrying request must conflict on (or None); its point entry is
-    appended to BOTH sides, mirroring legacy ``_idmp_point``."""
+    appended to BOTH sides, mirroring legacy ``_idmp_point``.
+
+    A request with no conflict range at all and no blobs (a commit the
+    cluster makes itself: the consistency scan's cursor, the shard map)
+    is an empty entry rather than a reason to build the batch legacy."""
     n = len(requests)
     if n == 0:
         z = np.zeros(0, dtype=np.int64)
         return FlatTxnBatch(num_limbs, z, z, z, z, z, b"", b"", b"", b"")
     fcs = [r.flat_conflicts for r in requests]
     if None in fcs:
-        return None
+        empty = FlatConflicts(num_limbs, 0, b"", 0, b"", 0, b"", 0, b"")
+        fcs = [
+            empty if f is None and not r.read_conflict_ranges
+            and not r.write_conflict_ranges else f
+            for f, r in zip(fcs, requests)
+        ]
+        if None in fcs:
+            return None
     has_ids = any(
         getattr(r, "idempotency_id", None) is not None for r in requests
     ) and idmp_key_of is not None
@@ -296,8 +318,7 @@ def build_flat_batch(requests, num_limbs, idmp_key_of=None):
     pw_parts = []
     rr_parts = []
     rw_parts = []
-    for i, r in enumerate(requests):
-        f = r.flat_conflicts
+    for i, (r, f) in enumerate(zip(requests, fcs)):
         if f.num_limbs != num_limbs:
             return None
         ik = idmp_key_of(r)

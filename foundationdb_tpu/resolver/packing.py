@@ -128,6 +128,19 @@ class CoarseBuckets:
             self._bounds, _rows_sortable(rows), side="right"
         ).astype(np.int32)
 
+    def of_entries(self, blob, rows):
+        """:meth:`of` for the rows of a flat entry blob
+        (core/flatpack.py), searched in the blob's own bytes: an entry,
+        key padded ‖ ``>I(len)``, is its row in :func:`_rows_sortable`'s
+        form already."""
+        if self._bounds is None:
+            return bucket_of(rows, self.bits)
+        if not blob:
+            return np.zeros(0, np.int32)
+        return np.searchsorted(
+            self._bounds, np.frombuffer(blob, self._bounds.dtype),
+            side="right").astype(np.int32)
+
     def note_rows(self, rows):
         """Take limb rows uint32[N, W] into the sample. On the thread
         that dispatches this is one short slice store, which keeps the
@@ -810,32 +823,47 @@ class BatchPacker:
                 rv_all - base_version, 0, 0xFFFFFFFF
             ).astype(u32)
             flat["txn_mask"][slot] = True
-        L = p.key_width - 1
-        for side, counts, blob, lanes in (
-                ("pr", prc, pr_blob, p.point_reads),
-                ("pw", pwc, pw_blob, p.point_writes)):
-            if len(blob):
+        # the four sides' entries decoded, their buckets found and the
+        # points' hashes taken in ONE pass each over the joined blobs: a
+        # numpy call costs the dispatching thread its fixed overhead
+        # whatever its size, and a pass a side was four decodes, six
+        # bucket searches and two hash chains a range pack
+        w4 = 4 * p.key_width
+        blob = b"".join((pr_blob, pw_blob, rr_blob, rw_blob))
+        rows = flatpack.point_limbs(blob, p.key_width - 1)
+        buckets = self.buckets.of_entries(blob, rows)
+        n_points = (len(pr_blob) + len(pw_blob)) // w4
+        hashes = fnv_hash_np(rows[:n_points]) if n_points else None
+        first = 0  # the side's first entry among ``rows``
+        for side, counts, n, lanes in (
+                ("pr", prc, len(pr_blob) // w4, p.point_reads),
+                ("pw", pwc, len(pw_blob) // w4, p.point_writes)):
+            if n:
                 at = entry_slots(counts, lanes)
-                rows = flatpack.point_limbs(blob, L)
-                flat[side + "_key"][at] = rows.view(self._row).reshape(-1)
-                flat[side + "_hash"][at] = fnv_hash_np(rows)
-                bucket = self.buckets.of(rows)
+                keys = rows[first:first + n]
+                bucket = buckets[first:first + n]
+                flat[side + "_key"][at] = keys.view(self._row).reshape(-1)
+                flat[side + "_hash"][at] = hashes[first:first + n]
                 flat[side + "_bucket"][at] = bucket
                 flat[side + "_mask"][at] = True
                 if side == "pw":
-                    self._note_writes(rows, bucket)
-        for side, counts, blob, lanes in (
-                ("rr", rrc, rr_blob, p.range_reads),
-                ("rw", rwc, rw_blob, p.range_writes)):
-            if len(blob):
+                    self._note_writes(keys, bucket)
+            first += n
+        for side, counts, n, lanes in (
+                ("rr", rrc, len(rr_blob) // w4, p.range_reads),
+                ("rw", rwc, len(rw_blob) // w4, p.range_writes)):
+            if n:  # lower || upper entry pairs
                 at = entry_slots(counts, lanes)
-                lo, hi = flatpack.range_limbs(blob, L)
+                lo = rows[first:first + n:2]
+                bucket = buckets[first:first + n]
                 flat[side + "_b"][at] = lo.view(self._row).reshape(-1)
-                flat[side + "_e"][at] = hi.view(self._row).reshape(-1)
-                flat[side + "_lo"][at] = self.buckets.of(lo)
-                flat[side + "_hi"][at] = self.buckets.of(hi)
+                flat[side + "_e"][at] = rows[first + 1:first + n:2].view(
+                    self._row).reshape(-1)
+                flat[side + "_lo"][at] = bucket[0::2]
+                flat[side + "_hi"][at] = bucket[1::2]
                 flat[side + "_mask"][at] = True
                 self.buckets.note_rows(lo)
+            first += n
         for b, (cv, ws) in enumerate(metas):
             bufs["cv"][b] = u32(cv - base_version)
             bufs["nws"][b] = u32(max(0, ws - base_version))

@@ -1413,6 +1413,12 @@ class Cluster:
                     "commit_proxy", "sched_reordered"),
                 "sched_deferred": self._sum_counter(
                     "commit_proxy", "sched_deferred"),
+                # commit pack path (core/flatpack.py): request batches
+                # built columnar vs through the legacy TxnRequest build
+                "pack_flat_batches": self._sum_counter(
+                    "commit_proxy", "pack_flat_batches"),
+                "pack_legacy_batches": self._sum_counter(
+                    "commit_proxy", "pack_legacy_batches"),
             },
             "commit_latency_bands": commit,
             "grv_latency_bands": grv,

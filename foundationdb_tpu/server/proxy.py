@@ -159,9 +159,13 @@ class CommitProxy:
         self.conflict_count = 0
         # commit pack-path observability: how many request batches
         # packed columnar vs legacy (tests/test_packing_flat.py holds
-        # the knob matrix to these)
+        # the knob matrix to these). Plain totals that count with the
+        # metrics kill switch off; the registry counters feed the status
+        # rollups, where a run reads the share of batches built flat
         self.pack_flat_batches = 0
         self.pack_legacy_batches = 0
+        self._m_pack_flat = self.metrics.counter("pack_flat_batches")
+        self._m_pack_legacy = self.metrics.counter("pack_legacy_batches")
         # abort-aware batch scheduling (server/scheduler.py, knob
         # commit_batch_scheduling): plain totals that count even with
         # the metrics kill switch off; the registry counters feed
@@ -1061,8 +1065,10 @@ class CommitProxy:
         flat = self._try_build_flat(requests)
         if flat is not None:
             self.pack_flat_batches += 1
+            self._m_pack_flat.inc()
             return flat
         self.pack_legacy_batches += 1
+        self._m_pack_legacy.inc()
         if not all(getattr(r_, "wants_point_split", True)
                    for r_ in self.resolvers):
             # host backends: a point IS its tiny range — hand the

@@ -255,6 +255,159 @@ def test_encode_conflicts_rejects_over_capacity_keys():
     assert f is not None and f.read_points == 1
 
 
+# ── a capacity-length key's successor as a range end ────────────────
+# (a get_range cut short by its limit records [first, last + \x00): for
+# mako's 32-byte keys at 8 limbs that end is one byte past capacity)
+def _succ_case(limbs):
+    cap = 4 * limbs
+    k = b"k" * cap
+    return cap, k, [(b"a", k + b"\x00")]
+
+
+@pytest.mark.parametrize("limbs", [L, 8])
+@pytest.mark.parametrize("side", ["read", "write"])
+def test_encode_conflicts_takes_a_capacity_keys_successor_as_a_range_end(
+        limbs, side):
+    cap, k, rng = _succ_case(limbs)
+    f = flatpack.encode_conflicts(
+        rng if side == "read" else [], rng if side == "write" else [], limbs)
+    assert f is not None
+    n, blob = ((f.read_ranges, f.read_range_blob) if side == "read"
+               else (f.write_ranges, f.write_range_blob))
+    w = flatpack.entry_width(limbs)
+    assert n == 1 and len(blob) == 2 * w
+    assert blob[w:] == k + (cap + 1).to_bytes(4, "big")
+    assert f.read_points == f.write_points == 0
+
+
+@pytest.mark.parametrize("shape", ["begin_over", "end_no_nul", "end_over2"])
+@pytest.mark.parametrize("side", ["read", "write"])
+def test_encode_conflicts_still_refuses_other_over_capacity_ranges(
+        shape, side):
+    """A begin past capacity, an end past it that does not end in
+    ``\x00``, and an end two bytes past it stay on the legacy route."""
+    cap = 4 * L
+    k = b"k" * cap
+    rng = {"begin_over": (k + b"\x00", k + b"\x01"),
+           "end_no_nul": (b"a", k + b"\x01"),
+           "end_over2": (b"a", k + b"\x00\x00")}[shape]
+    assert flatpack.encode_conflicts(
+        [rng] if side == "read" else [], [rng] if side == "write" else [],
+        L) is None
+
+
+@pytest.mark.parametrize("limbs", [L, 8])
+def test_a_successor_end_decodes_back_exactly(limbs):
+    """FlatTxnBatch[i], decode_side and heatmap.entry_key give back
+    ``k + b"\x00"``, byte for byte."""
+    from foundationdb_tpu.utils import heatmap
+
+    cap, k, rng = _succ_case(limbs)
+    point = (b"p", b"p\x00")
+    reqs = [CommitRequest(5, [], [point] + rng, rng,
+                          flat_conflicts=flatpack.encode_conflicts(
+                              [point] + rng, rng, limbs)),
+            CommitRequest(6, [], rng, [],
+                          flat_conflicts=flatpack.encode_conflicts(
+                              rng, [], limbs))]
+    flat = flatpack.build_flat_batch(reqs, limbs)
+    for i in range(2):
+        assert flat[i].range_reads == rng
+    assert flat[0].point_reads == [b"p"] and flat[0].range_writes == rng
+    f = reqs[0].flat_conflicts
+    assert flatpack.decode_side(f.read_point_blob, f.read_range_blob,
+                                limbs) == [point] + rng
+    w = flatpack.entry_width(limbs)
+    assert heatmap.entry_key(f.read_range_blob[w:]) == k + b"\x00"
+    # the scheduler's decode of a mixed-width batch reads it the same way
+    from foundationdb_tpu.server import scheduler
+
+    assert scheduler._entries_ranges(f.read_range_blob, limbs) == rng
+
+
+@pytest.mark.parametrize("limbs", [L, 8])
+def test_a_successor_entry_sorts_between_its_key_and_the_next(limbs):
+    """entry(k) < entry(k + \x00) < entry(next capacity-length key), as
+    raw bytes (the scheduler's order) and as limb rows (the packer's and
+    the router's): and every key of at most C bytes sorts against the
+    successor exactly as its bytes do."""
+    from foundationdb_tpu.resolver.packing import _rows_sortable
+
+    cap = 4 * limbs
+    k = b"m" * (cap - 2) + b"\x07\x00"
+    nxt = b"m" * (cap - 2) + b"\x07\x01"
+    f = flatpack.encode_conflicts([(b"a", k + b"\x00")], [], limbs)
+    succ = f.read_range_blob[flatpack.entry_width(limbs):]
+    hi = flatpack.point_limbs(f.read_range_blob, limbs)[1::2]
+    probes = [k, nxt, k[:-1], k[:-2], k[:-1] + b"\x01", b"m" * (cap - 2),
+              b"m" * (cap - 2) + b"\x08", b"", b"\xff" * cap]
+    for x in probes:
+        ex = flatpack.encode_entry(x, limbs)
+        want = x < k + b"\x00"
+        assert (ex < succ) == want, x
+        row = flatpack.point_limbs(ex, limbs)
+        assert (_rows_sortable(row)[0] < _rows_sortable(hi)[0]) == want, x
+    assert flatpack.encode_entry(k, limbs) < succ \
+        < flatpack.encode_entry(nxt, limbs)
+
+
+def test_a_successor_end_spans_the_lanes_the_legacy_end_spans():
+    """A four-lane router's span of a range that ends at a key's
+    successor: it reaches the lane whose bound IS that key and no
+    further, under the flat entry and under the legacy prefix successor
+    alike."""
+    from foundationdb_tpu.core.keys import KeyCodec
+    from foundationdb_tpu.resolver.packing import ShardRouter
+
+    params = params_from_knobs(Knobs(**{**TEST_KNOBS, "key_limbs": 8}))
+    codec = KeyCodec(8)
+
+    def key(i):
+        return b"mako%028d" % i
+
+    router = ShardRouter(params, 4, bounds=codec.encode_lower_batch(
+        [key(150), key(301), key(450)]))
+    for first, last, lanes in ((100, 149, (0, 0)), (100, 150, (0, 1)),
+                               (150, 300, (1, 1)), (151, 301, (1, 2)),
+                               (0, 599, (0, 3))):
+        rng = (key(first), key(last) + b"\x00")
+        f = flatpack.encode_conflicts([rng], [], 8)
+        rows = flatpack.point_limbs(f.read_range_blob, 8)
+        lo, hi = rows[0::2], rows[1::2]
+        got = router.lane_span(lo, hi)
+        assert (int(got[0][0]), int(got[1][0])) == lanes, rng
+        legacy = router.lane_span(codec.encode_lower_batch([rng[0]]),
+                                  codec.encode_upper(rng[1])[None])
+        assert (int(legacy[0][0]), int(legacy[1][0])) == lanes, rng
+
+
+def test_a_request_without_conflict_ranges_joins_a_flat_batch():
+    """A commit the cluster makes itself (the consistency scan's cursor)
+    carries no blobs and no conflict range: it is an empty entry, not a
+    reason to build the whole batch legacy. One with ranges and no blobs
+    still is."""
+    system = CommitRequest(9, [], [], [])
+    assert system.flat_conflicts is None
+    reqs = MIXED + [system]
+    flat = flatpack.build_flat_batch(reqs, L)
+    assert flat is not None and len(flat) == len(reqs)
+    assert flat.rv[-1] == 9
+    assert (flat.prc[-1], flat.pwc[-1], flat.rrc[-1], flat.rwc[-1]) == (
+        0, 0, 0, 0)
+    packer = BatchPacker(params_from_knobs(KNOBS))
+    _assert_batches_equal(
+        packer.pack([_legacy_txn(r) for r in reqs], 0, 30, 7),
+        packer.pack_flat(flat, 0, 30, 7))
+    # and beside an id-carrying request, on the per-request build
+    idk = b"\xff\x02/i"
+    with_id = flatpack.build_flat_batch(
+        [_req(5, [], [(b"a", b"a\x00")], idmp=b"x"), system], L,
+        lambda r: idk if r.idempotency_id is not None else None)
+    assert list(with_id.pwc) == [2, 0] and list(with_id.prc) == [1, 0]
+    unencoded = CommitRequest(9, [], [(b"a", b"b")], [])
+    assert flatpack.build_flat_batch(MIXED + [unencoded], L) is None
+
+
 def test_flat_decode_roundtrip():
     flat = flatpack.build_flat_batch(MIXED, L)
     for i, r in enumerate(MIXED):
